@@ -7,7 +7,7 @@ these guards catch the same hazard classes at runtime:
   lower events, so a training loop that recompiles per iteration fails
   its budget instead of silently running 100x slow. Counting hooks the
   "Compiling <name> ..." records jax's lowering path emits (logger
-  ``jax._src.interpreters.pxla``; jax 0.4.x) — persistent-XLA-cache hits
+  ``jax._src.interpreters.pxla``) — persistent-XLA-cache hits
   still lower, so the count reflects Python-level retraces, which is
   exactly the per-iteration recompile signal.
 - :func:`no_implicit_transfers` wraps ``jax.transfer_guard("disallow")``:
@@ -37,15 +37,13 @@ import os
 from contextlib import contextmanager
 from typing import List, Optional
 
-# jax 0.4.x emits "Compiling <name> with global shapes and types ..." from
-# these loggers when a function is traced+lowered (DEBUG unless
-# jax_log_compiles); dispatch.py carries the "Finished XLA compilation"
-# companion records; compiler.py logs "Persistent compilation cache
-# hit for '<name>' ..." when the lowered program is served from the
-# on-disk cache instead of XLA-compiled (the signal the ISSUE-4
-# relaunch-skips-recompilation test asserts on).
-_COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch",
-                    "jax._src.compiler")
+# pxla emits "Compiling <name> with global shapes and types ..." when a
+# function is traced+lowered (DEBUG unless jax_log_compiles);
+# compiler.py logs "Persistent compilation cache hit for '<name>' ..."
+# when the lowered program is served from the on-disk cache instead of
+# XLA-compiled (the signal the relaunch-skips-recompilation test
+# asserts on). Checked against the installed jax 0.9.0.
+_COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.compiler")
 
 
 class CompileBudgetExceeded(AssertionError):

@@ -53,8 +53,8 @@ def init_distributed(coordinator_address: Optional[str] = None,
         log.warning("init_distributed called twice; ignoring")
         return jax.process_index()
     # joining the world is the single most failure-prone call of a
-    # multi-host run (coordinator not up yet, DNS hiccup, tunnel
-    # cycling UNAVAILABLE) — retry under the shared device policy
+    # multi-host run (coordinator not up yet, DNS hiccup, a device
+    # runtime cycling UNAVAILABLE) — retry under the shared device policy
     # instead of dying on the first connection failure
     import os
 

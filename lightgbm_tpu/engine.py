@@ -43,19 +43,12 @@ def train(params: Dict[str, Any], train_set: Dataset,
     fresh. See README "Fault tolerance & checkpointing".
     """
     params = copy.deepcopy(params) if params else {}
-    # persistent compile cache (ISSUE 4): point XLA at the configured
-    # on-disk cache BEFORE any program compiles, so a relaunched/resumed
-    # run (crash recovery, supervisor retry) skips the multi-minute
-    # grower compile instead of repaying it. Env-driven supervisors
-    # (LGBM_TPU_COMPILE_CACHE / legacy LGBM_TPU_JIT_CACHE) win over
-    # nothing; the explicit param wins over both.
-    import os as _os
-    from .utils.jit_cache import (ENV_COMPILE_CACHE, ENV_JIT_CACHE,
-                                  enable_persistent_cache)
-    _cache_dir = str(params.get("tpu_compile_cache_dir") or "")
-    if _cache_dir or _os.environ.get(ENV_COMPILE_CACHE) or \
-            _os.environ.get(ENV_JIT_CACHE):
-        enable_persistent_cache(_cache_dir or None)
+    # persistent compile cache: point XLA at the configured on-disk
+    # cache BEFORE any program compiles, so a relaunched/resumed run
+    # (crash recovery, supervisor retry) skips the multi-minute grower
+    # compile instead of repaying it (utils/jit_cache has the order)
+    from .utils.jit_cache import enable_if_configured
+    enable_if_configured(str(params.get("tpu_compile_cache_dir") or ""))
     # resolve num_boost_round aliases (ref: engine.py:149-160)
     for alias in _ConfigAliases.get("num_iterations"):
         if alias in params and alias != "num_iterations":

@@ -1,13 +1,18 @@
 """On-demand build + ctypes bindings for the native runtime kernels.
 
 The parsing hot path (CSV/TSV/LibSVM byte scanning) runs as C++
-(parser.cpp) compiled once per machine into ``_build/lgbm_native.so``;
-every entry point has a pure-numpy fallback so the package works without
-a compiler (``LIGHTGBM_TPU_NO_NATIVE=1`` forces the fallback).
+(parser.cpp) compiled once per source state into
+``_build/lgbm_native.<hash of the sources>.so``, with
+``_build/lgbm_native.so`` kept as a link to the current one for C
+programs that link against it; every entry point has a pure-numpy
+fallback so the package works without a compiler
+(``LIGHTGBM_TPU_NO_NATIVE=1`` forces the fallback).
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -31,18 +36,40 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def artifact_path() -> str:
+    """The built library for the sources as they are NOW, named by a
+    hash of their contents. An mtime comparison is not enough: a copied
+    checkout (archive, container snapshot) can reset mtimes, and a stale
+    ``.so`` would then shadow edited C++."""
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")        # file boundary is part of the key
+    return os.path.join(_BUILD_DIR,
+                        f"lgbm_native.{h.hexdigest()[:16]}.so")
+
+
 def _build() -> Optional[str]:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if (os.path.exists(_SO_PATH) and
-            os.path.getmtime(_SO_PATH) >= max(os.path.getmtime(s)
-                                              for s in _SRCS)):
-        return _SO_PATH
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           *_SRCS, "-ldl", "-o", _SO_PATH + ".tmp"]
+    so = artifact_path()
+    tmp = f"{so}.{os.getpid()}.tmp"     # concurrent builders never share
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_SO_PATH + ".tmp", _SO_PATH)
-        return _SO_PATH
+        if not os.path.exists(so):
+            cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+                   "-pthread", *_SRCS, "-ldl", "-o", tmp]
+            subprocess.run(cmd, check=True, capture_output=True,
+                           timeout=120)
+            os.replace(tmp, so)
+            for stale in glob.glob(os.path.join(_BUILD_DIR,
+                                                "lgbm_native.*.so")):
+                if stale != so:
+                    os.unlink(stale)
+        if not (os.path.islink(_SO_PATH) and
+                os.readlink(_SO_PATH) == os.path.basename(so)):
+            os.symlink(os.path.basename(so), tmp)
+            os.replace(tmp, _SO_PATH)
+        return so
     except (OSError, subprocess.SubprocessError) as e:
         log.debug(f"native build failed ({e}); using numpy fallbacks")
         return None

@@ -60,7 +60,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .hist_pallas import _CompilerParams, _pad_to, fit_tiles
+from .hist_pallas import (_pad_to, bf16_triple, default_interpret,
+                          fit_tiles)
 
 
 def level_tiles(feature_tile: int, num_bin: int, block_rows: int,
@@ -149,13 +150,8 @@ def _hist_level_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray,
     f32_mode = gh.dtype == jnp.float32
     acc_dtype = jnp.int32 if int8_mode else jnp.float32
     if f32_mode:
-        # exact f32 accumulation at native bf16 MXU rate (the
-        # hist_pallas bf16-triple trick; see that module's rationale)
-        hi = gh.astype(jnp.bfloat16)
-        r1 = gh - hi.astype(jnp.float32)
-        mid = r1.astype(jnp.bfloat16)
-        lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-        gh = jnp.concatenate([hi, mid, lo], axis=1)          # [Rp, 3C]
+        # f32-accurate accumulation at native bf16 MXU rate
+        gh = bf16_triple(gh)                                 # [Rp, 3C]
     Cin = gh.shape[1]
     Cp = 32 if int8_mode else _pad_to(max(Cin, 16), 16)
     Bp = _pad_to(num_bin, 128)
@@ -191,7 +187,7 @@ def _hist_level_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_nodes + 1, Cp, Fp * Bp),
                                        acc_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(owner, bins_fm, gh_t)
@@ -222,14 +218,14 @@ def hist_level(bins_rm: jnp.ndarray, gh: jnp.ndarray, local: jnp.ndarray,
     construction: empty nodes own zero blocks (their never-written
     banks are masked to zero below), tiny nodes own one padded block.
 
-    ``interpret=None`` picks compiled mode on TPU and the Pallas
-    interpreter elsewhere (the CPU parity tests run the interpreter on
-    the SAME kernel). Infeasible tile shapes must be rejected by the
-    caller via ``level_tiles`` BEFORE calling (the level phase falls
-    back to the blocks composition there).
+    ``interpret=None`` picks the Pallas interpreter on the CPU backend
+    only (the CPU parity tests run the interpreter on the SAME kernel)
+    and compiled mode everywhere else. Infeasible tile shapes must be
+    rejected by the caller via ``level_tiles`` BEFORE calling (the level
+    phase falls back to the blocks composition there).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     R, F = bins_rm.shape
     feature_tile, block_rows, ok = level_tiles(feature_tile, num_bin,
                                                block_rows, n_nodes, R)

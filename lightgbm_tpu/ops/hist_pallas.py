@@ -43,10 +43,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.4.3x -> 0.5);
-# resolve whichever this jax ships so the kernel lowers on both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+from ..utils.log import info_once as _log_once
+
+
+def default_interpret() -> bool:
+    """Run the Pallas interpreter only where Mosaic cannot run at all —
+    the CPU backend (tier-1 tests). Every other platform compiles the
+    kernel or raises: an accelerator that silently interpreted would
+    publish interpreter timings as device numbers."""
+    return jax.default_backend() == "cpu"
 
 
 def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
@@ -105,6 +110,26 @@ def _pad_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+def bf16_triple(gh: jnp.ndarray) -> jnp.ndarray:
+    """f32 [R, C] -> bf16 [R, 3C] = (hi | mid | lo) with
+    hi + mid + lo == gh to ~24 mantissa bits (the one-hot operand is
+    0/1, exact in bf16, so three bf16 contractions re-summed in f32 give
+    an f32-accurate histogram).
+
+    The rounding is ``lax.reduce_precision``, NOT
+    ``astype(bf16).astype(f32)``: XLA:TPU elides a convert round trip it
+    considers excess precision, which zeroes ``mid`` and ``lo`` and
+    leaves a plain-bf16 histogram (seen on v5e: error 0.23 vs 6e-5 per
+    bin at 1M rows)."""
+    def rnd(x):
+        return lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    hi = rnd(gh)
+    r1 = gh - hi
+    mid = rnd(r1)
+    lo = r1 - mid
+    return jnp.concatenate([hi, mid, lo], axis=1).astype(jnp.bfloat16)
+
+
 @functools.partial(jax.jit, static_argnames=("num_bin", "block_rows",
                                              "feature_tile", "interpret"))
 def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
@@ -116,20 +141,12 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     f32_mode = gh.dtype == jnp.float32
     acc_dtype = jnp.int32 if int8_mode else jnp.float32
     if f32_mode:
-        # Full f32 accuracy at native bf16 MXU rate: split each channel
-        # into three bf16 components (hi + mid + lo reconstructs ~24
-        # mantissa bits exactly; the one-hot operand is 0/1, exact in
-        # bf16), contract all 3C channels in ONE matmul — 9 channels
-        # still fit the 16-sublane bf16 tile the plain-bf16 path pays
-        # for, so the extra accuracy is free — and re-sum the component
-        # histograms in f32 below. Measured: 6 ms at 1M rows vs 24 ms
-        # for the einsum-HIGHEST f32 path and 34 ms for in-kernel
-        # Precision.HIGHEST.
-        hi = gh.astype(jnp.bfloat16)
-        r1 = gh - hi.astype(jnp.float32)
-        mid = r1.astype(jnp.bfloat16)
-        lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-        gh = jnp.concatenate([hi, mid, lo], axis=1)         # [R, 3C]
+        # Full f32 accuracy at native bf16 MXU rate: contract all 3C
+        # bf16 component channels in ONE matmul — 9 channels still fit
+        # the 16-sublane bf16 tile the plain-bf16 path pays for, so the
+        # extra accuracy is free — and re-sum the component histograms
+        # in f32 below.
+        gh = bf16_triple(gh)                                # [R, 3C]
     Cin = gh.shape[1]
     # sublane-align the channel axis per dtype tile: (16,128) bf16,
     # (32,128) int8
@@ -162,7 +179,7 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
         out_specs=pl.BlockSpec((Cp, feature_tile * Bp), lambda i, j: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Cp, Fp * Bp), acc_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bins_fm.astype(jnp.int32), gh_t)
@@ -217,15 +234,17 @@ def hist_pallas(bins_t: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     """Histogram [F, num_bin, C] over feature-major [F, R] bins.
 
     Same contract as hist_xla (ops/histogram.py). `interpret=None` picks
-    compiled mode on TPU and the Pallas interpreter elsewhere (tests run
-    the interpreter on the CPU mesh; the kernel itself is identical).
+    the Pallas interpreter on the CPU backend only (``default_interpret``;
+    the kernel itself is identical) and compiled mode everywhere else.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     feature_tile, block_rows, ok = fit_tiles(feature_tile, num_bin,
                                              block_rows)
     if not ok:
         from .histogram import hist_xla
+        _log_once(f"hist_pallas: tiles infeasible for bins {bins_t.shape} "
+                  f"at num_bin={num_bin} (VMEM budget); using hist_xla")
         return hist_xla(bins_t, gh, num_bin, block_rows)
     # jaxlint: disable=JL001 — interpret is a static Python flag
     return _hist_pallas_impl(bins_t, gh, num_bin, block_rows, feature_tile,
@@ -243,11 +262,14 @@ def hist_pallas_rm(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     the gather that produced the block when both live in one program.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     feature_tile, block_rows, ok = fit_tiles(feature_tile, num_bin,
                                              block_rows)
     if not ok:
         from .histogram import hist_rowmajor
+        _log_once(f"hist_pallas_rm: tiles infeasible for bins "
+                  f"{bins_rm.shape} at num_bin={num_bin} (VMEM budget); "
+                  "using the einsum row-major kernel")
         return hist_rowmajor(bins_rm, gh, num_bin,
                              block_rows=block_rows, backend="einsum")
     # jaxlint: disable=JL001 — interpret is a static Python flag

@@ -64,11 +64,27 @@ _I32_MAX = np.iinfo(np.int32).max
 _MT_DUMMY = 3  # missing-type sentinel: element is always-hot padding
 
 
+# Deepest tree the f32 UNWIND recurrence explains within the route's
+# anchoring tolerance (atol 1e-5 vs the f64 host walk): the recurrence
+# subtracts nearly equal path weights and its error grows ~4x per 4
+# levels. Measured on CPU, 6 trees x 255 leaves, 512 rows, max |dev -
+# host|: 7e-6 at the 20-step window, 7e-5 at 24, 2e-3 at 28, 0.15 at 34.
+MAX_SHAP_DEPTH = 20
+
+
 def check_explainable(models: List[HostTree]) -> None:
     """Model-level eligibility for the device TreeSHAP routes. Linear
-    leaves change the value function itself and categorical splits keep
-    bitset membership on the host path — both fall back to the host
-    ``predict_contrib`` walk (loudly once at the Booster layer)."""
+    leaves change the value function itself, categorical splits keep
+    bitset membership on the host path, and trees deeper than
+    ``MAX_SHAP_DEPTH`` lose the f32 recurrence's accuracy — all fall
+    back to the host ``predict_contrib`` walk (loudly once at the
+    Booster layer)."""
+    deepest = max((_host_depth(t, int(t.num_leaves)) for t in models),
+                  default=0)
+    if deepest > MAX_SHAP_DEPTH:
+        raise ValueError(
+            f"device TreeSHAP is f32-accurate to depth {MAX_SHAP_DEPTH}; "
+            f"this model has a tree of depth {deepest}")
     if any(getattr(t, "is_linear", False) for t in models):
         raise ValueError("device TreeSHAP does not cover linear trees")
     if any(getattr(t, "num_cat", 0) > 0 for t in models):

@@ -32,19 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map as _shard_map
-
-    def _make_sharded(fn, mesh, in_specs, out_specs):
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # pre-0.8 jax: experimental API, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _make_sharded(fn, mesh, in_specs, out_specs):
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
 import numpy as np
 
 from ..core.grower import (B_DL, B_FEAT, B_GAIN, B_LG, B_LH, B_LC, B_LO,
@@ -53,6 +40,11 @@ from ..core.grower import (B_DL, B_FEAT, B_GAIN, B_LG, B_LH, B_LC, B_LO,
 from ..ops.split import FeatureMeta, SplitRecord, pack_record_rows
 from ..utils.log import info_once as _log_once
 from .mesh import DATA_AXIS, feature_tile
+
+
+def _make_sharded(fn, mesh, in_specs, out_specs):
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_global_best_combine(axis: str):

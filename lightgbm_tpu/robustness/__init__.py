@@ -1,7 +1,8 @@
 """Fault-tolerant training runtime.
 
-Three cooperating pieces (ISSUE 2; motivated by BENCH_r01-r05 all dying
-with ``device_unreachable`` and losing every iteration of progress):
+Three cooperating pieces (ISSUE 2; motivated by early bench rounds all
+dying with ``device_unreachable`` and losing every iteration of
+progress):
 
 - :mod:`.retry` — a reusable retry policy (bounded attempts,
   decorrelated-jitter backoff, overall deadline) with an error

@@ -58,8 +58,10 @@ class ContinualService:
     """The deployable train-and-serve process. See the module docstring.
 
     ``trainer_mode``: ``"process"`` (default — supervised child,
-    crash-isolated from serving) or ``"thread"`` (in-process, for tests
-    and the <30 s smoke). ``attempt_env(i)`` forwards to the
+    crash-isolated from serving) or ``"thread"`` (in-process: tests,
+    the <30 s smoke, and the ONE-process mode on a chip — a chip belongs
+    to one process, so where this process serves on an accelerator the
+    ``process`` child trains on the CPU and says so loudly). ``attempt_env(i)`` forwards to the
     :class:`TrainerSupervisor` so chaos harnesses can arm faults on one
     specific launch."""
 

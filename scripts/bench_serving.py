@@ -30,12 +30,7 @@ import os
 import sys
 import time
 
-import jax
-
-if not os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", "cpu")
-
-import numpy as np  # noqa: E402
+import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -44,6 +39,8 @@ OUT = os.path.join(REPO, "bench_logs", "SERVING.json")
 
 
 def run(n: int, n_trees: int) -> dict:
+    import jax
+
     import lightgbm_tpu as lgb
     from lightgbm_tpu.native import get_lib
 

@@ -117,8 +117,9 @@ Usage:
       [--mem-chaos] [--integrity-chaos] [--explain]
       [--explain-rate 16] [--explain-frac 0.3]
 
---devices D > 1 on a CPU host re-execs with D virtual XLA devices;
-an already-set JAX_PLATFORMS (e.g. a TPU session) is honored.
+--devices D > 1 under JAX_PLATFORMS=cpu re-execs with D virtual XLA
+devices; with the variable unset or naming an accelerator the run uses
+the devices jax finds (the CPU is asked for, never assumed).
 """
 from __future__ import annotations
 
@@ -242,20 +243,20 @@ def parse_args(argv=None):
 
 
 def ensure_virtual_devices(n: int) -> None:
-    """Re-exec with n virtual CPU devices when needed. Honors an
-    already-set JAX_PLATFORMS: a TPU session's real devices are used
-    as-is (the satellite fix bench_serving.py shares)."""
+    """Where the caller ASKED for the CPU (``JAX_PLATFORMS=cpu``),
+    re-exec with n virtual CPU devices. Anything else — the variable
+    unset included — serves on the devices jax finds: this script never
+    chooses the CPU because nothing was said (bench_serving.py shares
+    the rule)."""
     if n <= 1:
         return
-    plat = os.environ.get("JAX_PLATFORMS", "")
-    if plat and "cpu" not in plat.lower():
+    if "cpu" not in os.environ.get("JAX_PLATFORMS", "").lower():
         return                                   # real accelerator mesh
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
         return
     os.environ["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={n}").strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.execv(sys.executable, [sys.executable] + sys.argv)
 
 

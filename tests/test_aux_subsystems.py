@@ -86,3 +86,23 @@ def test_debug_checks_env_flag(tmp_path):
     out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, timeout=300)
     assert "SANITIZER-OK" in out.stdout, (out.stdout, out.stderr)
+
+
+def test_native_artifact_is_keyed_by_source_contents(tmp_path, monkeypatch):
+    """The built library's name is a hash of the sources' bytes: a
+    checkout copied with fresh mtimes can never load a stale .so."""
+    import shutil
+
+    from lightgbm_tpu import native
+    srcs = []
+    for src in native._SRCS:
+        dst = tmp_path / os.path.basename(src)
+        shutil.copy(src, dst)
+        srcs.append(str(dst))
+    monkeypatch.setattr(native, "_SRCS", srcs)
+    first = native.artifact_path()
+    os.utime(srcs[0], (1, 1))                    # mtime alone: same name
+    assert native.artifact_path() == first
+    with open(srcs[-1], "ab") as f:
+        f.write(b" ")                            # one byte: new name
+    assert native.artifact_path() != first

@@ -642,14 +642,15 @@ def test_second_serve_returns_live_server_no_second_dispatcher():
     base = len(dispatchers())
     srv = b.serve(linger_ms=1.0, raw_score=True)
     try:
-        assert len(dispatchers()) == base + 1
+        # one server owns two batchers: scores and explanations (PR 20)
+        assert len(dispatchers()) == base + 2
         again = b.serve()
         assert again is srv
-        assert len(dispatchers()) == base + 1, \
+        assert len(dispatchers()) == base + 2, \
             "second serve() spawned a second dispatcher"
         with pytest.raises(lgb.LightGBMError, match="live ModelServer"):
             b.serve(linger_ms=9.0)
-        assert len(dispatchers()) == base + 1
+        assert len(dispatchers()) == base + 2
     finally:
         srv.close()
     # a CLOSED server is replaced, not resurrected

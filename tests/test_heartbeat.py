@@ -393,8 +393,51 @@ def _tiny_train(extra_params, rounds=3):
                      num_boost_round=rounds)
 
 
-def test_engine_honors_compile_cache_param(tmp_path):
+def test_cache_dir_default_is_the_checkout_and_stable():
+    """Nothing set: <checkout>/.jax_cache, the same path every call —
+    never a temporary name."""
+    from lightgbm_tpu.utils.jit_cache import resolve_cache_dir
+    want = os.path.join(os.path.abspath(REPO), ".jax_cache")
+    assert resolve_cache_dir(env={}) == want
+    assert resolve_cache_dir(env={}) == want
+    # the jax variable beats the param and the in-repo variable, verbatim
+    assert resolve_cache_dir("param_dir", env={
+        "JAX_COMPILATION_CACHE_DIR": "placed/../placed",
+        "LGBM_TPU_COMPILE_CACHE": "/x"}) == "placed/../placed"
+    assert resolve_cache_dir(env={"LGBM_TPU_JIT_CACHE": "/legacy"}) == want
+
+
+def test_placed_cache_is_never_overridden(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the program uses it and sets no
+    other directory in code — not from the param, not from the in-repo
+    variable — before or after lgb.train."""
+    placed = str(tmp_path / "placed")
+    code = (
+        "import jax, numpy as np\n"
+        "import lightgbm_tpu as lgb\n"
+        "from lightgbm_tpu.utils.jit_cache import enable_persistent_cache\n"
+        "print('ENABLED', enable_persistent_cache('%s'))\n"
+        "X = np.random.default_rng(0).normal(size=(300, 4))\n"
+        "lgb.train({'objective': 'regression', 'num_leaves': 4,\n"
+        "           'verbose': -1, 'tpu_compile_cache_dir': '%s'},\n"
+        "          lgb.Dataset(X, label=X[:, 0]), num_boost_round=1)\n"
+        "print('CONFIG', jax.config.jax_compilation_cache_dir)\n"
+        % (tmp_path / "arg", tmp_path / "param"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=placed,
+               LGBM_TPU_COMPILE_CACHE=str(tmp_path / "inrepo"))
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert f"ENABLED {placed}" in out.stdout
+    assert f"CONFIG {placed}" in out.stdout
+    assert sorted(os.listdir(tmp_path)) in ([], ["placed"])
+
+
+def test_engine_honors_compile_cache_param(tmp_path, monkeypatch):
     import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev = jax.config.jax_compilation_cache_dir
     cache = str(tmp_path / "cc")
     try:
@@ -409,6 +452,7 @@ def test_engine_honors_compile_cache_env(tmp_path, monkeypatch):
     import jax
     prev = jax.config.jax_compilation_cache_dir
     cache = str(tmp_path / "env_cc")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", cache)
     try:
         _tiny_train({})
@@ -417,7 +461,7 @@ def test_engine_honors_compile_cache_env(tmp_path, monkeypatch):
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
-def test_warm_cache_relaunch_skips_recompile(tmp_path):
+def test_warm_cache_relaunch_skips_recompile(tmp_path, monkeypatch):
     """The ISSUE-4 compile-cache contract at mechanism level: the same
     program, 'relaunched' against a warm persistent cache (in-process
     jit caches cleared — what a fresh child process starts with), is
@@ -430,6 +474,7 @@ def test_warm_cache_relaunch_skips_recompile(tmp_path):
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     cache = str(tmp_path / "warm")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
         from lightgbm_tpu.utils.jit_cache import enable_persistent_cache
         enable_persistent_cache(cache)
@@ -489,7 +534,7 @@ def test_bench_salvages_partial_on_hang(tmp_path):
     env = dict(os.environ)
     env.pop("LGBM_TPU_HEARTBEAT", None)
     env.update({
-        "BENCH_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "BENCH_ROWS": "1500", "BENCH_ITERS": "300",
         "BENCH_LEAVES": "15", "BENCH_PROBE_COMPILE": "0",
         "BENCH_WATCHDOG_SEC": "180", "BENCH_SCHEDS": "compact",

@@ -84,3 +84,61 @@ def test_hist_pallas_rm_int8_exact(rng):
                                    num_bin=B, backend="pallas"))
     assert out.dtype == np.int32 and ref.dtype == np.int32
     np.testing.assert_array_equal(out, ref)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", False)])
+def test_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """The interpreter is chosen on the cpu backend alone; any other
+    platform compiles the kernel (or raises) — an accelerator must never
+    run interpreted."""
+    import jax
+
+    from lightgbm_tpu.ops import hist_pallas as hp
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert hp.default_interpret() is interpret
+
+
+def test_bf16_triple_reconstructs_f32(rng):
+    """hi + mid + lo recovers the f32 input (the rounding that must
+    survive XLA's excess-precision folding on TPU)."""
+    from lightgbm_tpu.ops.hist_pallas import bf16_triple
+    g = (rng.normal(size=(513, 3)) * 10.0 ** rng.integers(
+        -6, 3, size=(513, 3))).astype(np.float32)
+    t = np.asarray(bf16_triple(jnp.asarray(g)).astype(jnp.float32),
+                   np.float64)
+    assert np.abs(t[:, 3:6]).max() > 0 and np.abs(t[:, 6:9]).max() > 0
+    np.testing.assert_allclose(t[:, 0:3] + t[:, 3:6] + t[:, 6:9], g,
+                               rtol=2.0 ** -22, atol=0)
+
+
+def test_infeasible_tiles_fall_back_loudly(rng):
+    """A tile that cannot fit VMEM (huge num_bin) hands the histogram to
+    the einsum kernel — once, with the shape, never silently."""
+    from lightgbm_tpu.ops.hist_pallas import fit_tiles, hist_pallas_rm
+    from lightgbm_tpu.ops.histogram import hist_rowmajor
+    from lightgbm_tpu.utils import log
+
+    S, F, B = 96, 2, 8192
+    assert not fit_tiles(8, B, 512)[2]
+    bins = rng.integers(0, B, size=(S, F)).astype(np.uint16)
+    gh = rng.normal(size=(S, 3)).astype(np.float32)
+    seen = []
+    log.logged_once.clear()
+    level = log._level          # earlier tests train with verbose=-1
+    log.set_verbosity(log.INFO)
+    log.register_logger(seen.append)
+    try:
+        out = hist_pallas_rm(jnp.asarray(bins), jnp.asarray(gh), B)
+        hist_pallas_rm(jnp.asarray(bins), jnp.asarray(gh), B)
+    finally:
+        log.register_logger(None)
+        log.set_verbosity(level)
+    notes = [m for m in seen if "tiles infeasible" in m]
+    assert len(notes) == 1, seen
+    assert f"({S}, {F})" in notes[0] and f"num_bin={B}" in notes[0]
+    ref = hist_rowmajor(jnp.asarray(bins), jnp.asarray(gh), B,
+                        block_rows=512, backend="einsum")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)

@@ -579,3 +579,29 @@ def test_deadletter_survives_supervised_relaunch(tmp_path):
     with open(stream + ".deadletter", "rb") as f:
         dead = f.read()
     assert b"not,a,number" in dead and b"1.0,2.0" in dead
+
+
+def test_trainer_child_platform_is_explicit(monkeypatch):
+    """A caller-set JAX_PLATFORMS is inherited; unset on the CPU nothing
+    is added; unset while THIS process holds an accelerator the child is
+    put on the CPU (one process per chip) — loudly, never silently."""
+    import jax
+
+    from lightgbm_tpu.service.trainer import TrainerSupervisor
+    from lightgbm_tpu.utils import log
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert TrainerSupervisor._child_platform_env() == {}
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert TrainerSupervisor._child_platform_env() == {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seen = []
+    level = log._level          # earlier tests train with verbose=-1
+    log.set_verbosity(log.WARNING)
+    log.register_logger(seen.append)
+    try:
+        assert TrainerSupervisor._child_platform_env() == {
+            "JAX_PLATFORMS": "cpu"}
+    finally:
+        log.register_logger(None)
+        log.set_verbosity(level)
+    assert any("platform=cpu" in m and "thread" in m for m in seen), seen

@@ -227,6 +227,20 @@ def test_categorical_model_falls_back_to_host(rng, caplog):
     assert srv is None or srv.shap_pack is None
 
 
+def test_deep_trees_are_refused():
+    """The f32 unwind is accurate to MAX_SHAP_DEPTH; one deeper tree
+    sends the whole model to the host walk instead of wrong numbers."""
+    import types
+    tree = types.SimpleNamespace(is_linear=False, num_cat=0,
+                                 num_leaves=255,
+                                 max_depth=shap_pack.MAX_SHAP_DEPTH)
+    shap_pack.check_explainable([tree, tree])
+    deep = types.SimpleNamespace(**{**vars(tree),
+                                    "max_depth": tree.max_depth + 1})
+    with pytest.raises(ValueError, match="depth"):
+        shap_pack.check_explainable([tree, deep])
+
+
 def test_linear_model_falls_back_to_host(rng):
     X = rng.normal(size=(400, 5)).astype(np.float32).astype(np.float64)
     y = X[:, 0] * 2.0 + X[:, 1]
