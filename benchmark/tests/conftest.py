@@ -1,0 +1,12 @@
+"""Tests of the benchmark itself (``pytest benchmark/tests -q`` on the
+CPU). They are not part of tier-1 (``tests/``)."""
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
