@@ -11,7 +11,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from lightgbm_tpu.config import (_CHOICES, _P,  # noqa: E402
+from lightgbm_tpu.config import (_CHOICES, _NOTES, _P,  # noqa: E402
                                  _UNIMPLEMENTED_WHEN)
 
 
@@ -73,6 +73,8 @@ def main() -> str:
         if name in _CHOICES:
             lines.append("- options: " +
                          ", ".join(f"`{c}`" for c in _CHOICES[name]))
+        if name in _NOTES:
+            lines.append(f"- {_NOTES[name]}")
         if name in _UNIMPLEMENTED_WHEN:
             lines.append("- **note**: accepted for compatibility; the "
                          "underlying feature is not implemented yet and "

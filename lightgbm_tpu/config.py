@@ -25,6 +25,17 @@ from .utils import log
 
 _P: Dict[str, Tuple[Any, Any, Tuple[str, ...]]] = {}
 
+# one sentence of user documentation per parameter that needs one,
+# rendered into docs/Parameters.md beside the registry's own facts
+_NOTES: Dict[str, str] = {
+    "tpu_profile_dir": (
+        "directory for a `jax.profiler` capture of the training loop; the "
+        "capture shows each of the program's sections as a host span "
+        "`lgbm.<section>` on the device's clock and each device operation "
+        "under its `lgbm.<stage>` scope, so xprof groups by stage "
+        "(`lightgbm_tpu/utils/timer.py`)"),
+}
+
 # enumerated string params: name -> accepted values, rendered into
 # docs/Parameters.md by docs/gen_parameters.py (kept HERE so the
 # registry stays the single source of truth for user docs)

@@ -35,6 +35,7 @@ from ..ops.split import (FeatureMeta, SplitHyperParams, SplitRecord,
                          K_EPSILON, K_MIN_SCORE, best_split_for_leaf,
                          calculate_splitted_leaf_output, forced_split_record,
                          meta_has_categorical, pack_record_rows)
+from ..utils import timer
 from .tree import TreeArrays
 
 
@@ -599,8 +600,9 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
         forced_thr = jnp.asarray(forced[3], jnp.int32)
 
     def leaf_hist(bins_t, gh, leaf_id, target_leaf, ctx=None):
-        mask = (leaf_id == target_leaf).astype(gh.dtype)
-        return reduce_hist(hist_fn(bins_t, gh * mask[:, None]), ctx)
+        with timer.stage("hist_kernel"):
+            mask = (leaf_id == target_leaf).astype(gh.dtype)
+            return reduce_hist(hist_fn(bins_t, gh * mask[:, None]), ctx)
 
     # extra_trees composes with the row-sharded learners: the random
     # thresholds derive from the REPLICATED per-tree key, so every device
@@ -647,6 +649,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             return out[1]
         return select_best(out)
 
+    best_of = timer.in_stage("split_scan", best_of)
+
     def grow(bins_t: jnp.ndarray, gh: jnp.ndarray,
              feature_mask: Optional[jnp.ndarray] = None,
              cegb: Optional[tuple] = None,
@@ -675,9 +679,10 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
         F = int(meta.num_bin.shape[0]) if bundled else Fp
 
         if quantized:
-            gh, conv = quantize_gradients(cfg, gh, rng_key,
-                                          reduce_max=reduce_max,
-                                          localize_key=localize_key)
+            with timer.stage("gradients"):
+                gh, conv = quantize_gradients(cfg, gh, rng_key,
+                                              reduce_max=reduce_max,
+                                              localize_key=localize_key)
         else:
             conv = lambda hh: hh
 
@@ -697,6 +702,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 return jnp.stack(parts, axis=2).reshape(
                     w.shape[0], Wp * 4)[:, :Fp].astype(jnp.int32)
 
+            unpack_rows = timer.in_stage("hist_gather", unpack_rows)
+
             def bucket_branch(n):
                 """Index of the smallest bucket >= n (descending sizes)."""
                 return (jnp.sum(sizes_arr >= n) - 1).astype(jnp.int32)
@@ -712,63 +719,67 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     f = jnp.maximum(f, 0)
                     start_c = jnp.clip(start, 0, max(R - P, 0))
                     delta = start - start_c
-                    seg = lax.dynamic_slice(order, (start_c,), (P,))
-                    if feat_sharded:
-                        col = jnp.take(colv, seg).astype(jnp.int32)
-                    else:
-                        col_idx = b_group[f] if bundled else f
-                        if packed:
-                            word_i = col_idx // 4
-                            shift = 8 * (col_idx % 4)
-                            if flat_ok:
-                                w = bins_flat[seg * Wp + word_i]
-                            else:
-                                w = jnp.take(
-                                    jnp.take(bins_t, seg, axis=0),
-                                    word_i, axis=1)
-                            col = ((w >> shift.astype(w.dtype)) &
-                                   w.dtype.type(0xFF)).astype(jnp.int32)
-                        elif flat_ok:
-                            col = bins_flat[seg * Fp + col_idx].astype(
-                                jnp.int32)
+                    with timer.stage("partition_fetch"):
+                        seg = lax.dynamic_slice(order, (start_c,), (P,))
+                        if feat_sharded:
+                            col = jnp.take(colv, seg).astype(jnp.int32)
                         else:
-                            col = jnp.take(jnp.take(bins_t, seg, axis=0),
-                                           col_idx, axis=1).astype(jnp.int32)
-                        if bundled:
-                            col = decode_bin(col, f)
-                    go_left = _go_left_bins(
-                        col, thr, dl, f, pmeta,
-                        ncat if has_cat else None,
-                        cbins if has_cat else None, fscal=fscal)
-                    pos = jnp.arange(P, dtype=jnp.int32)
-                    valid = (pos >= delta) & (pos < delta + rows)
-                    lm = valid & go_left
-                    rmk = valid & ~go_left
-                    nL = jnp.sum(lm.astype(jnp.int32))
-                    # "auto": per-bucket-size choice — lax.sort wins on
-                    # big TPU segments (1.77 vs 5.17 ms at 1M rows) but
-                    # its bitonic stages carry a fixed cost that loses to
-                    # the cumsum scatter on small buckets
-                    use_sort = (cfg.partition_mode == "sort" or
-                                (cfg.partition_mode == "auto" and
-                                 P >= 32768))
-                    if use_sort:
-                        key = jnp.where(
-                            lm, 1, jnp.where(rmk, 2,
-                                             jnp.where(pos < delta, 0, 3))
-                        ).astype(jnp.int32)
-                        _, new_seg = lax.sort((key, seg), num_keys=1,
-                                              is_stable=True)
-                    else:
-                        dst_l = delta + jnp.cumsum(lm.astype(jnp.int32)) - 1
-                        dst_r = (delta + nL +
-                                 jnp.cumsum(rmk.astype(jnp.int32)) - 1)
-                        dest = jnp.where(lm, dst_l,
-                                         jnp.where(rmk, dst_r, pos))
-                        new_seg = jnp.zeros_like(seg).at[dest].set(
-                            seg, unique_indices=True)
-                    order = lax.dynamic_update_slice(order, new_seg,
-                                                     (start_c,))
+                            col_idx = b_group[f] if bundled else f
+                            if packed:
+                                word_i = col_idx // 4
+                                shift = 8 * (col_idx % 4)
+                                if flat_ok:
+                                    w = bins_flat[seg * Wp + word_i]
+                                else:
+                                    w = jnp.take(
+                                        jnp.take(bins_t, seg, axis=0),
+                                        word_i, axis=1)
+                                col = ((w >> shift.astype(w.dtype)) &
+                                       w.dtype.type(0xFF)).astype(jnp.int32)
+                            elif flat_ok:
+                                col = bins_flat[seg * Fp + col_idx].astype(
+                                    jnp.int32)
+                            else:
+                                col = jnp.take(
+                                    jnp.take(bins_t, seg, axis=0), col_idx,
+                                    axis=1).astype(jnp.int32)
+                            if bundled:
+                                col = decode_bin(col, f)
+                        go_left = _go_left_bins(
+                            col, thr, dl, f, pmeta,
+                            ncat if has_cat else None,
+                            cbins if has_cat else None, fscal=fscal)
+                    with timer.stage("partition_order"):
+                        pos = jnp.arange(P, dtype=jnp.int32)
+                        valid = (pos >= delta) & (pos < delta + rows)
+                        lm = valid & go_left
+                        rmk = valid & ~go_left
+                        nL = jnp.sum(lm.astype(jnp.int32))
+                        # "auto": per-bucket-size choice — lax.sort wins on
+                        # big TPU segments (1.77 vs 5.17 ms at 1M rows) but
+                        # its bitonic stages carry a fixed cost that loses to
+                        # the cumsum scatter on small buckets
+                        use_sort = (cfg.partition_mode == "sort" or
+                                    (cfg.partition_mode == "auto" and
+                                     P >= 32768))
+                        if use_sort:
+                            key = jnp.where(
+                                lm, 1, jnp.where(rmk, 2,
+                                                 jnp.where(pos < delta, 0, 3))
+                            ).astype(jnp.int32)
+                            _, new_seg = lax.sort((key, seg), num_keys=1,
+                                                  is_stable=True)
+                        else:
+                            dst_l = (delta +
+                                     jnp.cumsum(lm.astype(jnp.int32)) - 1)
+                            dst_r = (delta + nL +
+                                     jnp.cumsum(rmk.astype(jnp.int32)) - 1)
+                            dest = jnp.where(lm, dst_l,
+                                             jnp.where(rmk, dst_r, pos))
+                            new_seg = jnp.zeros_like(seg).at[dest].set(
+                                seg, unique_indices=True)
+                        order = lax.dynamic_update_slice(order, new_seg,
+                                                         (start_c,))
                     return order, nL
                 return part
 
@@ -781,26 +792,28 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     With the local-sums channel the segment's raw gh
                     totals ride along (multival hists lack the
                     default-bin mass, so totals can't come from them)."""
-                    start_c = jnp.clip(start, 0, max(R - S, 0))
-                    delta = start - start_c
-                    idx = lax.dynamic_slice(order, (start_c,), (S,))
-                    if mv_mode:
-                        from ..ops.hist_multival import take_rows
-                        blk = take_rows(bins_t, idx)
-                    elif packed:
-                        # gather packed words (4x fewer elements), unpack
-                        # with shifts after the gather
-                        blk = unpack_rows(jnp.take(bins_t, idx, axis=0))
-                    else:
-                        blk = jnp.take(bins_t, idx, axis=0)
-                    ghg = jnp.take(ghv, idx, axis=0)
-                    pos = jnp.arange(S, dtype=jnp.int32)
-                    w = ((pos >= delta) &
-                         (pos < delta + rows)).astype(ghg.dtype)
-                    ghw = ghg * w[:, None]
-                    h = hist_rm(blk, ghw)
-                    if local_pool:
-                        return h, jnp.sum(ghw.astype(hist_dtype), axis=0)
+                    with timer.stage("hist_gather"):
+                        start_c = jnp.clip(start, 0, max(R - S, 0))
+                        delta = start - start_c
+                        idx = lax.dynamic_slice(order, (start_c,), (S,))
+                        if mv_mode:
+                            from ..ops.hist_multival import take_rows
+                            blk = take_rows(bins_t, idx)
+                        elif packed:
+                            # gather packed words (4x fewer elements), unpack
+                            # with shifts after the gather
+                            blk = unpack_rows(jnp.take(bins_t, idx, axis=0))
+                        else:
+                            blk = jnp.take(bins_t, idx, axis=0)
+                        ghg = jnp.take(ghv, idx, axis=0)
+                        pos = jnp.arange(S, dtype=jnp.int32)
+                        w = ((pos >= delta) &
+                             (pos < delta + rows)).astype(ghg.dtype)
+                        ghw = ghg * w[:, None]
+                    with timer.stage("hist_kernel"):
+                        h = hist_rm(blk, ghw)
+                        if local_pool:
+                            return h, jnp.sum(ghw.astype(hist_dtype), axis=0)
                     return h
                 return hb
 
@@ -855,13 +868,14 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             root_out = calculate_splitted_leaf_output(
                 root_g, root_h + 2 * K_EPSILON, hp, root_c, jnp.float32(0.0))
             leaf_id0 = jnp.zeros(R, jnp.int32)
+            root_ctx = (root_g, root_h, root_c, root_out)
             if compact:
                 root_bins = unpack_rows(bins_t) if packed else bins_t
-                hist_root = reduce_hist(hist_rm(root_bins, gh),
-                                        (root_g, root_h, root_c, root_out))
+                with timer.stage("hist_kernel"):
+                    hist_root = reduce_hist(hist_rm(root_bins, gh), root_ctx)
             else:
-                hist_root = reduce_hist(hist_fn(bins_t, gh),
-                                        (root_g, root_h, root_c, root_out))
+                with timer.stage("hist_kernel"):
+                    hist_root = reduce_hist(hist_fn(bins_t, gh), root_ctx)
             root_path = jnp.zeros(F, bool)
             hist_root_l = conv(hist_root)
             root_lsum = conv(local_root.astype(hist_dtype)) if local_pool \
@@ -964,13 +978,14 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 want_forced = forced_active[i] & state.forced_ok
                 slot_i = forced_slot[i]
                 fs = state.stats[slot_i]
-                fhist = conv(state.hist[slot_i])
-                if bundled:
-                    fhist = expand_hist(fhist, fs[S_SG], fs[S_SH],
-                                        fs[S_CNT])
-                frec = forced_split_record(
-                    fhist, forced_feat[i], forced_thr[i],
-                    fs[S_SG], fs[S_SH], fs[S_CNT], fs[S_VAL], meta, hp)
+                with timer.stage("split_scan"):
+                    fhist = conv(state.hist[slot_i])
+                    if bundled:
+                        fhist = expand_hist(fhist, fs[S_SG], fs[S_SH],
+                                            fs[S_CNT])
+                    frec = forced_split_record(
+                        fhist, forced_feat[i], forced_thr[i],
+                        fs[S_SG], fs[S_SH], fs[S_CNT], fs[S_VAL], meta, hp)
                 if has_cat:  # forced splits are numerical-only
                     frec = frec._replace(
                         num_cat=jnp.int32(0),
@@ -1043,18 +1058,20 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 # from the final segments after the loop
                 leaf_id = state.leaf_id
             else:
-                if bundled and not feat_sharded:
-                    fsafe = jnp.maximum(rec.feature, 0)
-                    bin_col = decode_bin(
-                        fetch_bin_column(bins_t, b_group[fsafe]), fsafe)
-                else:
-                    # feature-sharded EFB: fetch_bin_column already
-                    # returns the owner-decoded LOGICAL column
-                    bin_col = fetch_bin_column(bins_t, rec.feature)
-                go_left = _go_left_bins(
-                    bin_col, rec.threshold, rec.default_left, rec.feature,
-                    pmeta, rec.num_cat if has_cat else None,
-                    rec.cat_bins if has_cat else None)
+                with timer.stage("partition_fetch"):
+                    if bundled and not feat_sharded:
+                        fsafe = jnp.maximum(rec.feature, 0)
+                        bin_col = decode_bin(
+                            fetch_bin_column(bins_t, b_group[fsafe]), fsafe)
+                    else:
+                        # feature-sharded EFB: fetch_bin_column already
+                        # returns the owner-decoded LOGICAL column
+                        bin_col = fetch_bin_column(bins_t, rec.feature)
+                    go_left = _go_left_bins(
+                        bin_col, rec.threshold, rec.default_left,
+                        rec.feature, pmeta,
+                        rec.num_cat if has_cat else None,
+                        rec.cat_bins if has_cat else None)
                 in_leaf = state.leaf_id == l
                 leaf_id = jnp.where(proceed & in_leaf & ~go_left,
                                     new_leaf, state.leaf_id)
@@ -1079,7 +1096,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     # owner-column broadcast OUTSIDE the (uniform) branch
                     # so the collective runs unconditionally every step
                     # (≡ feature_parallel_tree_learner.cpp:62-75)
-                    colv = fetch_bin_column(bins_t, rec.feature)
+                    with timer.stage("partition_fetch"):
+                        colv = fetch_bin_column(bins_t, rec.feature)
                 else:
                     colv = jnp.zeros((1,), jnp.int32)
 
@@ -1120,7 +1138,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     # feature_histogram.hpp:1368)
                     sp = state.slot_map[l]
                     have = sp >= 0
-                    hist_parent_b = state.hist[jnp.maximum(sp, 0)]
+                    with timer.stage("hist_subtract"):
+                        hist_parent_b = state.hist[jnp.maximum(sp, 0)]
 
                     def hit_path():
                         order2, nL = do_partition()
@@ -1131,9 +1150,10 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                         h = lax.switch(bucket_branch(s_rows),
                                        hist_branches, order2, s_start,
                                        s_rows, gh)
-                        large = hist_parent_b - h
-                        hl = jnp.where(lsm, h, large)
-                        hr = jnp.where(lsm, large, h)
+                        with timer.stage("hist_subtract"):
+                            large = hist_parent_b - h
+                            hl = jnp.where(lsm, h, large)
+                            hr = jnp.where(lsm, large, h)
                         return order2, nL, hl, hr
 
                     miss_path = part_and_both
@@ -1298,25 +1318,29 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 slot_stamp = _set(stamps1, sr, i, proceed)
                 slot_owner = _set(_set(state.slot_owner, sl, l, proceed),
                                   sr, new_leaf, proceed)
-                hist = state.hist.at[sl].set(
-                    jnp.where(proceed, hist_left, state.hist[sl]))
-                hist = hist.at[sr].set(
-                    jnp.where(proceed, hist_right, hist[sr]))
+                with timer.stage("hist_subtract"):
+                    hist = state.hist.at[sl].set(
+                        jnp.where(proceed, hist_left, state.hist[sl]))
+                    hist = hist.at[sr].set(
+                        jnp.where(proceed, hist_right, hist[sr]))
             else:
                 slot_map = state.slot_map
                 slot_stamp = state.slot_stamp
                 slot_owner = state.slot_owner
-                hist_parent = state.hist[l]
-                hist_large = hist_parent - hist_small
-                hist_left = jnp.where(left_smaller, hist_small, hist_large)
-                hist_right = jnp.where(left_smaller, hist_large, hist_small)
-                # NOTE: an unconditional pair write (no proceed select)
-                # was tried here and REVERTED — without the fallback
-                # read XLA lost the in-place pattern and double-copied
-                # the whole [L, F, B, 3] pool every split (2x 21 MB at
-                # the bench geometry); don't redo it.
-                hist = _set_rows2(state.hist, l, new_leaf,
-                                  hist_left, hist_right, proceed)
+                with timer.stage("hist_subtract"):
+                    hist_parent = state.hist[l]
+                    hist_large = hist_parent - hist_small
+                    hist_left = jnp.where(left_smaller, hist_small,
+                                          hist_large)
+                    hist_right = jnp.where(left_smaller, hist_large,
+                                           hist_small)
+                    # NOTE: an unconditional pair write (no proceed select)
+                    # was tried here and REVERTED — without the fallback
+                    # read XLA lost the in-place pattern and double-copied
+                    # the whole [L, F, B, 3] pool every split (2x 21 MB at
+                    # the bench geometry); don't redo it.
+                    hist = _set_rows2(state.hist, l, new_leaf,
+                                      hist_left, hist_right, proceed)
 
             # ---- local-sums channel (voting): children's LOCAL totals --
             if local_pool:
@@ -1476,17 +1500,18 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             # a concatenate kernel in the while body
             lr4 = brow[B_LG:B_RO + 1].reshape(2, 4)
             sg2, sh2, cn2 = lr4[:, 0], lr4[:, 1], lr4[:, 2]
-            hists2 = conv(jnp.stack([hist_left, hist_right]))
-            if bundled:
-                if local_pool:
-                    # LOCAL pool: default-bin mass reconstructed from
-                    # the shard's own totals (local-sums channel)
-                    hists2 = jax.vmap(expand_hist)(
-                        hists2, lsums2[:, 0], lsums2[:, 1],
-                        lsums2[:, 2])
-                else:
-                    hists2 = jax.vmap(expand_hist)(hists2, sg2, sh2,
-                                                   cn2)
+            with timer.stage("split_scan"):
+                hists2 = conv(jnp.stack([hist_left, hist_right]))
+                if bundled:
+                    if local_pool:
+                        # LOCAL pool: default-bin mass reconstructed from
+                        # the shard's own totals (local-sums channel)
+                        hists2 = jax.vmap(expand_hist)(
+                            hists2, lsums2[:, 0], lsums2[:, 1],
+                            lsums2[:, 2])
+                    else:
+                        hists2 = jax.vmap(expand_hist)(hists2, sg2, sh2,
+                                                       cn2)
             ou2 = lr4[:, 3]
             mn2 = jnp.stack([l_min, r_min])
             mx2 = jnp.stack([l_max, r_max])
@@ -1722,4 +1747,6 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             return tree, leaf_id
         return tree, state.leaf_id
 
-    return grow
+    # the grower's own bookkeeping (tree arrays, leaf_id, GrowState) is
+    # whatever no inner stage claims
+    return timer.in_stage("tree_update", grow)

@@ -55,6 +55,7 @@ from ..ops.split import FeatureMeta, SplitHyperParams
 from ..ops.forest import ServingEngine
 from ..ops.predict import depth_steps, tree_leaf_bins
 from ..utils import log
+from ..utils import timer
 from ..utils.timer import global_timer
 from .sample_strategy import SampleStrategy
 
@@ -412,7 +413,7 @@ class GBDT:
         pending, self._pending = self._pending, []
         self._stop_checked = 0
         self._hb_sync_beat()
-        with global_timer.section("Tree::ToHost"):
+        with global_timer.section("Tree::ToHost", iteration=self.iter):
             stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
                                    *[p.tree for p in pending])
             host_stacked = jax.device_get(stacked)
@@ -460,7 +461,7 @@ class GBDT:
             return False
         new = self._pending[self._stop_checked:]
         self._hb_sync_beat()
-        with global_timer.section("GBDT::StopCheck"):
+        with global_timer.section("GBDT::StopCheck", iteration=self.iter):
             nls = np.asarray(jax.device_get(
                 jnp.stack([p.tree.num_leaves for p in new])))
         self._stop_checked = len(self._pending)
@@ -518,7 +519,6 @@ class GBDT:
         if fn is None:
             meta = self.feature_meta
 
-            @jax.jit
             def fn(tree, bins, rate):
                 leaf = tree_leaf_bins(tree, bins, meta.num_bin,
                                       meta.missing_type, meta.default_bin,
@@ -527,6 +527,7 @@ class GBDT:
                                  tree.leaf_value[leaf] * rate,
                                  jnp.float32(0.0))
 
+            fn = timer.jit(timer.in_stage("score_update", fn))
             self._async_trav_fn[steps] = fn
         delta = fn(tree_dev, bins_dev, jnp.float32(rate))
         return score.at[k].add(delta)
@@ -577,7 +578,7 @@ class GBDT:
         # time DISPATCH only (a sync= barrier would serialize the very
         # pipeline this path exists to keep sync-free; device time shows
         # up in Tree::ToHost / GBDT::StopCheck at the batched fetches)
-        with global_timer.section("GBDT::Boosting"):
+        with global_timer.section("GBDT::Boosting", iteration=self.iter):
             grad, hess = self._gh_fn(self.score)
             if K == 1:
                 grad = grad[None, :]
@@ -622,13 +623,15 @@ class GBDT:
                 rng_key = jax.random.fold_in(
                     self._grow_rng, self.iter * K + k)
             # jaxlint: disable=JL005 — dispatch-only timing, see above
-            with global_timer.section("TreeLearner::Train"):
+            with global_timer.section("TreeLearner::Train",
+                                      iteration=self.iter):
                 tree_dev, leaf_id = self._grow(
                     self._train_bins(), gh, fmask,
                     self._cegb_penalty(), rng_key)
             rate = jnp.float32(self.shrinkage_rate)
             # jaxlint: disable=JL005 — dispatch-only timing, see above
-            with global_timer.section("GBDT::UpdateScore"):
+            with global_timer.section("GBDT::UpdateScore",
+                                      iteration=self.iter):
                 delta = self._leaf_delta(tree_dev.leaf_value,
                                          tree_dev.num_leaves, leaf_id,
                                          rate)
@@ -1137,7 +1140,7 @@ class GBDT:
                                                jnp.asarray(binv_h),
                                                train.num_used_features)
                 fetch, prepare = self._multival_hooks(train)
-                self._grow = jax.jit(make_tree_grower(
+                self._grow = timer.jit(make_tree_grower(
                     self.grower_cfg, self.feature_meta,
                     fetch_bin_column=fetch, prepare_split_hist=prepare,
                     prepare_is_pure=True))
@@ -1153,7 +1156,7 @@ class GBDT:
             self._inj = injected_collectives()
             hooks = make_injected_hooks()
             if hooks is not None:
-                self._grow = jax.jit(make_tree_grower(
+                self._grow = timer.jit(make_tree_grower(
                     self.grower_cfg, self.feature_meta, forced=forced,
                     bundle=self._bundle, **hooks))
             elif self.grower_cfg.row_sched == "level":
@@ -1164,7 +1167,7 @@ class GBDT:
                 from ..core.level_grower import (MAX_LEVEL_DEPTH,
                                                  make_level_grower)
                 if 1 <= self.grower_cfg.max_depth <= MAX_LEVEL_DEPTH:
-                    self._grow = jax.jit(
+                    self._grow = timer.jit(
                         make_level_grower(self.grower_cfg,
                                           self.feature_meta,
                                           bundle=self._bundle))
@@ -1176,11 +1179,11 @@ class GBDT:
                             f"tpu_level_handoff_depth={d0} exceeds "
                             f"MAX_LEVEL_DEPTH={MAX_LEVEL_DEPTH}; "
                             "clamping")
-                    self._grow = jax.jit(make_hybrid_grower(
+                    self._grow = timer.jit(make_hybrid_grower(
                         self.grower_cfg, self.feature_meta,
                         bundle=self._bundle, handoff_depth=d0))
             else:
-                self._grow = jax.jit(
+                self._grow = timer.jit(
                     make_tree_grower(self.grower_cfg, self.feature_meta,
                                      forced=forced, bundle=self._bundle))
         else:
@@ -1195,12 +1198,12 @@ class GBDT:
                 # biases are a traced argument so the host-side Newton
                 # update (ref: UpdatePositionBiasFactors) feeds back in
                 self._pos_bias = True
-                self._gh_fn = jax.jit(
-                    lambda s, b: obj.get_gradients(s[0], b))
+                gh_fn = lambda s, b: obj.get_gradients(s[0], b)
             elif K == 1:
-                self._gh_fn = jax.jit(lambda s: obj.get_gradients(s[0]))
+                gh_fn = lambda s: obj.get_gradients(s[0])
             else:
-                self._gh_fn = jax.jit(lambda s: obj.get_gradients(s))
+                gh_fn = lambda s: obj.get_gradients(s)
+            self._gh_fn = timer.jit(timer.in_stage("gradients", gh_fn))
         else:
             self._gh_fn = None
 
@@ -1406,7 +1409,7 @@ class GBDT:
                     bins_spec=mv_spec,
                     pre_fix=make_local_default_bin_fix(
                         dflt, self.num_bin_max))
-            self._grow_dist = jax.jit(grow)
+            self._grow_dist = timer.jit(grow)
         elif tl in ("data", "voting"):
             if bins_host is None:
                 bins_host = train.bins
@@ -1516,7 +1519,7 @@ class GBDT:
                                      gh[jnp.clip(_rm, 0), :],
                                      jnp.zeros((), gh.dtype))
                     return _base(bins_arr, gh_p, fmask, cegb, rng_key)
-            self._grow_dist = jax.jit(grow)
+            self._grow_dist = timer.jit(grow)
         else:  # feature-parallel
             if bins_host is None:
                 bins_host = train.bins
@@ -1553,7 +1556,7 @@ class GBDT:
                 meta_p = pad_feature_meta(self.feature_meta, Fp)
                 grow = make_feature_parallel_grower(self.grower_cfg,
                                                     meta_p, mesh)
-            self._grow_dist = jax.jit(grow)
+            self._grow_dist = timer.jit(grow)
         self._mesh = mesh
 
         def grow_wrapper(bins_unused, gh, fmask, cegb, rng_key=None):
@@ -1911,9 +1914,9 @@ class GBDT:
         HostTree.shrink stores in the model, so async runs, sync runs
         and replays stay bit-identical."""
         if self._async_delta_fn is None:
-            self._async_delta_fn = jax.jit(
-                lambda lv, nl, leaf, rate: jnp.where(
-                    nl > 1, lv[leaf] * rate, jnp.float32(0.0)))
+            self._async_delta_fn = timer.jit(timer.in_stage(
+                "score_update", lambda lv, nl, leaf, rate: jnp.where(
+                    nl > 1, lv[leaf] * rate, jnp.float32(0.0))))
         return self._async_delta_fn(lv, nl, leaf, rate)
 
     def _score_add(self, score, delta, k: int):
@@ -1922,12 +1925,10 @@ class GBDT:
         training-state buffer; donation lets XLA update it in place
         instead of holding both generations in HBM)."""
         if self._score_add_fn is None:
-            if self.config.tpu_donate_state:
-                self._score_add_fn = jax.jit(
-                    lambda s, d, kk: s.at[kk].add(d),
-                    donate_argnums=(0,))
-            else:
-                self._score_add_fn = jax.jit(lambda s, d, kk: s.at[kk].add(d))
+            self._score_add_fn = timer.jit(
+                timer.in_stage("score_update",
+                               lambda s, d, kk: s.at[kk].add(d)),
+                donate_argnums=(0,) if self.config.tpu_donate_state else ())
         return self._score_add_fn(score, delta, k)
 
     def _boost_from_average(self, k: int) -> float:
@@ -2206,8 +2207,8 @@ class GBDT:
         if gradients is None or hessians is None:
             for k in range(K):
                 init_scores[k] = self._boost_from_average(k)
-            with global_timer.section("GBDT::Boosting",
-                                      sync=lambda: grad):
+            with global_timer.section("GBDT::Boosting", sync=lambda: grad,
+                                      iteration=self.iter):
                 if self._pos_bias:
                     grad, hess = self._gh_fn(
                         self.score,
@@ -2303,12 +2304,13 @@ class GBDT:
                 rng_key = jax.random.fold_in(
                     self._grow_rng, self.iter * K + k)
             with global_timer.section("TreeLearner::Train",
-                                      sync=lambda: tree_dev.leaf_value):
+                                      sync=lambda: tree_dev.leaf_value,
+                                      iteration=self.iter):
                 tree_dev, leaf_id = self._grow(train_bins, gh, fmask,
                                                self._cegb_penalty(),
                                                rng_key)
             self._hb_sync_beat()
-            with global_timer.section("Tree::ToHost"):
+            with global_timer.section("Tree::ToHost", iteration=self.iter):
                 host = HostTree(jax.tree.map(np.asarray, tree_dev),
                                 self.train_set.used_feature_map)
 
@@ -2383,7 +2385,8 @@ class GBDT:
             # so sync, async and replayed models accumulate bit-identical
             # scores (see _leaf_delta)
             with global_timer.section("GBDT::UpdateScore",
-                                      sync=lambda: self.score):
+                                      sync=lambda: self.score,
+                                      iteration=self.iter):
                 if host.is_linear:
                     host.shrink(self.shrinkage_rate)
                     delta = jnp.asarray(
@@ -2399,7 +2402,8 @@ class GBDT:
                     self.score = self._score_add(self.score, delta, k)
             with global_timer.section(
                     "GBDT::UpdateValidScore",
-                    sync=lambda: [vd.score for vd in self.valid_sets]):
+                    sync=lambda: [vd.score for vd in self.valid_sets],
+                    iteration=self.iter):
                 for vd in self.valid_sets:
                     if host.is_linear:
                         vd.score = vd.score.at[k].add(

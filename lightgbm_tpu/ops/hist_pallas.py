@@ -43,6 +43,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import timer
 from ..utils.log import info_once as _log_once
 
 
@@ -157,11 +158,13 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     Fp = _pad_to(F, feature_tile)
     Rp = _pad_to(R, block_rows)
 
-    if Fp != F or Rp != R:
-        # dead feature rows produce columns sliced off below; padded rows
-        # carry gh = 0 so they accumulate nothing
-        bins_fm = jnp.pad(bins_fm, ((0, Fp - F), (0, Rp - R)))
-    gh_t = jnp.pad(gh, ((0, Rp - R), (0, Cp - Cin))).T    # [Cp, Rp]
+    with timer.stage("hist_gather"):
+        if Fp != F or Rp != R:
+            # dead feature rows produce columns sliced off below; padded
+            # rows carry gh = 0 so they accumulate nothing
+            bins_fm = jnp.pad(bins_fm, ((0, Fp - F), (0, Rp - R)))
+        bins_fm = bins_fm.astype(jnp.int32)
+        gh_t = jnp.pad(gh, ((0, Rp - R), (0, Cp - Cin))).T    # [Cp, Rp]
 
     grid = (Fp // feature_tile, Rp // block_rows)
     kernel = functools.partial(_hist_kernel, feature_tile=feature_tile,
@@ -182,7 +185,7 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(bins_fm.astype(jnp.int32), gh_t)
+    )(bins_fm, gh_t)
 
     # [Cp, Fp*Bp] -> [Fp, Bp, Cp] -> [F, num_bin, C]
     hist = out.reshape(Cp, Fp, Bp).transpose(1, 2, 0)
@@ -272,6 +275,8 @@ def hist_pallas_rm(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                   "using the einsum row-major kernel")
         return hist_rowmajor(bins_rm, gh, num_bin,
                              block_rows=block_rows, backend="einsum")
+    with timer.stage("hist_gather"):
+        bins_fm = bins_rm.T
     # jaxlint: disable=JL001 — interpret is a static Python flag
-    return _hist_pallas_impl(bins_rm.T, gh, num_bin, block_rows,
+    return _hist_pallas_impl(bins_fm, gh, num_bin, block_rows,
                              feature_tile, bool(interpret))

@@ -1,0 +1,254 @@
+"""The program's tracing (``lightgbm_tpu/utils/timer.py``): device stages
+and the stage map, host sections as profiler annotations and records."""
+import collections
+import contextlib
+import glob
+import os
+from unittest import mock
+
+import jax
+import jax.monitoring
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.utils import timer
+
+PARAMS = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+          "tpu_row_scheduling": "compact", "tpu_packed_bins": "true",
+          "tpu_async_boosting": "true"}
+# what does no work of its own: values the compiler materialises, and views
+NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+           "broadcast", "iota"}
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def table(seed=0, rows=3000, cols=10):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(rows, cols)).astype(np.float32)
+    y = (X[:, 0] * 2 + X[:, 1] ** 2 + rng.normal(size=rows) > 1)
+    return X, y.astype(np.float32)
+
+
+@contextlib.contextmanager
+def fresh_compiles():
+    """jax leaves metadata out of its persistent cache's key, so a cache
+    filled before the scopes existed (or under ``test_null_stages``) would
+    hand back an executable with other scopes than the program traced."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def grown(steps=3):
+    X, y = table()
+    with fresh_compiles():
+        booster = lgb.Booster(dict(PARAMS), lgb.Dataset(X, label=y))
+        for _ in range(steps):
+            booster.update()
+    return booster
+
+
+@pytest.fixture(scope="module")
+def booster():
+    """A compact-grower booster on packed bins and the asynchronous path,
+    kept alive so that its programs stay in the stage map."""
+    booster = grown()
+    g = booster._engine.grower_cfg
+    assert g.row_sched == "compact" and g.packed_cols == 10
+    assert booster._engine._async_on()
+    return booster
+
+
+@pytest.fixture(scope="module")
+def compile_events():
+    seen = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **_: seen.append(event))
+    return seen
+
+
+def test_stage_map_holds_every_stage_of_the_path(booster):
+    found = collections.Counter(timer.stage_map().values())
+    assert found
+    assert set(timer.STAGES) <= set(found)
+
+
+def test_stage_map_leaves_few_working_instructions_unnamed(booster):
+    grow = booster._engine._grow
+    working = [i for i in timer.instructions(grow.compiled_text())
+               if i[1] not in NO_WORK]
+    unnamed = [i for i in working if i[2] is None]
+    assert len(working) > 500
+    assert len(unnamed) < 0.10 * len(working), collections.Counter(
+        i[1] for i in unnamed).most_common(8)
+
+
+def test_stage_map_compiles_nothing(booster, compile_events):
+    before = compile_events.count(COMPILE_EVENT)
+    assert timer.stage_map()
+    assert compile_events.count(COMPILE_EVENT) == before
+
+
+def test_stage_map_keeps_no_buffer_alive(booster):
+    held = jax.tree.leaves(booster._engine._grow.last)
+    assert held and not any(isinstance(x, jax.Array) for x in held)
+
+
+def test_stage_map_forgets_a_dead_engine():
+    import gc
+    import weakref
+    throwaway = grown(steps=1)
+    grow = weakref.ref(throwaway._engine._grow)
+    assert grow() in timer._programs
+    del throwaway
+    gc.collect()
+    assert grow() is None and None not in set(timer._programs)
+
+
+class Text:
+    """A planted program for the stage map."""
+
+    def __init__(self, text):
+        self.text = text
+
+    def compiled_text(self):
+        return self.text
+
+
+def line(name, shape, opcode, operands, scope=None):
+    meta = f', metadata={{op_name="jit(f)/{scope}/{opcode}"}}' if scope \
+        else ""
+    return f"  {name} = {shape} {opcode}({operands}){meta}"
+
+
+def test_shared_name_is_ambiguous_and_the_shape_settles_it():
+    one = Text(line("%fusion.1", "u32[64]{0:T(128)}", "fusion", "%p",
+                    "lgbm.partition_fetch/while/body/lgbm.hist_gather"))
+    two = Text(line("%fusion.1", "f32[64,3]{1,0}", "fusion", "%p",
+                    "lgbm.gradients"))
+    with mock.patch.object(timer, "_programs", [one, two]):
+        found = timer.stage_map()
+    assert found["%fusion.1"] == timer.AMBIGUOUS
+    assert found["%fusion.1 = u32[64]{0:T(128)}"] == "hist_gather"
+    assert found["%fusion.1 = f32[64,3]{1,0}"] == "gradients"
+
+
+def test_name_outside_every_stage_in_one_program_is_ambiguous():
+    one = Text(line("%copy.2", "f32[8]{0}", "copy", "%p", "lgbm.tree_update"))
+    two = Text(line("%copy.2", "f32[8]{0}", "copy", "%p"))
+    with mock.patch.object(timer, "_programs", [one, two]):
+        assert timer.stage_map() == {"%copy.2": timer.AMBIGUOUS}
+
+
+def test_unstaged_instruction_takes_its_operands_stage():
+    text = "\n".join([
+        line("%p", "s32[8]{0}", "parameter", "0"),
+        line("%sort.1", "(s32[8]{0}, s32[8]{0})", "sort", "%p, %p",
+             "lgbm.partition_order"),
+        line("%copy.5", "s32[8]{0}", "copy", "%sort.1"),
+        line("%while.1", "(s32[], s32[8]{0})", "while", "%p")])
+    assert [(i[0], i[2]) for i in timer.instructions(text)] == [
+        ("%p", None), ("%sort.1", "partition_order"),
+        ("%copy.5", "partition_order"), ("%while.1", None)]
+
+
+def test_unknown_stage_raises():
+    with pytest.raises(ValueError, match="unknown stage"):
+        timer.stage("histogram")
+
+
+def test_null_stages_grow_the_same_model(booster):
+    """The scopes are metadata and nothing else."""
+    with mock.patch.object(timer, "stage",
+                           lambda name: contextlib.nullcontext()):
+        plain = grown()
+        assert not any(i[2] for i in timer.instructions(
+            plain._engine._grow.compiled_text()))
+    assert plain.model_to_string() == booster.model_to_string()
+
+
+def test_capture_holds_program_spans_inside_the_callers(tmp_path):
+    from jax.profiler import ProfileData
+    X, y = table(seed=1)
+    b = lgb.Booster(dict(PARAMS), lgb.Dataset(X, label=y))
+    b.update()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation("caller.window"):
+            for _ in range(3):
+                b.update()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for ln in plane.lines for e in ln.events]
+    (_, lo, hi), = [e for e in events if e[0] == "caller.window"]
+    train = [e for e in events if e[0] == "lgbm.TreeLearner::Train"]
+    assert len(train) == 3
+    assert all(lo <= a and b_ <= hi for _, a, b_ in train)
+    assert {"lgbm.GBDT::Boosting", "lgbm.GBDT::UpdateScore"} <= {
+        e[0] for e in events}
+
+
+def test_records_carry_parent_and_iteration():
+    t = timer.Timer()
+    with t.section("outer", iteration=7):
+        with t.section("inner"):
+            pass
+    with t.section("alone"):
+        pass
+    inner, outer, alone = t.records
+    assert (inner.name, inner.parent, inner.iteration) == ("inner", "outer", 7)
+    assert (outer.name, outer.parent, outer.iteration) == ("outer", None, 7)
+    assert (alone.parent, alone.iteration) == (None, None)
+    assert outer.start <= inner.start <= inner.end <= outer.end <= alone.start
+
+
+def test_training_records_every_iteration(booster):
+    done = [r for r in timer.global_timer.records
+            if r.name == "TreeLearner::Train"]
+    assert len(done) >= 3
+    assert all(r.parent is None and r.iteration is not None for r in done)
+
+
+def test_records_stay_at_their_bound_and_totals_go_on():
+    t = timer.Timer()
+    for _ in range(timer.MAX_RECORDS + 10):
+        with t.section("tick"):
+            pass
+    assert len(t.records) == timer.MAX_RECORDS
+    assert t._count["tick"] == timer.MAX_RECORDS + 10
+    assert "tick" in t.table()
+
+
+def test_table_fills_with_timetag_off():
+    t = timer.Timer()
+    t.enabled = False
+    with t.section("GBDT::Boosting"):
+        pass
+    header, row = t.table().splitlines()
+    assert header.split() == ["section", "total(s)", "count", "mean(ms)"]
+    assert row.split()[0] == "GBDT::Boosting" and row.split()[2] == "1"
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_sync_blocks_only_when_enabled(enabled):
+    t = timer.Timer()
+    t.enabled = enabled
+    asked = []
+    with t.section("step", sync=lambda: asked.append(1) or ()):
+        pass
+    assert bool(asked) == enabled
