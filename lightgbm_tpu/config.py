@@ -324,8 +324,9 @@ _reg("tpu_rows_per_block", int, 1024, ())    # row tile for histogram kernels
 # runs differ when enabled; balanced/query bagging stay host-side.
 _reg("tpu_device_bagging", bool, False, ())
 # bit-pack 4 uint8 bins per uint32 word for the compact scheduler's
-# per-leaf row gathers (TPU gathers cost per element; packing quarters
-# them). auto = off until device-measured; true/false force. Requires
+# per-leaf row gathers (a TPU gather pays per index, by where its
+# operand lives, PERF.md §6 PR 26; a packed row is 17 words, not 67
+# bytes). auto = off until device-measured; true/false force. Requires
 # all (possibly bundled) bins to fit uint8.
 _reg("tpu_packed_bins", str, "auto", ())     # auto | true | false
 _reg("tpu_donate_state", bool, True, ())     # donate training state buffers

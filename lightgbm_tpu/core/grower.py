@@ -116,9 +116,11 @@ class GrowerConfig:
     interaction_groups: Optional[tuple] = None
     # >0: compact-mode bins arrive bit-packed — uint32 [R, ceil(F/4)]
     # holding this many logical uint8 columns (little-endian byte k =
-    # column 4w+k). TPU gathers cost per ELEMENT, so packing 4 bins per
-    # word quarters the per-leaf row-gather cost; the kernel unpacks with
-    # shifts in registers after the gather.
+    # column 4w+k). A TPU gather pays per INDEX, at a price set by where
+    # its operand lives (VMEM or HBM) and how many strided reads one
+    # index takes, not per element fetched (PERF.md §6, PR 26): packing
+    # makes a row 17 words instead of 67 bytes and the table a quarter
+    # the width; the kernel unpacks with shifts after the gather.
     packed_cols: int = 0
 
 
@@ -689,11 +691,13 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
         if compact:
             sizes = _bucket_sizes(R, cfg.min_bucket)
             sizes_arr = jnp.asarray(sizes, jnp.int32)
-            # feat_sharded/multival partitions read the fetched column
-            # vector instead of the bins matrix
-            flat_ok = (R * (Wp if packed else Fp) < 2 ** 31
-                       and not feat_sharded)
-            bins_flat = bins_t.reshape(-1) if flat_ok else None
+            # the partition reads ONE column of the table per split:
+            # column-major view of the same buffer, made once outside
+            # the split loop (on TPU the [R, W] table already lies
+            # word-major, so the transpose is a bitcast). feat_sharded
+            # and multival partitions read the fetched column vector
+            # instead of the bins matrix
+            bins_cm = None if feat_sharded else bins_t.T
 
             def unpack_rows(w):
                 """uint32 [S, Wp] packed words -> int32 [S, Fp] bins."""
@@ -724,25 +728,20 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                         if feat_sharded:
                             col = jnp.take(colv, seg).astype(jnp.int32)
                         else:
+                            # the split column as one dense [R] slice,
+                            # then the leaf's rows out of THAT: a gather
+                            # pays per index by where its operand lives,
+                            # and an [R] column fits on chip where the
+                            # table does not (PERF.md §6, PR 26)
                             col_idx = b_group[f] if bundled else f
+                            colw = lax.dynamic_index_in_dim(
+                                bins_cm, col_idx // 4 if packed else col_idx,
+                                0, keepdims=False)
+                            col = colw[seg]
                             if packed:
-                                word_i = col_idx // 4
-                                shift = 8 * (col_idx % 4)
-                                if flat_ok:
-                                    w = bins_flat[seg * Wp + word_i]
-                                else:
-                                    w = jnp.take(
-                                        jnp.take(bins_t, seg, axis=0),
-                                        word_i, axis=1)
-                                col = ((w >> shift.astype(w.dtype)) &
-                                       w.dtype.type(0xFF)).astype(jnp.int32)
-                            elif flat_ok:
-                                col = bins_flat[seg * Fp + col_idx].astype(
-                                    jnp.int32)
-                            else:
-                                col = jnp.take(
-                                    jnp.take(bins_t, seg, axis=0), col_idx,
-                                    axis=1).astype(jnp.int32)
+                                shift = (8 * (col_idx % 4)).astype(col.dtype)
+                                col = (col >> shift) & col.dtype.type(0xFF)
+                            col = col.astype(jnp.int32)
                             if bundled:
                                 col = decode_bin(col, f)
                         go_left = _go_left_bins(

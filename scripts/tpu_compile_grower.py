@@ -1,0 +1,114 @@
+#!/usr/bin/env python
+"""Compile the compact grower for a v5e that is described, not attached, and
+print what the compiler did with the table: minutes in the sandbox, no chip.
+
+    JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \\
+        python scripts/tpu_compile_grower.py --rows 200000 [--unpacked] \\
+        [--text /root/scratch/grow.hlo.txt]
+
+It prints the compile seconds, the compiler's memory analysis (the
+temporaries are the block the chip reports as ``peak_bytes_reserved``) and,
+for one stage (``--stage``, default ``partition_fetch``), every gather with
+its operand's shape, layout and memory space: ``S(1)`` in a layout is VMEM,
+none is HBM. It also lists every ``copy`` and ``bitcast`` of an array of the
+table's size, with the computation it sits in: a copy inside a
+``branch_*`` computation is paid once a split. Nothing runs, so it gives no
+time. Layouts change with the row count (at 200,000 rows the row gather
+reads a row-major copy, at 2 M the word-major parameter): what a PR claims
+of the cell it checks at ``--rows 2000000`` (six minutes).
+"""
+import argparse
+import math
+import os
+import re
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from lightgbm_tpu.core.grower import GrowerConfig, make_tree_grower
+from lightgbm_tpu.ops.split import FeatureMeta
+from lightgbm_tpu.utils import timer
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[^\s(]+) \(.*\{\s*$")
+_DIMS = re.compile(r"\[([\d,]+)\]")
+
+
+def report(text, table_elements, stage):
+    """The lines of the compiled module that say where the stage's gathers
+    read from and what was copied."""
+    shape_of, comp = {}, None
+    for line in text.splitlines():
+        started = _COMPUTATION.match(line)
+        if started:
+            comp = started.group(1)
+        m = timer._INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, shape, opcode, operands = m.groups()
+        shape_of[name] = shape
+        op = timer._OP_NAME.search(line)
+        if (opcode == "fusion" and "kind=kCustom" in line and op and
+                op.group(1).endswith(f"lgbm.{stage}/gather")):
+            operand = timer._OPERAND.findall(operands)[0]
+            print(f"gather {name} = {shape}  in {comp}\n"
+                  f"    operand {operand} = {shape_of.get(operand)}")
+        elif opcode in ("copy", "bitcast") and not shape.startswith("("):
+            dims = _DIMS.search(shape)
+            if dims and math.prod(
+                    int(d) for d in dims.group(1).split(",")) == table_elements:
+                print(f"{opcode} {name} = {shape}  in {comp}")
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, default=200000)
+    ap.add_argument("--unpacked", action="store_true")
+    ap.add_argument("--stage", default="partition_fetch")
+    ap.add_argument("--text", help="write the compiled module's text here")
+    args = ap.parse_args()
+    # a compile for a described chip is written to the persistent cache
+    # and can never be read back from it
+    jax.config.update("jax_enable_compilation_cache", False)
+    F, B = 67, 255                      # `criteo-share`'s shape
+    meta = FeatureMeta(num_bin=jnp.full((F,), B, jnp.int32),
+                       missing_type=jnp.zeros((F,), jnp.int32),
+                       default_bin=jnp.zeros((F,), jnp.int32),
+                       is_categorical=jnp.zeros((F,), bool))
+    # what `criteo-share.train` resolves its `auto` parameters to on a v5e
+    cfg = GrowerConfig(num_leaves=255, num_bin=B,
+                       row_sched="compact", hist_rm_backend="pallas",
+                       partition_mode="auto", min_bucket=2048,
+                       packed_cols=0 if args.unpacked else F)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    width, dtype = (F, jnp.uint8) if args.unpacked else \
+        ((F + 3) // 4, jnp.uint32)
+    bins = jax.ShapeDtypeStruct((args.rows, width), dtype, sharding=chip)
+    gh = jax.ShapeDtypeStruct((args.rows, 3), jnp.float32, sharding=chip)
+    t0 = time.time()
+    compiled = jax.jit(make_tree_grower(cfg, meta)).lower(bins, gh).compile()
+    # jaxlint: disable=JL005 — times the compile, which returns when done;
+    # nothing is dispatched
+    print(f"compile_s {time.time() - t0:.1f}")
+    mem = compiled.memory_analysis()
+    for field in ("temp_size_in_bytes", "argument_size_in_bytes",
+                  "output_size_in_bytes"):
+        print(f"{field} {getattr(mem, field)} "
+              f"({getattr(mem, field) / 2 ** 20:.1f} MiB)")
+    text = compiled.as_text()
+    if args.text:
+        with open(args.text, "w") as f:
+            f.write(text)
+    report(text, args.rows * width, args.stage)
+
+
+if __name__ == "__main__":
+    main()

@@ -6,6 +6,8 @@ triangle the reference closes between its indexed histogram construction and
 a naive full scan (ref: src/treelearner/serial_tree_learner.cpp:368-386
 smaller-child scheduling, src/io/data_partition.hpp DataPartition).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -13,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.config import Config
+from lightgbm_tpu.io.bundling import find_bundles, pack_bins
 from lightgbm_tpu.io.dataset_core import BinnedDataset
 from lightgbm_tpu.ops.split import FeatureMeta, SplitHyperParams
 from lightgbm_tpu.core.grower import GrowerConfig, make_tree_grower
@@ -133,3 +136,141 @@ def test_compact_monotone(rng):
     full = _grow(ds, gh, 12, hp, "full", monotone=mono)
     comp = _grow(ds, gh, 12, hp, "compact", monotone=mono)
     _assert_same_tree(full, comp, 12)
+
+
+# ---- the partition's column fetch: packed, unpacked and full agree --------
+
+def _pack_words(rm):
+    """uint8 [R, F] -> uint32 [R, ceil(F/4)], byte k of word w = column
+    4w+k (the layout models/gbdt.py uploads for tpu_packed_bins)."""
+    R, F = rm.shape
+    W = (F + 3) // 4
+    full = np.zeros((R, W * 4), np.uint8)
+    full[:, :F] = rm
+    return full.view(np.uint32).reshape(R, W)
+
+
+def _grow_with_order(gcfg, meta, bundle, bins, gh):
+    """(tree, leaf_id, order): ``order`` is the split loop's state, which
+    ``grow`` does not return; it is read off the loop's outputs in the
+    jaxpr as the one int32 [R] that is a permutation of the rows."""
+    closed, shapes = jax.make_jaxpr(
+        make_tree_grower(gcfg, meta, bundle=bundle), return_shape=True)(
+            bins, gh)
+    loop = [e for e in closed.jaxpr.eqns
+            if e.primitive.name in ("scan", "while")][-1]
+    R = gh.shape[0]
+    extra = [v for v in loop.outvars
+             if v.aval.shape == (R,) and v.aval.dtype == jnp.int32]
+    both = closed.jaxpr.replace(
+        outvars=list(closed.jaxpr.outvars) + extra)
+    n_out = len(closed.jaxpr.outvars)
+    out = jax.jit(lambda b, g: jax.core.eval_jaxpr(
+        both, closed.consts, b, g))(bins, gh)
+    tree, leaf_id = jax.tree.unflatten(jax.tree.structure(shapes),
+                                       out[:n_out])
+    perms = [np.asarray(o) for o in out[n_out:]
+             if np.array_equal(np.sort(np.asarray(o)), np.arange(R))]
+    assert len(perms) == 1
+    return jax.tree.map(np.asarray, tree), np.asarray(leaf_id), perms[0]
+
+
+def _odd_columns(rng):
+    # 6 columns -> 2 words, the second part-filled; the signal sits in
+    # its last column
+    X = rng.normal(size=(3000, 6))
+    y = 2.0 * X[:, 5] + np.sin(3 * X[:, 4]) + 0.2 * X[:, 0]
+    return X, y, {}
+
+
+def _efb(rng):
+    # six mutually exclusive one-hots bundle into one physical column
+    n = 3000
+    cat = rng.integers(0, 6, size=n)
+    X = np.zeros((n, 11))
+    X[np.arange(n), cat] = 1.0
+    X[:, 6:] = rng.normal(size=(n, 5))
+    y = (cat % 2) * 2.0 + (cat == 3) + 0.3 * X[:, 7]
+    return X, y, {"bundle": True}
+
+
+def _clipped_start(rng):
+    # 2500 rows, buckets [2500, 2048]: a right child in the 2048 bucket
+    # starts past R - P, so start_c is clipped and delta > 0
+    X = rng.normal(size=(2500, 7))
+    y = X[:, 6] + 0.5 * X[:, 1] * X[:, 2]
+    return X, y, {"min_bucket": 2048}
+
+
+@pytest.mark.parametrize("partition_mode", ["scatter", "sort"])
+@pytest.mark.parametrize("case", [_odd_columns, _efb, _clipped_start])
+def test_partition_fetch_packed_unpacked_full_agree(rng, case,
+                                                    partition_mode):
+    """One column out of the table, then the leaf's rows out of the
+    column: ``order``, ``leaf_id`` and the tree are the same, element for
+    element, on packed words, on plain uint8 bins and (tree and
+    ``leaf_id``) on the full-row grower that keeps no ``order``."""
+    X, y, opt = case(rng)
+    L = 16
+    ds = BinnedDataset.from_matrix(
+        X, Config({"num_leaves": L, "min_data_in_leaf": 5}), label=y)
+    mappers = ds.used_bin_mappers()
+    meta = FeatureMeta.from_mappers(mappers)
+    B = int(max(m.num_bin for m in mappers))
+    phys, bundle = ds.bins, None                      # [F, R]
+    if opt.get("bundle"):
+        info = find_bundles(ds.bins, np.asarray(
+            [m.num_bin for m in mappers], np.int64), max_conflict_rate=0.0)
+        B = int(max(B, info.group_num_bin.max()))
+        info.build_gather_map(B)
+        phys = pack_bins(ds.bins, info)               # [G, R]
+        bundle = dict(gather_map=info.gather_map, group=info.group,
+                      offset=info.offset, default_bin=info.default_bin,
+                      num_bin=info.num_bin, num_groups=info.num_groups)
+        assert phys.shape[0] < ds.bins.shape[0]
+    grad = -(y.astype(np.float32))
+    gh = jnp.asarray(np.stack([grad, np.ones_like(grad),
+                               np.ones_like(grad)], axis=1))
+    rm = np.ascontiguousarray(phys.T).astype(np.uint8)
+    base = GrowerConfig(
+        num_leaves=L, num_bin=B, hparams=SplitHyperParams(min_data_in_leaf=5),
+        hist_backend="scatter", block_rows=512, hist_dtype="float32",
+        hist_rm_backend="scatter", partition_mode=partition_mode,
+        min_bucket=opt.get("min_bucket", 256))
+    compact = dataclasses.replace(base, row_sched="compact")
+    t_u, l_u, o_u = _grow_with_order(compact, meta, bundle,
+                                     jnp.asarray(rm), gh)
+    t_p, l_p, o_p = _grow_with_order(
+        dataclasses.replace(compact, packed_cols=rm.shape[1]), meta, bundle,
+        jnp.asarray(_pack_words(rm)), gh)
+    assert rm.shape[1] % 4 != 0
+    np.testing.assert_array_equal(o_p, o_u)
+    np.testing.assert_array_equal(l_p, l_u)
+    for a, b in zip(jax.tree.leaves(t_p), jax.tree.leaves(t_u)):
+        np.testing.assert_array_equal(a, b)
+    t_f, l_f = jax.jit(make_tree_grower(
+        dataclasses.replace(base, row_sched="full"), meta, bundle=bundle))(
+            jnp.asarray(phys), gh)
+    np.testing.assert_array_equal(l_p, np.asarray(l_f))
+    for name in ("split_feature", "threshold_bin", "default_left",
+                 "left_child", "right_child", "num_leaves"):
+        np.testing.assert_array_equal(getattr(t_p, name),
+                                      np.asarray(getattr(t_f, name)))
+    np.testing.assert_allclose(t_p.leaf_value, np.asarray(t_f.leaf_value),
+                               rtol=1e-5)
+    feats = t_p.split_feature[:int(t_p.num_leaves) - 1]
+    if case is _odd_columns:
+        # a split on the last word's columns (4, 5 of 6)
+        assert (feats >= 4).any()
+    if case is _efb:
+        # a split on a feature that shares its physical column
+        group = np.asarray(bundle["group"])
+        shared = np.bincount(group)[group] > 1
+        assert shared[feats].any()
+    if case is _clipped_start:
+        # the root's right child is split again, in the 2048 bucket, and
+        # starts where the left child ends: past R - P
+        lc, rc = int(t_p.left_child[0]), int(t_p.right_child[0])
+        n_left = (t_p.internal_count[lc] if lc >= 0
+                  else t_p.leaf_count[~lc])
+        assert rc >= 0 and n_left > X.shape[0] - 2048

@@ -2,6 +2,7 @@
 and the stage map, host sections as profiler annotations and records."""
 import collections
 import contextlib
+import dataclasses
 import glob
 import os
 from unittest import mock
@@ -110,6 +111,47 @@ def test_stage_map_forgets_a_dead_engine():
     del throwaway
     gc.collect()
     assert grow() is None and None not in set(timer._programs)
+
+
+def equations(jaxpr, scope=""):
+    """Every equation of a jaxpr and of the jaxprs inside it, each with the
+    scopes it sits under, the enclosing equations' included."""
+    for eqn in jaxpr.eqns:
+        here = f"{scope}/{eqn.source_info.name_stack}"
+        yield eqn, here
+        for param in eqn.params.values():
+            for inner in param if isinstance(param, (tuple, list)) \
+                    else (param,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from equations(inner, here)
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_partition_fetch_indexes_a_column_never_the_table(booster, packed):
+    """The partition takes the split's column out of the table as a slice
+    and the leaf's rows out of that column: a gather whose operand is the
+    table pays HBM's price an index for one word (PERF.md, PR 26)."""
+    from lightgbm_tpu.core.grower import make_tree_grower
+    engine = booster._engine
+    R, F = 3000, engine.grower_cfg.packed_cols
+    cfg = engine.grower_cfg if packed else dataclasses.replace(
+        engine.grower_cfg, packed_cols=0)
+    bins = jax.ShapeDtypeStruct((R, (F + 3) // 4), np.uint32) if packed \
+        else jax.ShapeDtypeStruct((R, F), np.uint8)
+    closed = jax.make_jaxpr(make_tree_grower(cfg, engine.feature_meta))(
+        bins, jax.ShapeDtypeStruct((R, 3), np.float32))
+    fetches = 0
+    for eqn, scope in equations(closed.jaxpr):
+        name = eqn.primitive.name
+        if name == "gather" and "lgbm.partition_fetch" in scope:
+            fetches += 1
+            assert eqn.invars[0].aval.size <= R, (scope, eqn.invars[0].aval)
+        if name == "reshape":
+            flat = eqn.outvars[0].aval
+            assert not (flat.ndim == 1 and flat.size == bins.size and
+                        flat.dtype == bins.dtype), scope
+    assert fetches
 
 
 class Text:
