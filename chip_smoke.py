@@ -236,8 +236,11 @@ F32_ACC_BOUND = 50.0
 
 
 def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
-    """hist_pallas_rm (f32 bf16-triple, bf16, int8) at the smoke's shape
-    and hist_level (f32, int8) at the depth-10 level shape: lowered for
+    """hist_pallas_rm (f32 bf16-triple, bf16, int8) and hist_pallas_words
+    (f32, int8: the packed words the training leg's grower hands it, 7
+    words of 28 columns, so the word axis ends inside the kernel's 8-word
+    tile) at the smoke's shape and hist_level (f32, int8) at the depth-10
+    level shape: lowered for
     this backend, shown to hold a Mosaic call, run, and compared with
     exact host sums (the scatter formulations run beside them for the
     record). Every variant is reported before a failure is raised."""
@@ -246,7 +249,7 @@ def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
 
     from lightgbm_tpu.core.level_grower import hist_level_scatter
     from lightgbm_tpu.ops.hist_level_pallas import hist_level
-    from lightgbm_tpu.ops.hist_pallas import hist_pallas_rm
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_rm, hist_pallas_words
     from lightgbm_tpu.ops.histogram import hist_scatter
 
     B = int(eng.grower_cfg.num_bin)
@@ -294,6 +297,15 @@ def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
     run("hist_pallas_rm/f32", rm, (bins_rm, gh), refs["f32"], ref_abs)
     run("hist_pallas_rm/bf16", rm, (bins_rm, gh_bf16), refs["bf16"], ref_abs)
     run("hist_pallas_rm/int8", rm, (bins_rm, gh_i8), refs["int8"], None)
+    F = bins_host.shape[1]
+    padded = np.zeros((R, -(-F // 4) * 4), np.uint8)
+    padded[:, :F] = bins_host           # byte k of word w = column 4w + k
+    words_cm = jnp.asarray(np.ascontiguousarray(padded.view(np.uint32).T))
+    words = functools.partial(hist_pallas_words, num_bin=B, num_cols=F,
+                              block_rows=block_rows)
+    run("hist_pallas_words/f32", words, (words_cm, gh), refs["f32"], ref_abs)
+    run("hist_pallas_words/int8", words, (words_cm, gh_i8), refs["int8"],
+        None)
 
     n_nodes = sizes.level_nodes
     rows = np.arange(R, dtype=np.uint32)

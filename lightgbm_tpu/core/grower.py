@@ -120,7 +120,9 @@ class GrowerConfig:
     # its operand lives (VMEM or HBM) and how many strided reads one
     # index takes, not per element fetched (PERF.md §6, PR 26): packing
     # makes a row 17 words instead of 67 bytes and the table a quarter
-    # the width; the kernel unpacks with shifts after the gather.
+    # the width. The Pallas histogram kernel reads the gathered words
+    # and takes each byte out in VMEM; the other backends get them
+    # unpacked with shifts after the gather.
     packed_cols: int = 0
 
 
@@ -204,6 +206,20 @@ def _set_rows2(arr, idx_a, idx_b, row_a, row_b, cond, fallback=None):
         fallback = arr[idx2]
     return arr.at[idx2].set(jnp.where(cond, upd2, fallback))
 
+
+def _set_slots2(pool, idx_a, idx_b, slot_a, slot_b, cond):
+    """``_set_rows2`` for the histogram pool [L, F, B, 3], a slot at a
+    time as slices. The compiler takes a gather's or a scatter's operand
+    for read whole: on the chip it then fetched all 56 MB of the pool into
+    VMEM and wrote them back once a split, 17 ms an iteration, as soon as
+    there was room for it (PERF.md §6, PR 30). A dynamic slice tells it
+    that a split touches two slots. Each write keeps its fallback read
+    (see the note where the pool is written)."""
+    for idx, slot in ((idx_a, slot_a), (idx_b, slot_b)):
+        old = lax.dynamic_index_in_dim(pool, idx, 0, keepdims=False)
+        pool = lax.dynamic_update_index_in_dim(
+            pool, jnp.where(cond, slot, old), idx, 0)
+    return pool
 
 
 def _bucket_sizes(num_rows: int, min_bucket: int) -> list:
@@ -699,8 +715,24 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             # instead of the bins matrix
             bins_cm = None if feat_sharded else bins_t.T
 
+            # packed words go to the Pallas kernel as the table stores
+            # them, word-major, and it takes a column's byte out in VMEM;
+            # the backends with no kernel of their own (einsum, scatter:
+            # the CPU's) get the int32 [S, Fp] rows unpack_rows makes,
+            # 4x the bytes of the words and a transpose away from the
+            # kernel's layout
+            words_kernel = packed and cfg.hist_rm_backend == "pallas"
+            if words_kernel:
+                from ..ops.hist_pallas import hist_pallas_words
+                hist_leaf = functools.partial(
+                    hist_pallas_words, num_bin=B, num_cols=Fp,
+                    block_rows=cfg.block_rows, dtype=cfg.hist_dtype)
+            else:
+                hist_leaf = hist_rm
+
             def unpack_rows(w):
-                """uint32 [S, Wp] packed words -> int32 [S, Fp] bins."""
+                """uint32 [S, Wp] packed words -> int32 [S, Fp] bins, for
+                the histogram backends that read rows of bins."""
                 parts = [(w >> w.dtype.type(8 * k)) & w.dtype.type(0xFF)
                          for k in range(4)]
                 return jnp.stack(parts, axis=2).reshape(
@@ -798,6 +830,10 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                         if mv_mode:
                             from ..ops.hist_multival import take_rows
                             blk = take_rows(bins_t, idx)
+                        elif words_kernel:
+                            # [Wp, S]: the gather already writes its
+                            # result word-major on the chip
+                            blk = jnp.take(bins_t, idx, axis=0).T
                         elif packed:
                             # gather packed words (4x fewer elements), unpack
                             # with shifts after the gather
@@ -810,7 +846,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                              (pos < delta + rows)).astype(ghg.dtype)
                         ghw = ghg * w[:, None]
                     with timer.stage("hist_kernel"):
-                        h = hist_rm(blk, ghw)
+                        h = hist_leaf(blk, ghw)
                         if local_pool:
                             return h, jnp.sum(ghw.astype(hist_dtype), axis=0)
                     return h
@@ -869,9 +905,12 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             leaf_id0 = jnp.zeros(R, jnp.int32)
             root_ctx = (root_g, root_h, root_c, root_out)
             if compact:
-                root_bins = unpack_rows(bins_t) if packed else bins_t
+                # the words kernel reads the table in place
+                root_bins = (bins_t.T if words_kernel else
+                             unpack_rows(bins_t) if packed else bins_t)
                 with timer.stage("hist_kernel"):
-                    hist_root = reduce_hist(hist_rm(root_bins, gh), root_ctx)
+                    hist_root = reduce_hist(hist_leaf(root_bins, gh),
+                                            root_ctx)
             else:
                 with timer.stage("hist_kernel"):
                     hist_root = reduce_hist(hist_fn(bins_t, gh), root_ctx)
@@ -1338,8 +1377,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     # read XLA lost the in-place pattern and double-copied
                     # the whole [L, F, B, 3] pool every split (2x 21 MB at
                     # the bench geometry); don't redo it.
-                    hist = _set_rows2(state.hist, l, new_leaf,
-                                      hist_left, hist_right, proceed)
+                    hist = _set_slots2(state.hist, l, new_leaf,
+                                       hist_left, hist_right, proceed)
 
             # ---- local-sums channel (voting): children's LOCAL totals --
             if local_pool:

@@ -20,10 +20,17 @@ multiples of (sublane, lane) = (8, 128) for 32-bit types fail to lower):
 - the channel axis C=3 (grad, hess, count) is padded to 8 sublanes
   (f32) / 32 (int8) — the dead rows multiply zeros and are sliced off;
 - the bins tile is feature-major [FT, RB] with FT a multiple of 8 and
-  the row block a multiple of 128. Row-major [S, F] inputs (the compact
-  scheduler's gathered-leaf layout) are transposed on entry — one cheap
-  XLA u8 transpose (~2 bytes/row/feature of HBM traffic) buys a
-  tile-legal lane-aligned row axis.
+  the row block a multiple of 128. Row-major uint8/int32 [S, F] inputs
+  (``hist_pallas_rm``: the compact scheduler's gathered leaf on unpacked
+  bins) are transposed, padded and widened to int32 on entry: 4 bytes a
+  bin. Bit-packed rows (``hist_pallas_words``: the compact scheduler on
+  packed bins) arrive as the uint32 words the table stores, word-major
+  [W, S], and are read in place, unpadded; the tile is [8, RB] words,
+  32 features, and the kernel takes a feature's byte out of its word in
+  VMEM with a shift and a mask — 1 byte a bin, no unpacked copy of the
+  rows, and at the root no copy of the table at all. Both entries run one
+  kernel body (``_hist_kernel``), which differs only in how it fetches a
+  feature's row from the tile.
 
 Gradients/hessians enter pre-masked by leaf (gh rows of other leaves are
 zero), so a leaf histogram is one pass over the row blocks; the sibling
@@ -55,14 +62,36 @@ def default_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
+def _row_of_bins(block, f):
+    """Feature ``f`` of a feature-major bins tile int32 [FT, RB]."""
+    return lax.slice_in_dim(block, f, f + 1, axis=0)
+
+
+def _row_of_words(block, f):
+    """Feature ``f`` of a word-major tile of packed words uint32 [WT, RB]:
+    byte ``f % 4`` of word ``f // 4``, as int32. The bitcast is free in
+    registers (outside the kernel it is a copy of the operand); after it
+    the shift is arithmetic and drags the sign down from the top byte,
+    and the mask leaves the byte either way."""
+    word = lax.bitcast_convert_type(
+        lax.slice_in_dim(block, f // 4, f // 4 + 1, axis=0), jnp.int32)
+    return (word >> (8 * (f % 4))) & 0xFF
+
+
 def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
-                 num_bin_padded: int, int8_mode: bool = False,
-                 interpret: bool = False):
+                 num_bin_padded: int, fetch, tiles: int, live_in_last: int,
+                 int8_mode: bool = False, interpret: bool = False):
     """One (feature-tile, row-block) grid step.
 
-    bins_ref: int32 [FT, RB] feature-major
+    bins_ref: int32 [FT, RB] feature-major bins, or uint32 [FT/4, RB]
+              word-major packed words; ``fetch(block, f)`` takes feature
+              ``f``'s int32 [1, RB] row out of either
     gh_ref:   f32/int8 [Cp, RB] — transposed, channel-padded, leaf-masked
     out_ref:  f32/int32 [Cp, FT*Bp] — accumulator, pinned across row blocks
+
+    ``live_in_last``: how many features of the last of the ``tiles``
+    feature tiles exist. That tile runs those alone: the others are
+    skipped, not histogrammed and sliced off.
 
     Every op here is Mosaic-friendly by construction: the one-hot for
     feature f is built in [Bp, RB] orientation (a static row slice of the
@@ -78,7 +107,7 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    bins = bins_ref[:]                              # [FT, RB] int32
+    bins = bins_ref[:]                              # [FT, RB] / [FT/4, RB]
     gh = gh_ref[:]                                  # [Cp, RB]
     rb = bins.shape[1]
     # iota_b[b, r] = b; onehot_f[b, r] = (bins[f, r] == b)
@@ -88,7 +117,7 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
         onehot_dtype, acc_dtype = jnp.int8, jnp.int32
     else:
         # f32 inputs arrive pre-decomposed into bf16 channel triples (see
-        # _hist_pallas_impl) — the kernel always contracts at native bf16
+        # _hist_call) — the kernel always contracts at native bf16
         # MXU rate with f32 accumulation. The interpreter backend (CPU
         # tests) lacks bf16 dots; f32 compute there is numerically
         # identical (bf16 values are exact in f32).
@@ -96,8 +125,9 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
         if interpret:
             onehot_dtype = jnp.float32
             gh = gh.astype(jnp.float32)
-    for f in range(feature_tile):
-        row = lax.slice_in_dim(bins, f, f + 1, axis=0)       # [1, RB]
+
+    def add(f):
+        row = fetch(bins, f)                                 # [1, RB]
         onehot_f = (row == iota_b).astype(onehot_dtype)      # [Bp, RB]
         # contract over rows: [Cp, RB] x [Bp, RB] -> [Cp, Bp]
         hist_f = lax.dot_general(
@@ -105,6 +135,20 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
             preferred_element_type=acc_dtype)
         sl = slice(f * num_bin_padded, (f + 1) * num_bin_padded)
         out_ref[:, sl] += hist_f
+
+    def features(n):
+        for f in range(n):
+            add(f)
+
+    if tiles == 1 or live_in_last == feature_tile:
+        features(live_in_last)
+    else:
+        # two straight-line branches, not a guard around each feature that
+        # the last tile lacks: 29 guards a kernel, 12 kernels, took 2 s
+        # more to trace and lower, at every start of a training process
+        lax.cond(pl.program_id(0) < tiles - 1,
+                 functools.partial(features, feature_tile),
+                 functools.partial(features, live_in_last))
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -131,13 +175,18 @@ def bf16_triple(gh: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([hi, mid, lo], axis=1).astype(jnp.bfloat16)
 
 
-@functools.partial(jax.jit, static_argnames=("num_bin", "block_rows",
-                                             "feature_tile", "interpret"))
-def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
-                      block_rows: int, feature_tile: int,
-                      interpret: bool) -> jnp.ndarray:
-    F, R = bins_fm.shape
-    C = gh.shape[1]
+def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
+               feature_tile, block_rows, fetch, interpret):
+    """The ``pallas_call`` and what both entries do around it: the bf16
+    triple split, ``gh`` padded and transposed, the re-sum.
+
+    bins_op: int32 bins or uint32 words, rows on the lane axis, at least
+    ``gh``'s; where it stops short of a whole block the kernel reads what
+    lies behind it, and ``gh`` is zero there. ``bins_block`` is its
+    block's sublane extent (``feature_tile`` bins rows, or the words that
+    hold them).
+    """
+    R, C = gh.shape
     int8_mode = gh.dtype == jnp.int8
     f32_mode = gh.dtype == jnp.float32
     acc_dtype = jnp.int32 if int8_mode else jnp.float32
@@ -153,28 +202,24 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     # (32,128) int8
     Cp = 32 if int8_mode else _pad_to(max(Cin, 16), 16)
     Bp = _pad_to(num_bin, 128)            # lane-align the bin axis
-    feature_tile = max(8, _pad_to(feature_tile, 8))
-    block_rows = _pad_to(block_rows, 128)
-    Fp = _pad_to(F, feature_tile)
     Rp = _pad_to(R, block_rows)
+    tiles = pl.cdiv(num_features, feature_tile)
+    Fp = tiles * feature_tile
 
     with timer.stage("hist_gather"):
-        if Fp != F or Rp != R:
-            # dead feature rows produce columns sliced off below; padded
-            # rows carry gh = 0 so they accumulate nothing
-            bins_fm = jnp.pad(bins_fm, ((0, Fp - F), (0, Rp - R)))
-        bins_fm = bins_fm.astype(jnp.int32)
+        # padded rows carry gh = 0 so they accumulate nothing
         gh_t = jnp.pad(gh, ((0, Rp - R), (0, Cp - Cin))).T    # [Cp, Rp]
 
-    grid = (Fp // feature_tile, Rp // block_rows)
-    kernel = functools.partial(_hist_kernel, feature_tile=feature_tile,
-                               num_bin_padded=Bp, int8_mode=int8_mode,
-                               interpret=interpret)
+    kernel = functools.partial(
+        _hist_kernel, feature_tile=feature_tile, num_bin_padded=Bp,
+        fetch=fetch, tiles=tiles,
+        live_in_last=num_features - (tiles - 1) * feature_tile,
+        int8_mode=int8_mode, interpret=interpret)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(tiles, Rp // block_rows),
         in_specs=[
-            pl.BlockSpec((feature_tile, block_rows), lambda i, j: (i, j),
+            pl.BlockSpec((bins_block, block_rows), lambda i, j: (i, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((Cp, block_rows), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),
@@ -185,11 +230,11 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(bins_fm, gh_t)
+    )(bins_op, gh_t)
 
     # [Cp, Fp*Bp] -> [Fp, Bp, Cp] -> [F, num_bin, C]
     hist = out.reshape(Cp, Fp, Bp).transpose(1, 2, 0)
-    hist = hist[:F, :num_bin, :]
+    hist = hist[:num_features, :num_bin, :]
     if f32_mode:
         # re-sum the bf16 hi/mid/lo component histograms in f32
         return (hist[:, :, 0:C] + hist[:, :, C:2 * C] +
@@ -197,30 +242,74 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     return hist[:, :, :C]
 
 
+# The benchmark finds the kernel's events in a device trace by the name of
+# the jit that encloses the ``pallas_call``: both start ``_hist_pallas``.
+@functools.partial(jax.jit, static_argnames=("num_bin", "block_rows",
+                                             "feature_tile", "interpret"))
+def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
+                      block_rows: int, feature_tile: int,
+                      interpret: bool) -> jnp.ndarray:
+    F, R = bins_fm.shape
+    feature_tile = max(8, _pad_to(feature_tile, 8))
+    block_rows = _pad_to(block_rows, 128)
+    Fp = _pad_to(F, feature_tile)
+    Rp = _pad_to(R, block_rows)
+    with timer.stage("hist_gather"):
+        if Fp != F or Rp != R:
+            # dead feature rows produce columns sliced off below
+            bins_fm = jnp.pad(bins_fm, ((0, Fp - F), (0, Rp - R)))
+        bins_fm = bins_fm.astype(jnp.int32)
+    return _hist_call(bins_fm, feature_tile, gh, num_bin, Fp, feature_tile,
+                      block_rows, _row_of_bins, interpret)[:F]
+
+
+_WORD_TILE = 8      # words a tile: one sublane tile of 32-bit elements
+
+
+@functools.partial(jax.jit, static_argnames=("num_bin", "num_cols",
+                                             "block_rows", "interpret"))
+def _hist_pallas_words(words_cm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
+                       num_cols: int, block_rows: int,
+                       interpret: bool) -> jnp.ndarray:
+    block_rows = _pad_to(block_rows, 128)
+    # the (8, block_rows) tile of 32-bit elements hist_pallas_rm reads, now
+    # 32 features. Where the word axis ends inside a tile (17 words: the
+    # third; fewer than 8: the only one) the block reaches past the
+    # operand, and the kernel fetches only the words its live columns
+    # lie in
+    return _hist_call(words_cm, _WORD_TILE, gh, num_bin, num_cols,
+                      4 * _WORD_TILE, block_rows, _row_of_words, interpret)
+
+
+# the kernel's VMEM residents stay within ~4 MB of 32-bit elements, which
+# leaves room for double buffering in the ~16 MB/core VMEM
+_VMEM_BUDGET_ELEMS = (4 << 20) // 4
+
+
+def _resident(feature_tile: int, block_rows: int, Bp: int) -> int:
+    return (feature_tile * block_rows       # bins tile
+            + 32 * feature_tile * Bp        # accumulator (Cp<=32)
+            + Bp * block_rows)              # one-hot
+
+
 def fit_tiles(feature_tile: int, num_bin: int,
               block_rows: int) -> tuple:
     """Shrink (feature_tile, block_rows) so the kernel's VMEM residents
     (bins tile + pinned accumulator + one [Bp, RB] one-hot at a time)
-    stay within ~4 MB, leaving room for double buffering in the
-    ~16 MB/core VMEM. feature_tile stays a multiple of 8 (sublane rule),
-    block_rows a multiple of 128 (lane rule); feature_tile shrinks
+    stay within the budget. feature_tile stays a multiple of 8 (sublane
+    rule), block_rows a multiple of 128 (lane rule); feature_tile shrinks
     first, then block_rows — the one-hot term Bp*block_rows is
     feature-tile-independent, so a large tpu_rows_per_block must clamp
     rows, not just features."""
-    budget_elems = (4 << 20) // 4
     Bp = _pad_to(num_bin, 128)
     feature_tile = max(8, _pad_to(feature_tile, 8))
     block_rows = max(128, _pad_to(block_rows, 128))
 
-    def resident(ft, br):
-        return (ft * br                 # bins tile
-                + 32 * ft * Bp          # accumulator (Cp<=32)
-                + Bp * br)              # one-hot
     while feature_tile > 8 and \
-            resident(feature_tile, block_rows) > budget_elems:
+            _resident(feature_tile, block_rows, Bp) > _VMEM_BUDGET_ELEMS:
         feature_tile //= 2
     while block_rows > 128 and \
-            resident(feature_tile, block_rows) > budget_elems:
+            _resident(feature_tile, block_rows, Bp) > _VMEM_BUDGET_ELEMS:
         block_rows //= 2
     feature_tile, block_rows = max(feature_tile, 8), max(block_rows, 128)
     # feasible=False when even the (8, 128) floor exceeds the budget
@@ -228,7 +317,7 @@ def fit_tiles(feature_tile: int, num_bin: int,
     # Bp >= 4096) — callers must fall back to a non-Pallas backend
     # rather than launch an over-budget kernel
     return feature_tile, block_rows, \
-        resident(feature_tile, block_rows) <= budget_elems
+        _resident(feature_tile, block_rows, Bp) <= _VMEM_BUDGET_ELEMS
 
 
 def hist_pallas(bins_t: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
@@ -280,3 +369,45 @@ def hist_pallas_rm(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     # jaxlint: disable=JL001 — interpret is a static Python flag
     return _hist_pallas_impl(bins_fm, gh, num_bin, block_rows,
                              feature_tile, bool(interpret))
+
+
+def hist_pallas_words(words_cm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
+                      num_cols: int, block_rows: int = 512,
+                      dtype: str = "float32",
+                      interpret: bool | None = None) -> jnp.ndarray:
+    """Histogram [num_cols, num_bin, C] over bit-packed rows as the table
+    stores them: ``words_cm`` uint32 [ceil(num_cols / 4), S] word-major
+    (rows on the lane axis), byte ``k`` of word ``w`` = column ``4w + k``.
+    ``dtype`` as ``hist_rowmajor`` takes it: "bfloat16" rounds a float
+    ``gh`` to bf16 first.
+
+    Equal bit for bit to ``hist_rowmajor(backend="pallas")`` on the
+    unpacked rows wherever the two read the same row blocks (any
+    ``block_rows`` up to 2,688: same order of accumulation), without the
+    int32 [S, num_cols] copy: the kernel reads an (8, block_rows) tile of
+    words, 32 columns, where that one reads 8 columns, and takes each byte
+    out in VMEM. The operand is read in place, unpadded: the table's own
+    word-major view at the root.
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    if words_cm.shape != ((num_cols + 3) // 4, gh.shape[0]):
+        raise ValueError(f"hist_pallas_words: words {words_cm.shape} are "
+                         f"not {num_cols} columns of {gh.shape[0]} rows, "
+                         "word-major")
+    Bp = _pad_to(num_bin, 128)
+    if Bp > 256:
+        raise ValueError(f"hist_pallas_words: num_bin={num_bin}, and a "
+                         "packed bin is a byte")
+    if dtype in ("bfloat16", "bf16") and gh.dtype != jnp.int8:
+        gh = gh.astype(jnp.bfloat16)
+    # the feature tile is the word tile's 32 columns whatever the budget
+    # says, so only the rows give way (from 2,730 up); a byte's 256 bins
+    # fit at 128 rows
+    block_rows = max(128, _pad_to(block_rows, 128))
+    while block_rows > 128 and \
+            _resident(_WORD_TILE * 4, block_rows, Bp) > _VMEM_BUDGET_ELEMS:
+        block_rows //= 2
+    # jaxlint: disable=JL001 — interpret is a static Python flag
+    return _hist_pallas_words(words_cm, gh, num_bin, num_cols, block_rows,
+                              bool(interpret))

@@ -12,10 +12,11 @@ for one stage (``--stage``, default ``partition_fetch``), every gather with
 its operand's shape, layout and memory space: ``S(1)`` in a layout is VMEM,
 none is HBM. It also lists every ``copy`` and ``bitcast`` of an array of the
 table's size, with the computation it sits in: a copy inside a
-``branch_*`` computation is paid once a split. Nothing runs, so it gives no
-time. Layouts change with the row count (at 200,000 rows the row gather
+``branch_*`` computation is paid once a split. The Pallas kernels are
+compiled by Mosaic as on the chip (``pallas_custom_calls``). Nothing runs,
+so it gives no time. Layouts change with the row count (at 200,000 rows the row gather
 reads a row-major copy, at 2 M the word-major parameter): what a PR claims
-of the cell it checks at ``--rows 2000000`` (six minutes).
+of the cell it checks at ``--rows 2000000`` (seven minutes).
 """
 import argparse
 import math
@@ -33,6 +34,7 @@ from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 
 from lightgbm_tpu.core.grower import GrowerConfig, make_tree_grower
+from lightgbm_tpu.ops import hist_pallas
 from lightgbm_tpu.ops.split import FeatureMeta
 from lightgbm_tpu.utils import timer
 
@@ -76,6 +78,10 @@ def main():
     # a compile for a described chip is written to the persistent cache
     # and can never be read back from it
     jax.config.update("jax_enable_compilation_cache", False)
+    # jax still sees the CPU here, where the program interprets its Pallas
+    # kernels: compile them as the chip would (until PR 30 this script
+    # compiled the interpreter's loops in the custom calls' place)
+    hist_pallas.default_interpret = lambda: False
     F, B = 67, 255                      # `criteo-share`'s shape
     meta = FeatureMeta(num_bin=jnp.full((F,), B, jnp.int32),
                        missing_type=jnp.zeros((F,), jnp.int32),
@@ -107,6 +113,7 @@ def main():
     if args.text:
         with open(args.text, "w") as f:
             f.write(text)
+    print(f"pallas_custom_calls {text.count('tpu_custom_call')}")
     report(text, args.rows * width, args.stage)
 
 

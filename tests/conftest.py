@@ -175,6 +175,16 @@ def rng():
 GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "data", "golden")
 
 
+def pack_words(rm):
+    """uint8 [R, F] -> uint32 [R, ceil(F/4)], byte k of word w = column
+    4w+k (the layout models/gbdt.py uploads for tpu_packed_bins)."""
+    R, F = rm.shape
+    W = (F + 3) // 4
+    full = np.zeros((R, W * 4), np.uint8)
+    full[:, :F] = rm
+    return full.view(np.uint32).reshape(R, W)
+
+
 def load_golden_csv(name):
     """Parse a golden CSV (label first; empty fields = missing) ->
     (labels, X). Shared by the consistency and codegen suites."""

@@ -14,6 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import pack_words
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.bundling import find_bundles, pack_bins
 from lightgbm_tpu.io.dataset_core import BinnedDataset
@@ -140,16 +141,6 @@ def test_compact_monotone(rng):
 
 # ---- the partition's column fetch: packed, unpacked and full agree --------
 
-def _pack_words(rm):
-    """uint8 [R, F] -> uint32 [R, ceil(F/4)], byte k of word w = column
-    4w+k (the layout models/gbdt.py uploads for tpu_packed_bins)."""
-    R, F = rm.shape
-    W = (F + 3) // 4
-    full = np.zeros((R, W * 4), np.uint8)
-    full[:, :F] = rm
-    return full.view(np.uint32).reshape(R, W)
-
-
 def _grow_with_order(gcfg, meta, bundle, bins, gh):
     """(tree, leaf_id, order): ``order`` is the split loop's state, which
     ``grow`` does not return; it is read off the loop's outputs in the
@@ -202,16 +193,11 @@ def _clipped_start(rng):
     return X, y, {"min_bucket": 2048}
 
 
-@pytest.mark.parametrize("partition_mode", ["scatter", "sort"])
-@pytest.mark.parametrize("case", [_odd_columns, _efb, _clipped_start])
-def test_partition_fetch_packed_unpacked_full_agree(rng, case,
-                                                    partition_mode):
-    """One column out of the table, then the leaf's rows out of the
-    column: ``order``, ``leaf_id`` and the tree are the same, element for
-    element, on packed words, on plain uint8 bins and (tree and
-    ``leaf_id``) on the full-row grower that keeps no ``order``."""
+def _tables(rng, case, L=16, **grower):
+    """A case's table as the compact grower takes it: (config for
+    unpacked uint8 rows, meta, bundle, physical bins [Fp, R], the same
+    row-major uint8 [R, Fp], gh, the raw matrix X)."""
     X, y, opt = case(rng)
-    L = 16
     ds = BinnedDataset.from_matrix(
         X, Config({"num_leaves": L, "min_data_in_leaf": 5}), label=y)
     mappers = ds.used_bin_mappers()
@@ -232,33 +218,19 @@ def test_partition_fetch_packed_unpacked_full_agree(rng, case,
     gh = jnp.asarray(np.stack([grad, np.ones_like(grad),
                                np.ones_like(grad)], axis=1))
     rm = np.ascontiguousarray(phys.T).astype(np.uint8)
+    assert rm.shape[1] % 4 != 0                # a part-filled last word
     base = GrowerConfig(
         num_leaves=L, num_bin=B, hparams=SplitHyperParams(min_data_in_leaf=5),
         hist_backend="scatter", block_rows=512, hist_dtype="float32",
-        hist_rm_backend="scatter", partition_mode=partition_mode,
-        min_bucket=opt.get("min_bucket", 256))
-    compact = dataclasses.replace(base, row_sched="compact")
-    t_u, l_u, o_u = _grow_with_order(compact, meta, bundle,
-                                     jnp.asarray(rm), gh)
-    t_p, l_p, o_p = _grow_with_order(
-        dataclasses.replace(compact, packed_cols=rm.shape[1]), meta, bundle,
-        jnp.asarray(_pack_words(rm)), gh)
-    assert rm.shape[1] % 4 != 0
-    np.testing.assert_array_equal(o_p, o_u)
-    np.testing.assert_array_equal(l_p, l_u)
-    for a, b in zip(jax.tree.leaves(t_p), jax.tree.leaves(t_u)):
-        np.testing.assert_array_equal(a, b)
-    t_f, l_f = jax.jit(make_tree_grower(
-        dataclasses.replace(base, row_sched="full"), meta, bundle=bundle))(
-            jnp.asarray(phys), gh)
-    np.testing.assert_array_equal(l_p, np.asarray(l_f))
-    for name in ("split_feature", "threshold_bin", "default_left",
-                 "left_child", "right_child", "num_leaves"):
-        np.testing.assert_array_equal(getattr(t_p, name),
-                                      np.asarray(getattr(t_f, name)))
-    np.testing.assert_allclose(t_p.leaf_value, np.asarray(t_f.leaf_value),
-                               rtol=1e-5)
-    feats = t_p.split_feature[:int(t_p.num_leaves) - 1]
+        hist_rm_backend="scatter", partition_mode="scatter",
+        min_bucket=opt.get("min_bucket", 256), row_sched="compact")
+    return (dataclasses.replace(base, **grower), meta, bundle, phys, rm, gh,
+            X)
+
+
+def _assert_case_exercised(case, tree, bundle, X):
+    """The tree touches what the case is named for."""
+    feats = tree.split_feature[:int(tree.num_leaves) - 1]
     if case is _odd_columns:
         # a split on the last word's columns (4, 5 of 6)
         assert (feats >= 4).any()
@@ -270,7 +242,64 @@ def test_partition_fetch_packed_unpacked_full_agree(rng, case,
     if case is _clipped_start:
         # the root's right child is split again, in the 2048 bucket, and
         # starts where the left child ends: past R - P
-        lc, rc = int(t_p.left_child[0]), int(t_p.right_child[0])
-        n_left = (t_p.internal_count[lc] if lc >= 0
-                  else t_p.leaf_count[~lc])
+        lc, rc = int(tree.left_child[0]), int(tree.right_child[0])
+        n_left = (tree.internal_count[lc] if lc >= 0
+                  else tree.leaf_count[~lc])
         assert rc >= 0 and n_left > X.shape[0] - 2048
+
+
+def _assert_same_growth(a, b):
+    """(tree, leaf_id, order) equal element for element."""
+    np.testing.assert_array_equal(a[2], b[2])
+    np.testing.assert_array_equal(a[1], b[1])
+    for x, y in zip(jax.tree.leaves(a[0]), jax.tree.leaves(b[0])):
+        np.testing.assert_array_equal(x, y)
+
+
+@pytest.mark.parametrize("partition_mode", ["scatter", "sort"])
+@pytest.mark.parametrize("case", [_odd_columns, _efb, _clipped_start])
+def test_partition_fetch_packed_unpacked_full_agree(rng, case,
+                                                    partition_mode):
+    """One column out of the table, then the leaf's rows out of the
+    column: ``order``, ``leaf_id`` and the tree are the same, element for
+    element, on packed words, on plain uint8 bins and (tree and
+    ``leaf_id``) on the full-row grower that keeps no ``order``."""
+    compact, meta, bundle, phys, rm, gh, X = _tables(
+        rng, case, partition_mode=partition_mode)
+    unpacked = _grow_with_order(compact, meta, bundle, jnp.asarray(rm), gh)
+    t_p, l_p, o_p = packed = _grow_with_order(
+        dataclasses.replace(compact, packed_cols=rm.shape[1]), meta, bundle,
+        jnp.asarray(pack_words(rm)), gh)
+    _assert_same_growth(packed, unpacked)
+    t_f, l_f = jax.jit(make_tree_grower(
+        dataclasses.replace(compact, row_sched="full"), meta,
+        bundle=bundle))(jnp.asarray(phys), gh)
+    np.testing.assert_array_equal(l_p, np.asarray(l_f))
+    for name in ("split_feature", "threshold_bin", "default_left",
+                 "left_child", "right_child", "num_leaves"):
+        np.testing.assert_array_equal(getattr(t_p, name),
+                                      np.asarray(getattr(t_f, name)))
+    np.testing.assert_allclose(t_p.leaf_value, np.asarray(t_f.leaf_value),
+                               rtol=1e-5)
+    _assert_case_exercised(case, t_p, bundle, X)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("case", [_odd_columns, _efb, _clipped_start])
+def test_words_kernel_packed_unpacked_agree(rng, case, quantized):
+    """With the Pallas kernel (interpreted here) packed words go to it as
+    they are gathered and it takes each byte out itself; unpacked uint8
+    rows go through ``hist_pallas_rm``. Same row blocks, same sums: the
+    tree, ``leaf_id`` and ``order`` are equal element for element, with a
+    part-filled last word, with EFB bundles, with a clipped segment, on
+    float and on int8 gradients."""
+    compact, meta, bundle, _, rm, gh, X = _tables(
+        rng, case, L=8, hist_rm_backend="pallas", quantized=quantized,
+        stochastic_rounding=False)
+    unpacked = _grow_with_order(compact, meta, bundle, jnp.asarray(rm), gh)
+    packed = _grow_with_order(
+        dataclasses.replace(compact, packed_cols=rm.shape[1]), meta, bundle,
+        jnp.asarray(pack_words(rm)), gh)
+    _assert_same_growth(packed, unpacked)
+    assert packed[0].num_leaves == 8
+    _assert_case_exercised(case, packed[0], bundle, X)

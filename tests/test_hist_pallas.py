@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import pack_words
 from lightgbm_tpu.ops.hist_pallas import hist_pallas
 from lightgbm_tpu.ops.histogram import hist_scatter, hist_xla
 
@@ -142,3 +143,75 @@ def test_infeasible_tiles_fall_back_loudly(rng):
                         block_rows=512, backend="einsum")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
+
+
+# (columns, rows, num_bin, block_rows): 67 columns are 17 words, the last
+# part-filled, in three tiles of 8 words; 64 fill 16 words, two whole
+# tiles; 5 and 12 are one tile of fewer than 8 words; 3000 and 700 rows
+# end inside a row block, 256 rows are less than one
+WORD_CASES = [(67, 3000, 255, 512), (64, 1024, 255, 512),
+              (5, 700, 64, 256), (12, 2048, 64, 1024), (40, 256, 255, 512)]
+
+
+@pytest.mark.parametrize("gh_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("F,S,B,block_rows", WORD_CASES)
+def test_hist_pallas_words_equals_unpacked_bit_for_bit(rng, F, S, B,
+                                                       block_rows, gh_dtype):
+    """The packed entry reads the words the table stores and takes each
+    byte out in the kernel: same row blocks, same order of accumulation,
+    so the same bits as ``hist_pallas_rm`` on the unpacked rows."""
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_rm, hist_pallas_words
+
+    rm = rng.integers(0, B, size=(S, F)).astype(np.uint8)
+    # every fourth column is a word's top byte: bins of 255 there set the
+    # word's sign bit, which the arithmetic shift drags down
+    rm[::3, 3::4] = B - 1
+    rm[::5, F - 1] = B - 1
+    if gh_dtype == "int8":
+        gh = jnp.asarray(rng.integers(-8, 8, size=(S, 3)).astype(np.int8))
+    else:
+        gh = jnp.asarray(rng.normal(size=(S, 3)).astype(np.float32)
+                         ).astype(gh_dtype)
+    ref = hist_pallas_rm(jnp.asarray(rm.astype(np.int32)), gh, B,
+                         block_rows=block_rows)
+    out = hist_pallas_words(jnp.asarray(pack_words(rm).T), gh, B, F,
+                            block_rows=block_rows)
+    assert out.shape == (F, B, 3) and out.dtype == ref.dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    assert np.asarray(out)[F - 1, B - 1].any()
+
+
+def test_hist_pallas_words_rows_give_way_to_the_vmem_budget(monkeypatch):
+    """The word tile's 32 columns are fixed, so a row block too large for
+    the budget beside them is halved until it fits: 4096 rows run as
+    2048, and what ``tpu_rows_per_block`` gives by default stays."""
+    from lightgbm_tpu.ops import hist_pallas
+    assert hist_pallas._resident(32, 4096, 256) > \
+        hist_pallas._VMEM_BUDGET_ELEMS >= hist_pallas._resident(32, 2048, 256)
+    ran = []
+    monkeypatch.setattr(hist_pallas, "_hist_pallas_words",
+                        lambda w, gh, B, F, block_rows, interp:
+                        ran.append(block_rows))
+    words = jnp.zeros((3, 300), jnp.uint32)
+    gh = jnp.zeros((300, 3), jnp.float32)
+    for asked in (4096, 3072, 2048, 1024, 100):
+        hist_pallas.hist_pallas_words(words, gh, 255, 9, block_rows=asked)
+    assert ran == [2048, 1536, 2048, 1024, 128]
+
+
+def test_hist_pallas_words_dtype_matches_rowmajor(rng):
+    """``hist_pallas_words`` is ``hist_rowmajor(backend="pallas")`` on the
+    same rows, for the dtypes the grower hands it."""
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_words
+    from lightgbm_tpu.ops.histogram import hist_rowmajor
+    F, S, B = 10, 900, 64
+    rm = rng.integers(0, B, size=(S, F)).astype(np.uint8)
+    gh = jnp.asarray(rng.normal(size=(S, 3)).astype(np.float32))
+    words = jnp.asarray(pack_words(rm).T)
+    for dtype in ("float32", "bfloat16"):
+        np.testing.assert_array_equal(
+            np.asarray(hist_pallas_words(words, gh, B, F, block_rows=512,
+                                         dtype=dtype)),
+            np.asarray(hist_rowmajor(jnp.asarray(rm.astype(np.int32)), gh,
+                                     B, block_rows=512, dtype=dtype,
+                                     backend="pallas")))

@@ -313,7 +313,12 @@ def test_fleet_device_rot_quarantines_only_afflicted_tenant(fleet_pair):
 
 def test_fleet_host_rot_diagnosed_by_crc_and_rebuilt(fleet_pair):
     X, b1, b2 = fleet_pair
-    fleet = lgb.serve_fleet({"a": b1, "b": b2})
+    # a LONG probe interval: the rebuild's verify finds the rot, once; on
+    # a loaded machine the 0.15 s background probe found it too, first,
+    # and the count below read 2 (ROADMAP C10)
+    cfg = b1.config.copy()
+    cfg.set("tpu_integrity_probe_interval_s", 600.0)
+    fleet = lgb.serve_fleet({"a": b1, "b": b2}, config=cfg)
     try:
         ya0, yb0 = fleet.predict("a", X), fleet.predict("b", X)
         # rot the RETAINED host mega-pack in place: the recorded CRC

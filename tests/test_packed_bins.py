@@ -1,6 +1,7 @@
 """tpu_packed_bins: bit-packed (4 uint8/uint32) compact-scheduler bins
 must reproduce the unpacked path's models exactly — the packing only
-changes how the per-leaf row gather reads memory (grower.py unpack_rows).
+changes how the per-leaf row gather reads memory (grower.py: unpack_rows
+for the einsum/scatter kernels, the words themselves for the Pallas one).
 """
 import pytest
 import numpy as np
@@ -14,6 +15,16 @@ def _data(n=3000, f=10, seed=0):
     y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] +
          0.1 * rng.normal(size=n) > 0).astype(np.float32)
     return X, y
+
+
+def _efb_data():
+    rng = np.random.default_rng(3)
+    n = 2000
+    cat = rng.integers(0, 6, size=n)
+    X = np.zeros((n, 12), np.float32)
+    X[np.arange(n), cat] = 1.0           # 6 mutually-exclusive one-hots
+    X[:, 6:] = rng.normal(size=(n, 6)).astype(np.float32)
+    return X, (cat % 2 == 0).astype(np.float32)
 
 
 def _trees_only(model_str: str) -> str:
@@ -51,13 +62,7 @@ def test_packed_matches_unpacked_odd_features():
 
 @pytest.mark.slow
 def test_packed_with_efb_bundling():
-    rng = np.random.default_rng(3)
-    n = 2000
-    cat = rng.integers(0, 6, size=n)
-    X = np.zeros((n, 12), np.float32)
-    X[np.arange(n), cat] = 1.0           # 6 mutually-exclusive one-hots
-    X[:, 6:] = rng.normal(size=(n, 6)).astype(np.float32)
-    y = (cat % 2 == 0).astype(np.float32)
+    X, y = _efb_data()
     out = {}
     for mode in ("false", "true"):
         b = lgb.train(dict(objective="binary", num_leaves=7, verbose=-1,
@@ -74,3 +79,27 @@ def test_packed_quantized():
                           stochastic_rounding=False))
     assert (_trees_only(out["true"].model_to_string()) ==
             _trees_only(out["false"].model_to_string()))
+
+
+@pytest.mark.parametrize("extra", [{}, {"enable_bundle": True},
+                                   {"use_quantized_grad": True,
+                                    "stochastic_rounding": False}],
+                         ids=["plain", "efb", "int8"])
+def test_packed_matches_unpacked_pallas_kernel(extra):
+    """tpu_hist_kernel=pallas (the chip's kernel, interpreted here): packed
+    words reach the kernel as the table stores them, and the models are the
+    unpacked path's to the last digit — 10 columns are 3 words, the last
+    part-filled; with EFB the words hold physical columns."""
+    X, y = _efb_data() if "enable_bundle" in extra else _data()
+    out = {}
+    for mode in ("false", "true"):
+        b = lgb.train(dict(objective="binary", num_leaves=7, verbose=-1,
+                           min_data_in_leaf=5, tpu_hist_kernel="pallas",
+                           tpu_row_scheduling="compact",
+                           tpu_packed_bins=mode, **extra),
+                      lgb.Dataset(X, label=y), num_boost_round=3)
+        cfg = b._engine.grower_cfg
+        assert cfg.hist_rm_backend == "pallas" and cfg.row_sched == "compact"
+        assert (cfg.packed_cols > 0) == (mode == "true")
+        out[mode] = _trees_only(b.model_to_string())
+    assert out["true"] == out["false"]

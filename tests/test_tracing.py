@@ -154,6 +154,68 @@ def test_partition_fetch_indexes_a_column_never_the_table(booster, packed):
     assert fetches
 
 
+def packed_grower_jaxpr(engine, rows, **changed):
+    """The jaxpr of the booster's grower on packed words, and its config."""
+    from lightgbm_tpu.core.grower import make_tree_grower
+    cfg = dataclasses.replace(engine.grower_cfg, **changed)
+    closed = jax.make_jaxpr(make_tree_grower(cfg, engine.feature_meta))(
+        jax.ShapeDtypeStruct((rows, (cfg.packed_cols + 3) // 4), np.uint32),
+        jax.ShapeDtypeStruct((rows, 3), np.float32))
+    return closed.jaxpr, cfg
+
+
+def test_packed_pallas_grower_hands_the_kernel_words_not_unpacked_rows(
+        booster):
+    """On packed bins the Pallas histogram kernel reads the words the table
+    stores: between the gather and the kernel there is no int32 copy of a
+    bucket's rows at the table's column count, in either orientation, and
+    the kernel's operand is 32-bit words of the table's word count
+    (PERF.md, PR 30)."""
+    from lightgbm_tpu.core.grower import _bucket_sizes
+    jaxpr, cfg = packed_grower_jaxpr(booster._engine, 3000,
+                                     hist_rm_backend="pallas")
+    F = cfg.packed_cols
+    W = (F + 3) // 4
+    buckets = set(_bucket_sizes(3000, cfg.min_bucket))
+    assert len(buckets) > 1
+    kernels = collections.Counter()
+    for eqn, scope in equations(jaxpr):
+        if "lgbm.hist_gather" in scope or "lgbm.hist_kernel" in scope:
+            for var in eqn.outvars:
+                aval = var.aval
+                unpacked = (aval.ndim == 2 and aval.dtype == np.int32 and
+                            F in aval.shape and buckets & set(aval.shape))
+                assert not unpacked, (scope, eqn.primitive.name, aval)
+        if eqn.primitive.name == "pallas_call":
+            assert "lgbm.hist_kernel" in scope, scope
+            words = eqn.invars[0].aval
+            assert words.dtype in (np.uint32, np.int32), words
+            assert words.shape[0] in (W, -(-W // 8) * 8), words
+            assert words.shape[1] in buckets, words
+            kernels[words.shape[1]] += 1
+    # the root's and one for every bucket a child can fall into
+    assert set(kernels) == buckets, kernels
+
+
+def test_histogram_pool_is_read_and_written_as_slices(booster):
+    """A split touches two slots of the [L, F, B, 3] pool. As a gather and a
+    scatter the compiler took the whole pool for read, and on the chip it
+    moved all of it into VMEM and back once a split (PERF.md, PR 30)."""
+    jaxpr, cfg = packed_grower_jaxpr(booster._engine, 3000)
+
+    def is_pool(aval):
+        return aval.ndim == 4 and aval.shape[0] == cfg.num_leaves
+
+    touched = collections.Counter()
+    for eqn, scope in equations(jaxpr):
+        # (the root's slot is set once a tree, outside the stage)
+        if "lgbm.hist_subtract" in scope and any(
+                is_pool(v.aval) for v in eqn.invars if hasattr(v, "aval")):
+            touched[eqn.primitive.name] += 1
+    assert touched["dynamic_slice"] and touched["dynamic_update_slice"]
+    assert not {"gather", "scatter", "scatter-add"} & set(touched), touched
+
+
 class Text:
     """A planted program for the stage map."""
 
