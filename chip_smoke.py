@@ -65,8 +65,8 @@ class Sizes:
     quant_rows: int      # rows of the quantized serial-vs-data identity check
 
 
-# width is never cut; rows stay >= tuned.FLIP_MIN_ROWS_DEFAULT so the chip
-# run resolves the same kernels as the 1M bench shape
+# width is never cut; rows stay >= core/plan.MEASURED_FROM_ROWS so the chip
+# run resolves the kernels a large table takes
 CHIP = Sizes(rows=1_000_000, level_nodes=1024, predict_rows=100_000,
              parity_rows=4096, contrib_rows=1024, quant_rows=262_144)
 REHEARSAL = Sizes(rows=32768, level_nodes=16, predict_rows=2048,
@@ -107,6 +107,17 @@ def _version(dist: str) -> str:
         return importlib.metadata.version(dist)
     except importlib.metadata.PackageNotFoundError:
         return "not installed"
+
+
+def synth_higgs(n, f, seed=0):
+    """A Higgs-shaped table: ``f`` standard-normal columns, a label cut at
+    the median of a logit of the first four."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, f)).astype(np.float32)
+    logits = (X[:, 0] - 0.5 * X[:, 1] * X[:, 2] + 0.25 * X[:, 3] ** 2
+              + 0.1 * rng.normal(size=n))
+    y = (logits > np.median(logits)).astype(np.float32)
+    return X, y
 
 
 def _logloss(bst) -> float:
@@ -478,7 +489,6 @@ def _data_parallel(sizes: Sizes, devs) -> dict:
     of them (rows per chip as in the one-chip leg), placement checked
     shard by shard; then quantized serial == quantized data-parallel."""
     import lightgbm_tpu as lgb
-    from bench import synth_higgs
     n = len(devs)
     X, y = synth_higgs(sizes.rows * n, N_FEATURES, seed=1)
     bst, report = _train(X, y, {"tree_learner": "data"})
@@ -560,8 +570,7 @@ def main(argv=None) -> int:
     on_chip = plat == "tpu"
     sizes = CHIP if on_chip else REHEARSAL
 
-    from bench import synth_higgs
-    from lightgbm_tpu.models.gbdt import resolve_hist_kernel
+    from lightgbm_tpu.core.plan import make_plan
     from lightgbm_tpu.utils.jit_cache import enable_persistent_cache
     cache_dir = enable_persistent_cache()
     print(f"[smoke] compile cache: {cache_dir}", flush=True)
@@ -574,13 +583,17 @@ def main(argv=None) -> int:
         bst, reports["train"] = _train(X, y, {})
         eng = bst._engine
         cfg = eng.config
-        want = resolve_hist_kernel(
-            cfg.tpu_hist_kernel, cfg.tpu_hist_dtype,
-            bool(cfg.use_quantized_grad), eng.num_data, plat)
+        want = make_plan(
+            platform=plat, num_data=eng.num_data,
+            num_bin_max=eng.num_bin_max,
+            quantized=bool(cfg.use_quantized_grad),
+            hist_dtype=cfg.tpu_hist_dtype, tree_learner="serial",
+            storage="dense", row_sched="compact",
+            hist_kernel=cfg.tpu_hist_kernel).hist_rm_backend
         res = reports["train"]["resolved"]
         check(res["hist_rm_backend"] == want,
               f"compact-path kernel is {res['hist_rm_backend']!r}, "
-              f"resolve_hist_kernel names {want!r} for {plat}")
+              f"make_plan names {want!r} for {plat}")
         if on_chip:
             check(res["row_sched"] == "compact" and
                   res["hist_rm_backend"] == "pallas" and res["async"] and

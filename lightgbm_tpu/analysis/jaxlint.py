@@ -481,8 +481,6 @@ def lint_source(src: str, rel: str,
 
 def default_targets(root: str) -> List[str]:
     cands = [os.path.join(root, "lightgbm_tpu"),
-             os.path.join(root, "bench.py"),
-             os.path.join(root, "microbench.py"),
              os.path.join(root, "scripts")]
     return [c for c in cands if os.path.exists(c)]
 
@@ -625,7 +623,7 @@ def main(argv: Optional[List[str]] = None, root: Optional[str] = None
                     "see lightgbm_tpu/analysis/rules.py)")
     parser.add_argument("paths", nargs="*",
                         help="files/dirs to lint (default: the package + "
-                             "bench/scripts)")
+                             "scripts)")
     parser.add_argument("--baseline", default=None,
                         help=f"baseline json (default: <root>/"
                              f"{BASELINE_NAME})")

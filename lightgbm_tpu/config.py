@@ -43,14 +43,11 @@ _CHOICES: Dict[str, Tuple[str, ...]] = {
     "tpu_hist_kernel": ("auto", "einsum", "scatter", "pallas",
                         "pallas_level"),
     "tpu_hist_dtype": ("float32", "bfloat16", "bf16"),
-    # "leaf" = the masked full-pass leaf-wise program (same row layout
-    # as "full"; kept for parity with existing configs/tests)
-    "tpu_row_scheduling": ("compact", "full", "leaf", "level"),
+    "tpu_row_scheduling": ("compact", "full", "level"),
     "tpu_sparse_storage": ("auto", "dense", "multival", "none"),
     "tpu_partition_mode": ("auto", "scatter", "sort"),
-    # full truthy/falsy set the consumer (models/gbdt.py packed-bins
-    # resolution) accepts — validation must not reject spellings that
-    # worked before it existed
+    # full truthy/falsy set the consumer (core/plan.py) accepts —
+    # validation must not reject spellings that worked before it existed
     "tpu_packed_bins": ("auto", "true", "false", "1", "0", "yes", "no",
                         "on", "off"),
     "tpu_ingest": ("auto", "replicated", "sharded"),
@@ -58,8 +55,8 @@ _CHOICES: Dict[str, Tuple[str, ...]] = {
     # allreduce psums full histograms and scans replicated;
     # reduce_scatter leaves each device a feature slice + scans its
     # window + combines winners (≡ Network::ReduceScatter +
-    # SyncUpGlobalBestSplit). auto = allreduce unless the tuned cache
-    # recorded a measured reduce_scatter win (allreduce incumbent).
+    # SyncUpGlobalBestSplit). auto = allreduce (core/plan.py: no
+    # reading across chips has chosen reduce_scatter).
     "tpu_hist_reduce": ("auto", "allreduce", "reduce_scatter"),
     # fleet serving placement (serving/fleet.py, ISSUE 13): replicate
     # packs + row-shard requests (small fleets) vs shard the model
@@ -278,8 +275,7 @@ _reg("tpu_hist_dtype", str, "float32", ())   # histogram input dtype:
                                              # float32 | bfloat16
 _reg("tpu_hist_kernel", str, "auto", ())     # auto | einsum | scatter |
                                              # pallas | pallas_level
-                                             # (auto: einsum on TPU,
-                                             #  scatter-add on CPU;
+                                             # (auto: core/plan.py;
                                              #  pallas_level = the
                                              #  one-launch sorted-segment
                                              #  level kernel, level/hybrid
@@ -296,7 +292,7 @@ _reg("tpu_row_scheduling", str, "compact", ())  # compact | full | level
 # packed per-device records (≡ SyncUpGlobalBestSplit). Trees are
 # bit-identical between the modes (exact int32 psum_scatter under
 # use_quantized_grad; f32 ties resolve by global feature index). auto
-# consults the tuned cache (allreduce incumbent). Ineligible configs
+# is allreduce (core/plan.py). Ineligible configs
 # (EFB bundles, multival, forced splits, categorical, monotone) fall
 # back to allreduce, logged once at INFO.
 _reg("tpu_hist_reduce", str, "auto", ())     # auto | allreduce |
@@ -312,10 +308,10 @@ _reg("tpu_level_handoff_depth", int, 0, (), (0, None, True, False))
 # [R, K]; auto picks multival for sufficiently sparse scipy inputs
 _reg("tpu_sparse_storage", str, "auto", ())  # auto | dense | multival
 _reg("tpu_partition_mode", str, "auto", ())  # auto | scatter | sort
-# (auto: sort on TPU — measured 1.77 ms vs 5.17 ms scatter at 1M rows on
-#  v5e, docs/TPU_RUNBOOK.md; scatter on CPU)
+# (auto: scatter on CPU, core/plan.py; on a chip the grower sorts buckets
+#  of 32,768 rows up — measured 1.77 ms vs 5.17 ms scatter at 1M rows on
+#  v5e, docs/TPU_RUNBOOK.md — and scatters smaller ones)
 _reg("tpu_min_bucket", int, 2048, ())        # smallest pow2 segment bucket
-_reg("tpu_use_pallas", bool, False, ())      # Pallas histogram kernel (off until tuned)
 _reg("tpu_rows_per_block", int, 1024, ())    # row tile for histogram kernels
 # opt-in device-side bagging: draw the bagging mask on device from a
 # stateless key chain instead of host RNG + [N] mask upload (~15-25 ms
@@ -326,8 +322,8 @@ _reg("tpu_device_bagging", bool, False, ())
 # bit-pack 4 uint8 bins per uint32 word for the compact scheduler's
 # per-leaf row gathers (a TPU gather pays per index, by where its
 # operand lives, PERF.md §6 PR 26; a packed row is 17 words, not 67
-# bytes). auto = off until device-measured; true/false force. Requires
-# all (possibly bundled) bins to fit uint8.
+# bytes). auto = from 65,536 rows (core/plan.py); true/false force.
+# Requires all (possibly bundled) bins to fit uint8.
 _reg("tpu_packed_bins", str, "auto", ())     # auto | true | false
 _reg("tpu_donate_state", bool, True, ())     # donate training state buffers
 # async boosting: keep grown trees on device and defer host

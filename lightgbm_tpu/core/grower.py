@@ -46,7 +46,7 @@ class GrowerConfig:
     max_depth: int = -1
     num_bin: int = 256          # B: max bins over used features
     hparams: SplitHyperParams = SplitHyperParams()
-    hist_backend: str = "xla"   # xla | scatter | pallas
+    hist_backend: str = "xla"   # xla | scatter | multival
     block_rows: int = 4096
     # row scheduling: "full" = masked full-row histogram passes (bins given
     # feature-major [F, R]); "compact" = per-leaf contiguous row ordering
@@ -61,9 +61,9 @@ class GrowerConfig:
     # level-mode histogram kernel: "" derives from hist_rm_backend
     # (legacy); otherwise scatter | einsum | pallas | pallas_level —
     # the last is the ONE-launch sorted-segment Pallas kernel
-    # (ops/hist_level_pallas.py). Resolved by
-    # models/gbdt.resolve_level_hist_kernel from tpu_hist_kernel +
-    # the tuned cache at the training row count.
+    # (ops/hist_level_pallas.py). Resolved by core/plan.make_plan
+    # from tpu_hist_kernel and the platform, like hist_rm_backend and
+    # partition_mode.
     level_hist_backend: str = ""
     # compact-mode segment partition primitive: scatter | sort
     partition_mode: str = "scatter"

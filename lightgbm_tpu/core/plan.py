@@ -1,0 +1,128 @@
+"""What the grower runs, decided in one place from what the code can see.
+
+``make_plan`` maps the platform, the table (rows, widest bin count,
+storage), the learner, the scheduler and the user's explicit requests to
+the values ``models/gbdt.GBDT._setup_train`` hands to ``GrowerConfig``:
+the histogram kernel of the compact path and of the level phase, the
+partition primitive, whether the row-major bins are packed four to a
+32-bit word, and the histogram collective. It is pure: the platform is an
+argument (so the chip's answers are held by ``tests/test_plan.py`` on the
+CPU), and it reads no file and no environment. An explicit request passes
+through; ``auto`` takes the constants below, each with the measurement it
+stands on. Nothing else in the package writes these defaults.
+
+Choices this module does not make, because they are made per bucket inside
+the traced program from static shapes (``core/grower.py``, ``grow``):
+``words_kernel`` (packed words go to ``hist_pallas_words`` as the table
+stores them when the backend is ``pallas``; every other backend gets
+``unpack_rows``), and ``partition_mode="auto"``'s ``lax.sort`` for buckets
+of 32,768 rows and up, cumsum-scatter below. What needs the engine's state
+stays with the engine: the scheduler's eligibility (``_level_ineligibility``),
+the collective's (``_resolve_hist_reduce_mode``), async boosting
+(``_async_on``), the histogram pool's budget.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Tuple
+
+# The row count from which a chip's `auto` takes the measured combination.
+# At and above it: every accepted line of `criteo-share.train` (2,000,000 x
+# 67; ledger, PR 24, 26, 30: 1.1521 -> 1.4205 -> 1.5361 iter/s) ran the
+# Pallas kernel on packed words; chip_smoke.py holds the same at 1,000,000
+# x 28. Below it: the builders' v5e reading of 2026-08-01, 84.1 it/s on
+# einsum and unpacked bytes against 57.0 with both flips at 16,384 x 28.
+# No cell stands on the small side (ROADMAP.md C14).
+MEASURED_FROM_ROWS = 65536
+
+# float32 histograms on a chip, compact path (ledger, PR 24/26/30, as above)
+F32_KERNEL_LARGE = "pallas"
+F32_KERNEL_SMALL = "einsum"
+# bfloat16 and int8 histograms on a chip, any size: 5.99 / 5.56 ms a call
+# against einsum's 16.5 / 16.3 at 1 M rows (builders' v5e reading of the
+# feature-major kernel, docs/Features.md; not re-measured since)
+NARROW_KERNEL = "pallas"
+# level-phase histograms on a chip: the level growers never grew a tree
+# there (ROADMAP.md C1), so this is the conservative seed, not a reading
+LEVEL_KERNEL = "einsum"
+# the CPU backend, at every size: a one-hot einsum is about 100 times
+# slower there than a scatter-add, and the cumsum scatter beats lax.sort
+CPU_KERNEL = "scatter"
+CPU_PARTITION = "scatter"
+# row-sharded learners: allreduce is the incumbent; reduce_scatter was
+# never measured across chips (ROADMAP.md B4)
+HIST_REDUCE = "allreduce"
+# four uint8 bins to a word: a wider bin does not fit
+PACK_MAX_BIN = 255
+
+_TRUTHY = ("true", "1", "yes", "on")
+
+
+@dataclasses.dataclass(frozen=True)
+class Plan:
+    hist_rm_backend: str        # GrowerConfig.hist_rm_backend
+    level_hist_backend: str     # GrowerConfig.level_hist_backend
+    partition_mode: str         # GrowerConfig.partition_mode
+    pack: bool                  # store the serial learner's rows as words
+    hist_reduce: str            # before the learner's eligibility check
+    # (level, line) for the engine to log: requests not granted as asked
+    notes: Tuple[Tuple[str, str], ...] = ()
+
+
+def make_plan(*, platform: str, num_data: int, num_bin_max: int,
+              quantized: bool, hist_dtype: str, tree_learner: str,
+              storage: str, row_sched: str, hist_kernel: str = "auto",
+              packed_bins: str = "auto", partition_mode: str = "auto",
+              hist_reduce: str = "auto") -> Plan:
+    """The plan for one training set-up.
+
+    ``platform`` is ``jax.default_backend()``; ``storage`` one of
+    ``dense`` / ``bundled`` / ``multival``; ``row_sched`` the scheduler
+    after its eligibility fallback; the last four are the ``tpu_*``
+    parameters of the same names as the user set them.
+    """
+    notes = []
+    cpu = platform == "cpu"
+    large = num_data >= MEASURED_FROM_ROWS
+
+    if hist_kernel == "pallas_level":
+        # silent remaps make A/B numbers unattributable: say so
+        notes.append(("info",
+                      "tpu_hist_kernel=pallas_level applies to level-phase "
+                      "histograms only; the compact/tail row-major path "
+                      "resolves as auto"))
+    if hist_kernel not in ("auto", "pallas_level"):
+        rm_backend = hist_kernel
+    elif cpu:
+        rm_backend = CPU_KERNEL
+    elif quantized or hist_dtype in ("bfloat16", "bf16"):
+        rm_backend = NARROW_KERNEL
+    else:
+        rm_backend = F32_KERNEL_LARGE if large else F32_KERNEL_SMALL
+
+    if hist_kernel != "auto":
+        level_backend = hist_kernel
+    else:
+        level_backend = CPU_KERNEL if cpu else LEVEL_KERNEL
+
+    if partition_mode == "auto" and cpu:
+        partition_mode = CPU_PARTITION
+
+    if hist_reduce == "auto":
+        hist_reduce = HIST_REDUCE
+
+    # only the serial learner's row-major copy is packed (the distributed
+    # learners shard their own, multi-value storage has none), and only
+    # for the compact scheduler: the level grower reads plain uint8 rows
+    asked = str(packed_bins).lower()
+    pack = (tree_learner == "serial" and storage != "multival" and
+            row_sched == "compact" and
+            (asked in _TRUTHY or (asked == "auto" and large)))
+    if pack and num_bin_max > PACK_MAX_BIN:
+        notes.append(("warning",
+                      "tpu_packed_bins: bins exceed uint8 "
+                      f"(num_bin_max={num_bin_max}); storing unpacked"))
+        pack = False
+
+    return Plan(rm_backend, level_backend, partition_mode, pack,
+                hist_reduce, tuple(notes))

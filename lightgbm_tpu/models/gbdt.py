@@ -43,11 +43,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import tuned
 from ..config import Config
 from ..robustness import faults, heartbeat, integrity
 from ..core.grower import GrowerConfig, make_tree_grower
 from ..core.metrics import Metric, metrics_for_config
+from ..core.plan import make_plan
 from ..core.objective import ObjectiveFunction, CustomObjective, K_EPSILON
 from ..core.tree import HostTree, TreeArrays, host_tree_to_arrays
 from ..io.dataset_core import BinnedDataset
@@ -185,85 +185,6 @@ class _ValidData:
             init = dataset.metadata.init_score.reshape(
                 -1, dataset.num_data).astype(np.float32)
             self.score = jnp.asarray(init)
-
-
-def resolve_hist_kernel(requested: str, hist_dtype: str, use_quant: bool,
-                        num_data, platform: str) -> str:
-    """Resolve ``tpu_hist_kernel=auto`` to a concrete backend.
-
-    CPU: scatter-add (einsum one-hot is pathologically slow there).
-    TPU bf16/int8: the VMEM-resident Pallas kernel (measured on v5e at
-    1M rows, docs/TPU_RUNBOOK.md: 6.0 / 5.6 ms vs einsum's 16.5 /
-    16.3). TPU f32: einsum unless the on-device A/B recorded a Pallas
-    win in the tuned cache — size-gated (tuned.applies), since the
-    100k-measured flips regress small runs. Unknown cache values fall
-    back: tuning must never be able to break training.
-    """
-    if requested not in ("auto", "pallas_level"):
-        return requested
-    if requested == "pallas_level":
-        # "pallas_level" names the LEVEL-mode sorted-segment kernel
-        # only; the compact/tail row-major path resolves as if auto (it
-        # has no level formulation to run) — SAY so (r05 postmortem:
-        # silent remaps make A/B numbers unattributable)
-        log.info("tpu_hist_kernel=pallas_level applies to level-phase "
-                 "histograms only; the compact/tail row-major path "
-                 "resolves as auto")
-    if platform == "cpu":
-        return "scatter"
-    if use_quant or hist_dtype in ("bfloat16", "bf16"):
-        return "pallas"
-    tk = (tuned.get("f32_hist_kernel", "einsum")
-          if tuned.applies(num_data) else "einsum")
-    return tk if tk in ("einsum", "pallas", "scatter") else "einsum"
-
-
-def resolve_hist_reduce(requested: str, num_data, platform: str) -> str:
-    """Resolve ``tpu_hist_reduce=auto`` to a concrete histogram
-    collective for the row-sharded learners (ISSUE 12).
-
-    Explicit values pass through (eligibility fallback happens at the
-    learner, attributably). ``auto``: allreduce on CPU (virtual-device
-    collectives are shared-memory copies — the reduce_scatter win is
-    ICI bytes + the divided scan, both device properties); on TPU the
-    tuned cache's ``hist_reduce`` (re-learned by the session
-    ``ab_hist_reduce_*`` arms at the 1M depth-10 shape, 3% margin),
-    size-gated like every tuned flip, allreduce incumbent. Unknown
-    cache values fall back — tuning must never be able to break
-    training.
-    """
-    if requested != "auto":
-        return requested
-    if platform == "cpu":
-        return "allreduce"
-    tk = (tuned.get("hist_reduce", "allreduce")
-          if tuned.applies(num_data) else "allreduce")
-    return tk if tk in ("allreduce", "reduce_scatter") else "allreduce"
-
-
-def resolve_level_hist_kernel(requested: str, num_data,
-                              platform: str) -> str:
-    """Resolve ``tpu_hist_kernel`` for the LEVEL phase's per-node
-    histograms (core/level_grower.py; the compact/tail path resolves
-    separately through resolve_hist_kernel).
-
-    Explicit values pass through (``pallas_level`` = the one-launch
-    sorted-segment Pallas kernel, ops/hist_level_pallas.py; a bare
-    ``pallas`` stays einsum-pinned under blocks mode per ADVICE r05 —
-    level_grower._resolve_rm_backend). ``auto``: scatter on CPU;
-    on TPU the tuned cache's ``level_hist_backend`` (re-learned by the
-    microbench ``hist_level`` A/B at level shapes), size-gated like
-    every tuned flip, einsum fallback. Unknown cache values fall back —
-    tuning must never be able to break training.
-    """
-    if requested != "auto":
-        return requested
-    if platform == "cpu":
-        return "scatter"
-    tk = (tuned.get("level_hist_backend", "einsum")
-          if tuned.applies(num_data) else "einsum")
-    return tk if tk in ("einsum", "pallas", "scatter", "pallas_level") \
-        else "einsum"
 
 
 class GBDT:
@@ -776,9 +697,6 @@ class GBDT:
             cat_l2=float(cfg.cat_l2), cat_smooth=float(cfg.cat_smooth),
             max_cat_to_onehot=int(cfg.max_cat_to_onehot),
             min_data_per_group=int(cfg.min_data_per_group))
-        backend = "xla"
-        if cfg.tpu_use_pallas and jax.default_backend() == "tpu":
-            backend = "pallas"
         # interaction constraints: "[0,1,2],[2,3]" over ORIGINAL feature
         # indices -> tuple of tuples of USED indices (ref: col_sampler.hpp,
         # config.h interaction_constraints)
@@ -797,33 +715,18 @@ class GBDT:
                 for grp in parsed)
         self._bynode = cfg.feature_fraction_bynode < 1.0
         # compact row scheduling (O(rows_in_leaf) histogram passes) is the
-        # serial default; "full" keeps the masked full-pass program.
-        # tpu_hist_kernel=auto picks scatter-add on the CPU backend
-        # (einsum one-hot is pathologically slow there) and the MXU
-        # einsum kernel on TPU.
+        # serial default; "full" keeps the masked full-pass program. The
+        # kernels, the partition primitive and packing are the plan's
+        # (core/plan.py), filled in below once the learner, the storage
+        # and the scheduler are settled.
         row_sched = cfg.tpu_row_scheduling
         hist_dtype = cfg.tpu_hist_dtype
-        rm_backend = resolve_hist_kernel(
-            cfg.tpu_hist_kernel, hist_dtype, bool(cfg.use_quantized_grad),
-            self.num_data, jax.default_backend())
-        level_backend = resolve_level_hist_kernel(
-            cfg.tpu_hist_kernel, self.num_data, jax.default_backend())
-        part_mode = cfg.tpu_partition_mode
-        if part_mode == "auto" and jax.default_backend() == "cpu":
-            # CPU favors scatter at every size; on TPU "auto" passes
-            # through to the grower, which picks sort for big buckets
-            # (1.77 vs 5.17 ms at 1M rows, docs/TPU_RUNBOOK.md) and
-            # scatter for small ones (lax.sort's fixed bitonic cost)
-            part_mode = "scatter"
         self.grower_cfg = GrowerConfig(
             num_leaves=cfg.num_leaves, max_depth=cfg.max_depth,
-            num_bin=self.num_bin_max, hparams=hp, hist_backend=backend,
+            num_bin=self.num_bin_max, hparams=hp,
             block_rows=cfg.tpu_rows_per_block,
             bynode_mask=self._bynode, interaction_groups=groups,
             row_sched=row_sched, hist_dtype=hist_dtype,
-            hist_rm_backend=rm_backend,
-            level_hist_backend=level_backend,
-            partition_mode=part_mode,
             min_bucket=cfg.tpu_min_bucket,
             quantized=bool(cfg.use_quantized_grad),
             quant_bins=int(cfg.num_grad_quant_bins),
@@ -1028,6 +931,29 @@ class GBDT:
                 self.grower_cfg = dataclasses.replace(
                     self.grower_cfg, row_sched="compact")
 
+        # every input of the plan is settled here: the learner, the storage
+        # (EFB may have raised num_bin_max) and the scheduler
+        self._plan = plan = make_plan(
+            platform=jax.default_backend(), num_data=self.num_data,
+            num_bin_max=self.num_bin_max,
+            quantized=bool(cfg.use_quantized_grad),
+            hist_dtype=cfg.tpu_hist_dtype,
+            tree_learner=self._tree_learner,
+            storage=("multival" if self._multival else
+                     "bundled" if self._bundle is not None else "dense"),
+            row_sched=self.grower_cfg.row_sched,
+            hist_kernel=cfg.tpu_hist_kernel,
+            packed_bins=cfg.tpu_packed_bins,
+            partition_mode=cfg.tpu_partition_mode,
+            hist_reduce=cfg.tpu_hist_reduce)
+        for level, line in plan.notes:
+            getattr(log, level)(line)
+        log.debug(f"plan: {plan}")
+        self.grower_cfg = dataclasses.replace(
+            self.grower_cfg, hist_rm_backend=plan.hist_rm_backend,
+            level_hist_backend=plan.level_hist_backend,
+            partition_mode=plan.partition_mode)
+
         self.bins_rf = None
         self._bins_packed_dev = None
         self._packed_cols = 0
@@ -1036,18 +962,7 @@ class GBDT:
             # row-major copy for the gather path; bins_dev keeps the
             # feature-major layout used by prediction/traversal (the
             # distributed learners shard their own row-major copy)
-            pb = str(cfg.tpu_packed_bins).lower()
-            # auto: off until the on-device gather A/B records a win in
-            # the tuned cache (u32 packed words gather 4x fewer elements;
-            # measured on CPU proxy only so far). Only a literal JSON
-            # true counts — any other cache value falls back to off.
-            want_pack = (pb in ("true", "1", "yes", "on") or
-                         (pb == "auto" and
-                          tuned.applies(self.num_data) and
-                          tuned.get("packed_bins", False) is True))
-            # the level grower reads plain u8 [R, F] directly
-            want_pack &= self.grower_cfg.row_sched == "compact"
-            if want_pack and self.num_bin_max <= 255:
+            if plan.pack:
                 # bit-pack 4 uint8 bins per uint32 word: quarters the
                 # element count of the compact scheduler's per-leaf row
                 # gathers (grower unpacks with shifts post-gather)
@@ -1062,10 +977,6 @@ class GBDT:
                     .reshape(Rn, W))
                 self._packed_cols = Fn
             else:
-                if want_pack:
-                    log.warning("tpu_packed_bins: bins exceed uint8 "
-                                f"(num_bin_max={self.num_bin_max}); "
-                                "storing unpacked")
                 self.bins_rf = jnp.asarray(
                     np.ascontiguousarray(train_bins_host.T))
         elif self._bundle is not None and self._tree_learner == "serial":
@@ -1294,8 +1205,7 @@ class GBDT:
         INFO with the reason (the PR6 backend-fallback rule: silent
         remaps make A/B numbers unattributable)."""
         cfg = self.config
-        mode = resolve_hist_reduce(cfg.tpu_hist_reduce, self.num_data,
-                                   jax.default_backend())
+        mode = self._plan.hist_reduce
         if tl not in ("data", "voting"):
             self._hist_reduce = "n/a"   # no histogram collective at all
             return "allreduce"

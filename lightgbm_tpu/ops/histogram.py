@@ -175,10 +175,6 @@ def make_hist_fn(backend: str, num_bin: int, block_rows: int = 4096):
     if backend == "xla":
         return functools.partial(hist_xla, num_bin=num_bin,
                                  block_rows=block_rows)
-    if backend == "pallas":
-        from .hist_pallas import hist_pallas
-        return functools.partial(hist_pallas, num_bin=num_bin,
-                                 block_rows=block_rows)
     if backend == "multival":
         from .hist_multival import hist_multival
         return functools.partial(hist_multival, num_bin=num_bin)

@@ -16,10 +16,9 @@ Fault classes (the ``site`` argument of :func:`maybe_fail`):
 - ``collective``  — the injected-collective host callables
   (distributed.make_injected_hooks) raise :class:`FaultInjected`
   (classified transient: its message carries ``UNAVAILABLE``).
-- ``probe_timeout`` — device probes (robustness.retry.probe_device,
-  which bench.py's probe child routes through) raise a transient
-  failure, simulating a device runtime that is cycling through
-  recovery.
+- ``probe_timeout`` — device probes (robustness.retry.probe_device)
+  raise a transient failure, simulating a device runtime that is
+  cycling through recovery.
 - ``write_kill`` — checkpoint writes die MID-WRITE (after the payload
   is partially written, before the atomic rename), simulating a kill
   -9 during snapshotting; raises :class:`WriteKilled`.

@@ -11,7 +11,7 @@ to a single flaky accelerator in the spirit of Dean & Barroso's
 tail-tolerance techniques (PAPERS.md):
 
 - **Writer** (:class:`Heartbeat`): instrumented children — the gbdt
-  training loop, bench measurement children, session stages — append
+  training loop, sharded-ingest constructors — append
   phase-tagged beats (``compiling`` / ``warmup`` / ``measuring`` /
   ``iter`` + progress counter, monotonic timestamp, pid) to a
   crash-safe single-line-rewrite file (tmp + ``os.replace``; a torn or
@@ -84,8 +84,8 @@ def rank_path(path: str, rank: int) -> str:
     """Per-rank heartbeat file for gang workers: the supervisor exports
     ONE base path (``LGBM_TPU_HEARTBEAT``) and every rank writes
     ``base.r<rank>`` — the shared convention between the gang
-    supervisor (robustness/gang.py), models/gbdt.py's install, the
-    sharded-ingest constructor, and the bench ingest children."""
+    supervisor (robustness/gang.py), models/gbdt.py's install and the
+    sharded-ingest constructor."""
     return f"{path}.r{int(rank)}"
 
 # exit code of a self-watchdogged child: the supervisor maps it to the

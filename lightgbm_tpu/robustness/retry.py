@@ -6,9 +6,9 @@ A device runtime that is recovering answers
 (docs/TPU_RUNBOOK.md), and a single unretried failure turns a
 recovering device into a dead run. This module is the one shared answer:
 ``init_distributed``, the injected-collective call sites
-(distributed.py) and the bench probe (bench.py) all retry through the
-same policy, so "how long do we believe in a flaky device" is configured
-in exactly one place.
+(distributed.py) and the device probe (``probe_device`` below) all retry
+through the same policy, so "how long do we believe in a flaky device" is
+configured in exactly one place.
 
 Backoff is decorrelated jitter (Brooker, "Exponential Backoff And
 Jitter", AWS builders' library): ``sleep = min(cap, uniform(base,

@@ -1,8 +1,9 @@
 """Heartbeat-aware child supervision (ISSUE 4 tentpole, parent side).
 
-Replaces the blind wall-clock slots in bench.py and
-scripts/tpu_session_auto.py with phase-aware liveness deadlines over the
-heartbeat protocol (robustness/heartbeat.py):
+Supervises a child process (the continual-learning service's trainer,
+service/trainer.py; gang.py generalizes it to N ranks) with phase-aware
+liveness deadlines over the heartbeat protocol (robustness/heartbeat.py),
+where a blind wall-clock slot would kill a long compile or wait out a hang:
 
 - a child whose heartbeats advance (phase change, progress change, or a
   live keepalive within its phase's stall budget) is NEVER killed or

@@ -1,10 +1,9 @@
-"""Persistent XLA compilation cache setup (shared by bench/tests/CLI).
+"""Persistent XLA compilation cache setup (shared by engine/tests/CLI).
 
 The grower programs for realistic shapes take minutes to compile on TPU;
 a warm on-disk cache turns a retried or relaunched attempt's compile into
 a file read. One helper so the cache directory convention and tuning
-thresholds live in one place (the engine, ``chip_smoke.py`` and both
-supervisors — bench.py and scripts/tpu_session_auto.py — all route
+thresholds live in one place (the engine and ``chip_smoke.py`` route
 through it).
 
 The cache can be PLACED from outside: where ``JAX_COMPILATION_CACHE_DIR``

@@ -3,7 +3,7 @@
 ONE copy of the session-driver contract: every `bench_logs/SERVING*.json`
 writer goes through `write_record` (mkdir + pretty JSON + the stdout
 echo the driver tails) and classifies failures through
-`classify_status` (bench.py's grammar: transient device symptoms are
+`classify_status` (one grammar: transient device symptoms are
 "device_unreachable", anything else "no_result") — three scripts
 drifting on this grammar is the bug class the helper removes.
 
@@ -39,7 +39,7 @@ def write_record(path: str, record: dict) -> dict:
 
 
 def classify_status(exc: BaseException) -> str:
-    """bench.py's failure grammar: "device_unreachable" only for
+    """The failure grammar: "device_unreachable" only for
     transient device symptoms (the 0.0 says nothing about the code
     under test), "no_result" otherwise."""
     from lightgbm_tpu.robustness.retry import is_transient_error

@@ -5,7 +5,7 @@ The reference serves predictions through an OMP row-parallel C++ loop
 native/c_api.cpp's interpreter-free model parser + ParallelRows thread
 pool, plus the packed-forest device route (ops/forest.py). This script
 times the paths on the same model/data and writes
-bench_logs/SERVING.json under bench.py's status grammar
+bench_logs/SERVING.json under scripts/_bench_io.py's status grammar
 ("measured" / "device_unreachable" / "no_result" — the session driver
 keys on it):
 

@@ -4,7 +4,7 @@ SAME model file and the SAME [N, 28] f32 matrix, single thread
 (ref: src/application/predictor.hpp:31 — the reference serves via an
 OMP row-parallel loop; ours via native/c_api.cpp ParallelRows).
 
-Writes bench_logs/SERVING_AB.json under bench.py's status grammar
+Writes bench_logs/SERVING_AB.json under scripts/_bench_io.py's status grammar
 ("measured" / "no_result" — the session driver keys on it; ISSUE 8
 satellite). A run that cannot measure (reference build absent on this
 host) keeps the last measured record under "previous" instead of

@@ -32,8 +32,8 @@ ServingCounters exactly as clients observed it, the forced degradation
 recovers via the background probe, and p999 stays under
 ``--chaos-p999-ms``.
 
-Results land in bench_logs/SERVING_LOAD.json under bench.py's status
-grammar (measured / degraded / device_unreachable / no_result — a
+Results land in bench_logs/SERVING_LOAD.json under scripts/_bench_io.py's
+status grammar (measured / degraded / device_unreachable / no_result — a
 "degraded" record means the tier ended on the host fallback) so the
 session driver can key on them.
 

@@ -26,7 +26,7 @@ def _data(seed=5, n=2000, f=8):
 
 
 @pytest.mark.parametrize("sched,max_depth", [
-    ("leaf", -1),
+    ("full", -1),
     ("level", 6),     # pure level mode
     ("level", -1),    # HYBRID level+tail (the round-7 default-config
                       # path: level phase + traced-start fori tail —

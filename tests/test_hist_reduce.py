@@ -398,29 +398,23 @@ def test_engine_fallback_attribution(rng):
     assert _trees_only(mono) == _trees_only(ar)
 
 
-def test_resolve_hist_reduce_unit(tmp_path, monkeypatch):
-    from lightgbm_tpu import tuned
-    from lightgbm_tpu.models.gbdt import resolve_hist_reduce
-    assert resolve_hist_reduce("reduce_scatter", 10, "cpu") == \
-        "reduce_scatter"
-    assert resolve_hist_reduce("allreduce", 10 ** 7, "tpu") == "allreduce"
-    assert resolve_hist_reduce("auto", 10 ** 7, "cpu") == "allreduce"
-    # on-device auto consults the tuned cache above the flip floor...
-    cache = tmp_path / "TUNED.json"
-    cache.write_text('{"hist_reduce": "reduce_scatter"}')
-    monkeypatch.setenv("LIGHTGBM_TPU_TUNED", str(cache))
-    tuned.reload()
-    try:
-        assert resolve_hist_reduce("auto", 10 ** 7, "tpu") == \
-            "reduce_scatter"
-        # ...not below it, and never on an unknown value
-        assert resolve_hist_reduce("auto", 100, "tpu") == "allreduce"
-        cache.write_text('{"hist_reduce": "banana"}')
-        tuned.reload()
-        assert resolve_hist_reduce("auto", 10 ** 7, "tpu") == "allreduce"
-    finally:
-        monkeypatch.delenv("LIGHTGBM_TPU_TUNED")
-        tuned.reload()
+def test_resolve_hist_reduce_unit():
+    from lightgbm_tpu.core.plan import make_plan
+
+    def reduce_of(requested, num_data, platform):
+        return make_plan(platform=platform, num_data=num_data,
+                         num_bin_max=255, quantized=False,
+                         hist_dtype="float32", tree_learner="data",
+                         storage="dense", row_sched="compact",
+                         hist_reduce=requested).hist_reduce
+    # explicit values pass through (the learner's eligibility fallback
+    # is the engine's, test_engine_fallback_attribution)
+    assert reduce_of("reduce_scatter", 10, "cpu") == "reduce_scatter"
+    assert reduce_of("allreduce", 10 ** 7, "tpu") == "allreduce"
+    # auto is allreduce on both platforms, on both sides of the row gate
+    for platform in ("cpu", "tpu"):
+        for num_data in (100, 10 ** 7):
+            assert reduce_of("auto", num_data, platform) == "allreduce"
 
 
 def test_config_validates_hist_reduce_choice():
@@ -429,32 +423,6 @@ def test_config_validates_hist_reduce_choice():
         lgb.Dataset(np.zeros((50, 2)), label=np.zeros(50),
                     params={"tpu_hist_reduce": "reduce_scater"}
                     ).construct()
-
-
-def test_bench_records_carry_hist_reduce():
-    """Every BENCH_r*.json training record — headline, banked partial,
-    parent-side failure line — carries the resolved hist_reduce field
-    (the PR6 level_backend contract extended to the comm config), and
-    the comms A/B line follows the same status grammar."""
-    import importlib.util
-    import json
-    import os
-    repo = os.path.join(os.path.dirname(__file__), "..")
-    spec = importlib.util.spec_from_file_location(
-        "bench_hist_reduce_test", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    rec = bench._result_record(1.5)
-    assert rec["hist_reduce"] == "unknown"     # parent-side default
-    assert rec["level_backend"] == "unknown"
-    bench._HIST_REDUCE = "reduce_scatter"
-    assert bench._result_record(1.5)["hist_reduce"] == "reduce_scatter"
-    fail = json.loads(bench._fail_line("boom"))
-    assert fail["hist_reduce"] == "reduce_scatter"
-    comms = bench._comms_record(0.0, status="no_result", note="x")
-    assert comms["status"] == "no_result"
-    assert comms["unit"] == "iters/sec"
-    assert comms["metric"].startswith("comms_ab_")
 
 
 def test_grower_rejects_ineligible_window_configs():

@@ -436,22 +436,3 @@ def test_multiclass_windows_and_iteration_ranges(rng):
         host = bst.predict(X, **kw)
         dev = bst.predict(X, device=True, **kw)
         np.testing.assert_allclose(dev, host, rtol=1e-5, atol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# bench record shapes (the inference metric's status grammar)
-# ---------------------------------------------------------------------------
-
-def test_bench_predict_record_grammar():
-    import importlib
-    import json
-    bench = importlib.import_module("bench")
-    rec = bench._predict_record(1234.5, sched="compact")
-    assert rec["metric"].endswith("_predict_rows_per_sec") or \
-        "_predict_rows_per_sec" in rec["metric"]
-    assert rec["unit"] == "rows/sec"
-    fail = json.loads(bench._predict_fail_line(
-        "x", status="device_unreachable"))
-    assert fail["status"] == "device_unreachable"
-    assert fail["value"] == 0.0
-    assert "_predict_rows_per_sec" in fail["metric"]

@@ -1,0 +1,207 @@
+"""What the grower runs, as one table: ``core/plan.make_plan`` from what the
+code can observe to the values ``GrowerConfig`` and ``_setup_train`` consume.
+
+The rows were written down from the parent's behaviour (``resolve_hist_kernel``,
+``resolve_level_hist_kernel``, ``resolve_hist_reduce``, the ``part_mode`` block
+and the ``want_pack`` expression of ``models/gbdt.py`` with the shipped
+tuned-defaults cache) before the code moved. The platform is an argument, so the
+TPU's answers are held here on the CPU.
+"""
+import pytest
+
+from lightgbm_tpu.core import plan as plan_mod
+from lightgbm_tpu.core.plan import make_plan
+
+M2 = 2_000_000
+GATE = plan_mod.MEASURED_FROM_ROWS
+
+# (platform, num_data, dtype, quantized, learner, storage, sched,
+#  num_bin_max, requests) -> (hist_rm_backend, level_hist_backend,
+#                             partition_mode, pack, hist_reduce)
+CASES = [
+    # the cell's own parameters (criteo-share.train): every tpu_* auto
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 255, {},
+     ("pallas", "einsum", "auto", True, "allreduce")),
+    # the same on the CPU: scatter everywhere; the row gate of packing
+    # never asked for a platform
+    ("cpu", M2, "float32", False, "serial", "dense", "compact", 255, {},
+     ("scatter", "scatter", "scatter", True, "allreduce")),
+    ("cpu", 20_000, "float32", False, "serial", "dense", "compact", 255, {},
+     ("scatter", "scatter", "scatter", False, "allreduce")),
+    # the 65,536-row gate, both sides
+    ("tpu", GATE - 1, "float32", False, "serial", "dense", "compact", 255,
+     {}, ("einsum", "einsum", "auto", False, "allreduce")),
+    ("tpu", GATE, "float32", False, "serial", "dense", "compact", 255, {},
+     ("pallas", "einsum", "auto", True, "allreduce")),
+    # bf16 and int8 histograms take the kernel at every size; packing
+    # still waits for the gate
+    ("tpu", GATE - 1, "bfloat16", False, "serial", "dense", "compact", 255,
+     {}, ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", GATE - 1, "bf16", False, "serial", "dense", "compact", 255, {},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "bf16", False, "serial", "dense", "compact", 255, {},
+     ("pallas", "einsum", "auto", True, "allreduce")),
+    ("tpu", GATE - 1, "float32", True, "serial", "dense", "compact", 255,
+     {}, ("pallas", "einsum", "auto", False, "allreduce")),
+    ("cpu", M2, "bfloat16", True, "serial", "dense", "compact", 255, {},
+     ("scatter", "scatter", "scatter", True, "allreduce")),
+    # every explicit tpu_hist_kernel passes through on both platforms
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 255,
+     {"hist_kernel": "einsum"},
+     ("einsum", "einsum", "auto", True, "allreduce")),
+    ("tpu", GATE - 1, "float32", False, "serial", "dense", "compact", 255,
+     {"hist_kernel": "pallas"},
+     ("pallas", "pallas", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 255,
+     {"hist_kernel": "scatter"},
+     ("scatter", "scatter", "auto", True, "allreduce")),
+    ("cpu", 20_000, "float32", False, "serial", "dense", "compact", 255,
+     {"hist_kernel": "einsum"},
+     ("einsum", "einsum", "scatter", False, "allreduce")),
+    ("cpu", 20_000, "float32", False, "serial", "dense", "compact", 255,
+     {"hist_kernel": "pallas"},
+     ("pallas", "pallas", "scatter", False, "allreduce")),
+    # pallas_level names the level phase's kernel; the row-major path
+    # resolves as auto
+    ("tpu", M2, "float32", False, "serial", "dense", "level", 255,
+     {"hist_kernel": "pallas_level"},
+     ("pallas", "pallas_level", "auto", False, "allreduce")),
+    ("tpu", GATE - 1, "float32", False, "serial", "dense", "level", 255,
+     {"hist_kernel": "pallas_level"},
+     ("einsum", "pallas_level", "auto", False, "allreduce")),
+    ("cpu", 20_000, "float32", False, "serial", "dense", "level", 255,
+     {"hist_kernel": "pallas_level"},
+     ("scatter", "pallas_level", "scatter", False, "allreduce")),
+    # tpu_packed_bins: asked for, refused, and the uint8 limit
+    ("cpu", 20_000, "float32", False, "serial", "dense", "compact", 255,
+     {"packed_bins": "true"},
+     ("scatter", "scatter", "scatter", True, "allreduce")),
+    ("tpu", GATE - 1, "float32", False, "serial", "dense", "compact", 255,
+     {"packed_bins": "on"},
+     ("einsum", "einsum", "auto", True, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 255,
+     {"packed_bins": "false"},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 255,
+     {"packed_bins": "0"},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 256, {},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 256,
+     {"packed_bins": "true"},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    # EFB's physical columns pack like logical ones
+    ("tpu", M2, "float32", False, "serial", "bundled", "compact", 255, {},
+     ("pallas", "einsum", "auto", True, "allreduce")),
+    # who never packs: the distributed learners (they shard a copy of
+    # their own), multi-value storage, the level and full schedulers
+    ("tpu", M2, "float32", False, "data", "dense", "compact", 255, {},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "data", "dense", "compact", 255,
+     {"packed_bins": "true"},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "voting", "dense", "compact", 255, {},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "multival", "compact", 255,
+     {"packed_bins": "true"},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "level", 255,
+     {"packed_bins": "true"},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "full", 255, {},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+    # tpu_partition_mode: auto is the CPU's scatter and the grower's own
+    # choice per bucket on a chip; explicit values pass through
+    ("cpu", 20_000, "float32", False, "serial", "dense", "compact", 255,
+     {"partition_mode": "sort"},
+     ("scatter", "scatter", "sort", False, "allreduce")),
+    ("tpu", M2, "float32", False, "serial", "dense", "compact", 255,
+     {"partition_mode": "scatter"},
+     ("pallas", "einsum", "scatter", True, "allreduce")),
+    # tpu_hist_reduce: auto is allreduce; an explicit value passes through
+    # (the learner's eligibility fallback is the engine's)
+    ("tpu", M2, "float32", False, "data", "dense", "compact", 255,
+     {"hist_reduce": "reduce_scatter"},
+     ("pallas", "einsum", "auto", False, "reduce_scatter")),
+    ("cpu", 20_000, "float32", False, "data", "dense", "compact", 255,
+     {"hist_reduce": "reduce_scatter"},
+     ("scatter", "scatter", "scatter", False, "reduce_scatter")),
+    ("tpu", M2, "float32", False, "voting", "dense", "compact", 255,
+     {"hist_reduce": "allreduce"},
+     ("pallas", "einsum", "auto", False, "allreduce")),
+]
+
+
+def _plan(case):
+    platform, num_data, dtype, quantized, learner, storage, sched, \
+        num_bin_max, requests, _ = case
+    return make_plan(platform=platform, num_data=num_data,
+                     num_bin_max=num_bin_max, quantized=quantized,
+                     hist_dtype=dtype, tree_learner=learner,
+                     storage=storage, row_sched=sched, **requests)
+
+
+def _case_id(case):
+    platform, num_data, dtype, quantized, learner, storage, sched, \
+        num_bin_max, requests, _ = case
+    asked = ",".join(f"{k}={v}" for k, v in requests.items()) or "auto"
+    return (f"{platform}-{num_data}-{dtype}{'-q' if quantized else ''}-"
+            f"{learner}-{storage}-{sched}-{num_bin_max}-{asked}")
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_plan_table(case):
+    p = _plan(case)
+    assert (p.hist_rm_backend, p.level_hist_backend, p.partition_mode,
+            p.pack, p.hist_reduce) == case[-1]
+
+
+def test_plan_notes_say_what_was_remapped():
+    """The two requests the plan does not grant as asked each leave one
+    line for the engine to log; a granted plan leaves none."""
+    assert _plan(CASES[0]).notes == ()
+    level = make_plan(platform="tpu", num_data=M2, num_bin_max=255,
+                      quantized=False, hist_dtype="float32",
+                      tree_learner="serial", storage="dense",
+                      row_sched="level", hist_kernel="pallas_level")
+    assert [lvl for lvl, _ in level.notes] == ["info"]
+    assert "level-phase" in level.notes[0][1]
+    wide = make_plan(platform="tpu", num_data=M2, num_bin_max=256,
+                     quantized=False, hist_dtype="float32",
+                     tree_learner="serial", storage="dense",
+                     row_sched="compact")
+    assert [lvl for lvl, _ in wide.notes] == ["warning"]
+    assert "num_bin_max=256" in wide.notes[0][1]
+    # a learner that never packs is not warned about widths
+    assert make_plan(platform="tpu", num_data=M2, num_bin_max=256,
+                     quantized=False, hist_dtype="float32",
+                     tree_learner="data", storage="dense",
+                     row_sched="compact").notes == ()
+
+
+def test_engine_runs_the_plan():
+    """The engine's GrowerConfig carries what the plan says for its own
+    inputs (here: the CPU, a small table, packing asked for)."""
+    import jax
+    import numpy as np
+    import lightgbm_tpu as lgb
+
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(600, 5)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    bst = lgb.train({"objective": "binary", "num_leaves": 7,
+                     "verbosity": -1, "min_data_in_leaf": 5,
+                     "tpu_packed_bins": "true"},
+                    lgb.Dataset(X, label=y), num_boost_round=1)
+    eng = bst._engine
+    want = make_plan(platform=jax.default_backend(), num_data=600,
+                     num_bin_max=eng.num_bin_max, quantized=False,
+                     hist_dtype="float32", tree_learner="serial",
+                     storage="dense", row_sched="compact",
+                     packed_bins="true")
+    g = eng.grower_cfg
+    assert (g.hist_rm_backend, g.level_hist_backend, g.partition_mode,
+            eng._packed_cols > 0) == \
+        (want.hist_rm_backend, want.level_hist_backend,
+         want.partition_mode, want.pack)
+    assert eng._plan == want

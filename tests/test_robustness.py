@@ -17,7 +17,6 @@ harness:
 - tpu_fallback_to_cpu completes training when the device probe never
   succeeds.
 """
-import json
 import os
 import threading
 
@@ -674,54 +673,6 @@ def test_probe_retries_then_succeeds(monkeypatch):
                                             base_delay=0.001,
                                             max_delay=0.01))
     assert out >= 1
-
-
-def test_bench_probe_retries_under_shared_policy(monkeypatch, capsys):
-    """bench.py: UNAVAILABLE probe children are retried under the
-    shared RetryPolicy; rc=4 device_unreachable is reported only after
-    the policy's deadline/attempts budget is spent (multiple attempts,
-    not the old single-shot failure)."""
-    import importlib.util
-    import subprocess
-    spec = importlib.util.spec_from_file_location(
-        "bench_retry_test",
-        os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench.BENCH_WATCHDOG_SEC = 8    # reserve=4s -> 4s probe deadline
-
-    attempts = []
-
-    class _FakeProc:
-        pid = 1
-
-        def poll(self):
-            return 1
-
-    class _FakeChild:
-        """Probe child that dies with the UNAVAILABLE recovery
-        signature (post-ISSUE-4 spawn surface: _ChildSpawn +
-        watch_child instead of _spawn)."""
-
-        def __init__(self, env_extra, tag, partial=False):
-            attempts.append(tag)
-            self.hb_path = "/nonexistent.hb"
-            self.partial_path = ""
-            self.proc = _FakeProc()
-
-        def read_streams(self):
-            return "", "UNAVAILABLE: TPU backend setup/compile error"
-
-        def cleanup(self):
-            pass
-
-    monkeypatch.setattr(bench, "_ChildSpawn", _FakeChild)
-    monkeypatch.setattr(bench, "watch_child", lambda *a, **k: 1)
-    rc = bench.main()
-    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == bench.RC_DEVICE_UNREACHABLE == 4
-    assert res["status"] == "device_unreachable"
-    assert len(attempts) >= 2       # the policy actually retried
 
 
 def test_probe_nonfallback_raises(rng, monkeypatch):
