@@ -36,6 +36,7 @@ from ..ops.split import (FeatureMeta, SplitHyperParams, SplitRecord,
                          calculate_splitted_leaf_output, forced_split_record,
                          meta_has_categorical, pack_record_rows)
 from ..utils import timer
+from .plan import first_split_dense_rows
 from .tree import TreeArrays
 
 
@@ -186,6 +187,9 @@ class GrowState(NamedTuple):
     slot_map: jnp.ndarray = None    # i32 [L] leaf -> pool slot (-1 miss)
     slot_stamp: jnp.ndarray = None  # i32 [P] last-touch step (-1 free)
     slot_owner: jnp.ndarray = None  # i32 [P] owning leaf (-1 free)
+    # bool scalar: this tree's first split histogrammed its smaller child
+    # in one masked pass over the table in place (compact scheduling)
+    first_dense: jnp.ndarray = None
 
 
 def _set(arr, idx, val, cond):
@@ -744,42 +748,53 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 """Index of the smallest bucket >= n (descending sizes)."""
                 return (jnp.sum(sizes_arr >= n) - 1).astype(jnp.int32)
 
-            def make_part(P):
+            def go_left_of(seg, f, thr, dl, ncat, cbins, colv, fscal):
+                """Which of the rows ``seg`` the split sends left; ``None``
+                is every row in the table's own order, read in place.
+                ``colv`` is the replicated [R] global bin column of the
+                split feature when features are sharded (gathered once
+                per split via fetch_bin_column), else a dummy."""
+                f = jnp.maximum(f, 0)
+                if feat_sharded:
+                    col = colv if seg is None else jnp.take(colv, seg)
+                    col = col.astype(jnp.int32)
+                else:
+                    # the split column as one dense [R] slice, then the
+                    # leaf's rows out of THAT: a gather pays per index by
+                    # where its operand lives, and an [R] column fits on
+                    # chip where the table does not (PERF.md §6, PR 26)
+                    col_idx = b_group[f] if bundled else f
+                    colw = lax.dynamic_index_in_dim(
+                        bins_cm, col_idx // 4 if packed else col_idx,
+                        0, keepdims=False)
+                    col = colw if seg is None else colw[seg]
+                    if packed:
+                        shift = (8 * (col_idx % 4)).astype(col.dtype)
+                        col = (col >> shift) & col.dtype.type(0xFF)
+                    col = col.astype(jnp.int32)
+                    if bundled:
+                        col = decode_bin(col, f)
+                return _go_left_bins(
+                    col, thr, dl, f, pmeta, ncat if has_cat else None,
+                    cbins if has_cat else None, fscal=fscal)
+
+            def make_part(P, dense=False):
                 def part(order, start, rows, f, thr, dl, ncat, cbins,
                          colv, fscal):
                     """Stable two-way partition of the leaf's segment
                     (≡ DataPartition::Split, data_partition.hpp:102).
-                    ``colv`` is the replicated [R] global bin column of the
-                    split feature when features are sharded (gathered once
-                    per split via fetch_bin_column), else a dummy."""
-                    f = jnp.maximum(f, 0)
+                    ``dense`` is a tree's first split: the segment is
+                    every row and ``order`` still the identity, so the
+                    column is read whole and nothing is gathered."""
                     start_c = jnp.clip(start, 0, max(R - P, 0))
                     delta = start - start_c
                     with timer.stage("partition_fetch"):
-                        seg = lax.dynamic_slice(order, (start_c,), (P,))
-                        if feat_sharded:
-                            col = jnp.take(colv, seg).astype(jnp.int32)
+                        if dense:
+                            seg = jnp.arange(R, dtype=jnp.int32)
                         else:
-                            # the split column as one dense [R] slice,
-                            # then the leaf's rows out of THAT: a gather
-                            # pays per index by where its operand lives,
-                            # and an [R] column fits on chip where the
-                            # table does not (PERF.md §6, PR 26)
-                            col_idx = b_group[f] if bundled else f
-                            colw = lax.dynamic_index_in_dim(
-                                bins_cm, col_idx // 4 if packed else col_idx,
-                                0, keepdims=False)
-                            col = colw[seg]
-                            if packed:
-                                shift = (8 * (col_idx % 4)).astype(col.dtype)
-                                col = (col >> shift) & col.dtype.type(0xFF)
-                            col = col.astype(jnp.int32)
-                            if bundled:
-                                col = decode_bin(col, f)
-                        go_left = _go_left_bins(
-                            col, thr, dl, f, pmeta,
-                            ncat if has_cat else None,
-                            cbins if has_cat else None, fscal=fscal)
+                            seg = lax.dynamic_slice(order, (start_c,), (P,))
+                        go_left = go_left_of(None if dense else seg, f, thr,
+                                             dl, ncat, cbins, colv, fscal)
                     with timer.stage("partition_order"):
                         pos = jnp.arange(P, dtype=jnp.int32)
                         valid = (pos >= delta) & (pos < delta + rows)
@@ -815,14 +830,16 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 return part
 
             def make_histb(S):
-                def hb(order, start, rows, ghv):
+                def hb(order, start, rows, ghv, *split):
                     """O(rows_in_leaf) histogram over the gathered segment
                     (≡ indexed Bin::ConstructHistogram, dense_bin.hpp;
                     multival: O(rows_in_leaf * K) over stored nonzeros,
                     ≡ multi_val_sparse_bin.hpp ConstructHistogram).
                     With the local-sums channel the segment's raw gh
                     totals ride along (multival hists lack the
-                    default-bin mass, so totals can't come from them)."""
+                    default-bin mass, so totals can't come from them).
+                    ``split`` is what ``hist_first`` below reads, where
+                    one switch holds both."""
                     with timer.stage("hist_gather"):
                         start_c = jnp.clip(start, 0, max(R - S, 0))
                         delta = start - start_c
@@ -852,8 +869,40 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     return h
                 return hb
 
-            part_branches = [make_part(P) for P in sizes]
+            def hist_first(order, start, rows, ghv, left, f, thr, dl,
+                           ncat, cbins, colv, fscal):
+                """The smaller child of a tree's first split, as one pass
+                over the table in place with ``gh`` zero outside the child:
+                no row gathered. The child's rows are the split's own
+                ``go_left`` (``left``) or the rest, every row being in the
+                root; the column is read a second time rather than carried
+                out of the partition's branch (one [R] slice)."""
+                with timer.stage("hist_gather"):
+                    go_left = go_left_of(None, f, thr, dl, ncat, cbins,
+                                         colv, fscal)
+                    ghw = ghv * (go_left == left).astype(ghv.dtype)[:, None]
+                with timer.stage("hist_kernel"):
+                    h = hist_leaf(bins_t.T, ghw)
+                    if local_pool:
+                        return h, jnp.sum(ghw.astype(hist_dtype), axis=0)
+                return h
+
+            # the last partition branch is a tree's first split, where
+            # ``order`` is still the identity: same integers out, nothing
+            # gathered (both the root and a hybrid handoff at step 0 start
+            # from arange(R); a handoff further on never sees step 0)
+            part_branches = [make_part(P) for P in sizes] + \
+                [make_part(R, dense=True)]
             hist_branches = [make_histb(S) for S in sizes]
+            # where the kernel reads the table in place, that split's
+            # smaller child takes ``hist_first`` once its bucket is over
+            # the rule's line (core/plan.py): from this many rows up, the
+            # largest bucket that keeps the gathered call. Every other
+            # backend pays per row of its input and keeps the gathered call
+            if words_kernel:
+                dense_from = max((S for S in sizes if S <=
+                                  first_split_dense_rows(R, Wp, Fp)),
+                                 default=0)
 
         if use_ic:
             # bool [G, F]: membership of each interaction group
@@ -890,6 +939,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             # hybrid handoff: the level phase committed `start_step`
             # splits; resume the sequential loop from its state
             state, start_step = init
+            if state.first_dense is None:
+                state = state._replace(first_dense=jnp.asarray(False))
         else:
             start_step = 0
             # ---- root (ref: LeafSplits::Init + first FindBestSplits) ----
@@ -995,6 +1046,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 leaf_fhi=(jnp.broadcast_to(
                     meta.num_bin.astype(jnp.int32)[None, :] - 1,
                     (L, F)).copy() if use_mc_inter else None),
+                first_dense=jnp.asarray(False),
             )
 
         def body(i, state: GrowState) -> GrowState:
@@ -1006,6 +1058,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             l = jnp.argmax(cand).astype(jnp.int32)
             gain = cand[l]
             forced_ok = state.forced_ok
+            first_dense = state.first_dense
 
             if forced is not None:
                 # forced-prefix step: split forced_slot[i] at the given
@@ -1147,15 +1200,16 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 # to static constants: zero runtime ops.
                 fscal = _feature_meta_scalars(pmeta, rec.feature)
 
+                first = i == 0
+                split = (rec.feature, rec.threshold, rec.default_left,
+                         rec.num_cat if has_cat else jnp.int32(0),
+                         rec.cat_bins if has_cat else
+                         jnp.full((1,), -1, jnp.int32), colv, fscal)
+
                 def do_partition():
-                    pb = bucket_branch(rows_l)
-                    ncat_a = rec.num_cat if has_cat else jnp.int32(0)
-                    cbins_a = rec.cat_bins if has_cat else \
-                        jnp.full((1,), -1, jnp.int32)
-                    return lax.switch(
-                        pb, part_branches, state.order, start_l, rows_l,
-                        rec.feature, rec.threshold, rec.default_left,
-                        ncat_a, cbins_a, colv, fscal)
+                    pb = jnp.where(first, len(sizes), bucket_branch(rows_l))
+                    return lax.switch(pb, part_branches, state.order,
+                                      start_l, rows_l, *split)
 
                 def part_and_both():
                     """Partition the leaf and histogram BOTH children
@@ -1257,28 +1311,36 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                         s_start = start_l + jnp.where(lsm, 0, nL)
                         s_rows = jnp.where(lsm, nL, nR)
                         sb = bucket_branch(s_rows)
-                        hs = lax.switch(sb, hist_branches, order2,
-                                        s_start, s_rows, gh)
+                        if words_kernel:
+                            dense = first & (s_rows > dense_from)
+                            hs = lax.switch(
+                                jnp.where(dense, len(sizes), sb),
+                                hist_branches + [hist_first], order2,
+                                s_start, s_rows, gh, lsm, *split)
+                        else:
+                            dense = jnp.asarray(False)
+                            hs = lax.switch(sb, hist_branches, order2,
+                                            s_start, s_rows, gh)
                         if local_pool:
-                            return (order2, nL, lsm) + hs
-                        return order2, nL, lsm, hs
+                            return (order2, nL, lsm, dense) + hs
+                        return order2, nL, lsm, dense, hs
 
                     if local_pool:
-                        (order, nL_raw, left_smaller, hist_small,
-                         small_lsum) = lax.cond(
+                        (order, nL_raw, left_smaller, took_dense,
+                         hist_small, small_lsum) = lax.cond(
                             proceed, do_part_hist,
                             lambda: (state.order, jnp.int32(0),
-                                     jnp.asarray(True),
+                                     jnp.asarray(True), jnp.asarray(False),
                                      jnp.zeros((Fp, B, 3), hist_dtype),
                                      jnp.zeros((3,), hist_dtype)))
                     else:
-                        order, nL_raw, left_smaller, hist_small = \
-                            lax.cond(
-                                proceed, do_part_hist,
-                                lambda: (state.order, jnp.int32(0),
-                                         jnp.asarray(True),
-                                         jnp.zeros((Fp, B, 3),
-                                                   hist_dtype)))
+                        (order, nL_raw, left_smaller, took_dense,
+                         hist_small) = lax.cond(
+                            proceed, do_part_hist,
+                            lambda: (state.order, jnp.int32(0),
+                                     jnp.asarray(True), jnp.asarray(False),
+                                     jnp.zeros((Fp, B, 3), hist_dtype)))
+                    first_dense = state.first_dense | took_dense
                     if distributed:
                         pick = lambda a, b: jnp.where(left_smaller, a, b)
                         small_ctx = (pick(rec.left_sum_gradient,
@@ -1738,7 +1800,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 path_mask=path_mask, forced_ok=forced_ok, order=order,
                 seg=seg, leaf_flo=leaf_flo, leaf_fhi=leaf_fhi,
                 lsum=lsum, slot_map=slot_map, slot_stamp=slot_stamp,
-                slot_owner=slot_owner)
+                slot_owner=slot_owner, first_dense=first_dense)
 
         state = lax.fori_loop(start_step, L - 1, body, state)
 
@@ -1768,6 +1830,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             shrinkage=jnp.asarray(1.0, jnp.float32),
             cat_count=i32c(N_CCNT) if has_cat else None,
             cat_bins=state.tree_cat,
+            first_split_dense=state.first_dense.astype(jnp.int32),
         )
         if compact:
             # rebuild per-row leaf ids from the final segments: mark each

@@ -16,14 +16,21 @@ the traced program from static shapes (``core/grower.py``, ``grow``):
 ``words_kernel`` (packed words go to ``hist_pallas_words`` as the table
 stores them when the backend is ``pallas``; every other backend gets
 ``unpack_rows``), and ``partition_mode="auto"``'s ``lax.sort`` for buckets
-of 32,768 rows and up, cumsum-scatter below. What needs the engine's state
-stays with the engine: the scheduler's eligibility (``_level_ineligibility``),
+of 32,768 rows and up, cumsum-scatter below. One choice is made per split,
+at run time, from what the device sees: a tree's first split, where
+``order`` is still the identity, reads its column in place, and histograms
+its smaller child in one masked pass over the table in place when the
+child's bucket holds more rows than ``first_split_dense_rows`` below says
+(the rule is here, with its constants; the grower applies it to the
+child's row count). What needs the engine's state stays with the engine:
+the scheduler's eligibility (``_level_ineligibility``),
 the collective's (``_resolve_hist_reduce_mode``), async boosting
 (``_async_on``), the histogram pool's budget.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Tuple
 
 # The row count from which a chip's `auto` takes the measured combination.
@@ -55,7 +62,39 @@ HIST_REDUCE = "allreduce"
 # four uint8 bins to a word: a wider bin does not fit
 PACK_MAX_BIN = 255
 
+# The first split's smaller child: gathered, or one masked pass over the
+# table in place. Prices in ns on a v5e, from `criteo-share.train` at 17
+# packed words and 67 columns (ledger, PR 31: `train.stage.hist_gather_ms`
+# 275.40, `train.stage.hist_kernel_ms` 115.62; PERF.md section 5 has the
+# parts) and `msltr.train` at 35 words and 137 columns (ledger, PR 27:
+# `train.stage.hist_gather_ms` 477.58, `train.stage.hist_kernel_ms` 252.28).
+# The packed-row gather out of HBM pays per index, and a little per word:
+# 239.5 ms over 7.9 M padded indices = 30.3 ns at 17 words, and the PR 27
+# line at 35 words puts the two parts at 20.6 ns an index + 0.55 ns a word.
+ROW_GATHER_NS_INDEX = 20.6
+ROW_GATHER_NS_WORD = 0.55
+# the gh rows f32[S,3] out of VMEM: 34.3 ms over the same 7.9 M indices
+GH_GATHER_NS_INDEX = 4.3
+# the Pallas kernel, whatever the bucket: 11.65 ns a row at 67 columns (the
+# root's call 23.3 ms for 2 M rows read in place), 0.174 ns a column a row
+KERNEL_NS_COLUMN_ROW = 0.174
+
 _TRUTHY = ("true", "1", "yes", "on")
+
+
+def first_split_dense_rows(num_rows: int, num_words: int,
+                           num_cols: int) -> int:
+    """The most rows a bucket may hold and keep the gathered call at a
+    tree's first split. The dense pass costs ``num_rows`` rows of the
+    kernel; the gathered call costs the bucket's rows of the gather and of
+    the kernel: dense when ``bucket x (gather + kernel) > num_rows x
+    kernel``. A wider table moves the line up, towards half the rows
+    (about R/7 at 28 columns, R/4 at 67, R/3 at 137, R/2 at 700): rough,
+    two widths priced it."""
+    kernel = KERNEL_NS_COLUMN_ROW * num_cols
+    gather = (ROW_GATHER_NS_INDEX + ROW_GATHER_NS_WORD * num_words +
+              GH_GATHER_NS_INDEX)
+    return math.floor(num_rows * kernel / (gather + kernel))
 
 
 @dataclasses.dataclass(frozen=True)

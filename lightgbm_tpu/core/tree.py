@@ -83,6 +83,11 @@ class TreeArrays(NamedTuple):
     # (ops/predict.py). None for grower-built device trees (the grower
     # never traverses its own output; depth is computed on the host copy)
     max_depth: jnp.ndarray = None  # i32 scalar
+    # 1 where the sequential grower histogrammed the first split's smaller
+    # child in one masked pass over the table in place (core/grower.py),
+    # 0 where it gathered the child's rows; None from every other maker.
+    # What the device decided, for utils/timer's count; no part of the model
+    first_split_dense: jnp.ndarray = None  # i32 scalar
 
     @staticmethod
     def empty(max_leaves: int, max_cat: int = 0) -> "TreeArrays":
@@ -144,6 +149,7 @@ class HostTree:
         self.shrinkage = float(a["shrinkage"])
         self.max_depth = max_leaf_depth(self.left_child, self.right_child,
                                         self.num_leaves)
+        self.first_split_dense = bool(a.get("first_split_dense", 0))
         # per-node category-BIN sets from the grower (inner representation,
         # ref: cat_threshold_inner_); -1 padded, empty for numerical nodes
         if "cat_bins" in a and n_int:
@@ -188,6 +194,7 @@ class HostTree:
         self.leaf_parent = np.full(1, -1, np.int32)
         self.shrinkage = 1.0
         self.max_depth = 0
+        self.first_split_dense = False
         self.threshold_real = np.zeros(0, np.float64)
         self.decision_type = np.zeros(0, np.int32)
         self.is_linear = False

@@ -2447,7 +2447,11 @@ class GBDT:
     def _finalize_tree(self, host: HostTree) -> None:
         """Resolve bin thresholds to real values and pack decision_type bits
         (ref: tree.h kCategoricalMask=1, kDefaultLeftMask=2, missing type in
-        bits 2-3; Tree::Split stores RealThreshold = bin upper bound)."""
+        bits 2-3; Tree::Split stores RealThreshold = bin upper bound).
+        Every tree a grower grew passes here once, on the host: the place
+        of the tracing's two counters (utils/timer.py)."""
+        global_timer.count("trees")
+        global_timer.count("first_split_dense", host.first_split_dense)
         mappers = self.train_set.bin_mappers
         n_int = host.num_leaves - 1
         thr_real = np.zeros(n_int, np.float64)

@@ -9,9 +9,14 @@ profiler session; inside one (``tpu_profile_dir``, the benchmark's traced
 run) the span lies in the xplane's host plane, on the device trace's
 clock — and (b) a record ``(name, start, end, parent, iteration)`` on
 ``time.perf_counter`` in a bounded deque, beside totals and counts that
-``table()`` prints. ``LIGHTGBM_TPU_TIMETAG`` (or ``global_timer.enabled =
-True``) turns on only the ``sync=`` barrier and the table printed at the
-end of training.
+``table()`` prints. Under the sections ``table()`` prints the **counters**
+(``global_timer.count(name)``): what the device decided, read off what comes
+to the host anyway. There are two, counted where a grown tree becomes a
+``HostTree`` (``models/gbdt.py``): ``trees``, and ``first_split_dense``, those
+whose first split histogrammed its smaller child in one masked pass over the
+table in place (``TreeArrays.first_split_dense``, ``core/grower.py``).
+``LIGHTGBM_TPU_TIMETAG`` (or ``global_timer.enabled = True``) turns on only
+the ``sync=`` barrier and the table printed at the end of training.
 
 What a section measures: on the synchronous path with ``enabled``, the
 section blocks on its ``sync=`` value, so it holds the device's seconds. On
@@ -91,6 +96,7 @@ class Timer:
         self.enabled = bool(os.environ.get("LIGHTGBM_TPU_TIMETAG"))
         self._total = defaultdict(float)
         self._count = defaultdict(int)
+        self.counters = defaultdict(int)
         self.records = collections.deque(maxlen=MAX_RECORDS)
         self._open = _OpenSections()
 
@@ -119,21 +125,34 @@ class Timer:
             self._count[name] += 1
             self.records.append(Record(name, t0, t1, parent, iteration))
 
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the counter ``name``."""
+        self.counters[name] += int(n)
+
     def reset(self) -> None:
         self._total.clear()
         self._count.clear()
+        self.counters.clear()
         self.records.clear()
 
     def table(self) -> str:
-        """Render the aggregate table (ref: Timer::Print, common.h:1013)."""
-        if not self._total:
+        """Render the aggregate table (ref: Timer::Print, common.h:1013),
+        then the counters in the order they were first counted."""
+        if not self._total and not self.counters:
             return "(no timing sections recorded)"
-        width = max(len(k) for k in self._total)
-        lines = [f"{'section'.ljust(width)}   total(s)      count    mean(ms)"]
+        width = max(len(k) for k in (*self._total, *self.counters))
+        lines = []
+        if self._total:
+            lines.append(
+                f"{'section'.ljust(width)}   total(s)      count    mean(ms)")
         for name in sorted(self._total, key=self._total.get, reverse=True):
             t, c = self._total[name], self._count[name]
             lines.append(f"{name.ljust(width)} {t:10.3f} {c:10d} "
                          f"{1e3 * t / max(c, 1):11.3f}")
+        if self.counters:
+            lines.append(f"{'counter'.ljust(width)} {'count':>10}")
+        for name, c in self.counters.items():
+            lines.append(f"{name.ljust(width)} {c:10d}")
         return "\n".join(lines)
 
     def print(self) -> None:

@@ -19,6 +19,7 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.bundling import find_bundles, pack_bins
 from lightgbm_tpu.io.dataset_core import BinnedDataset
 from lightgbm_tpu.ops.split import FeatureMeta, SplitHyperParams
+from lightgbm_tpu.core import grower as grower_mod
 from lightgbm_tpu.core.grower import GrowerConfig, make_tree_grower
 from lightgbm_tpu.core.tree import HostTree
 
@@ -199,7 +200,9 @@ def _tables(rng, case, L=16, **grower):
     row-major uint8 [R, Fp], gh, the raw matrix X)."""
     X, y, opt = case(rng)
     ds = BinnedDataset.from_matrix(
-        X, Config({"num_leaves": L, "min_data_in_leaf": 5}), label=y)
+        X, Config({"num_leaves": L, "min_data_in_leaf": 5,
+                   **opt.get("params", {})}), label=y,
+        categorical_features=opt.get("categorical", ()))
     mappers = ds.used_bin_mappers()
     meta = FeatureMeta.from_mappers(mappers)
     B = int(max(m.num_bin for m in mappers))
@@ -286,7 +289,8 @@ def test_partition_fetch_packed_unpacked_full_agree(rng, case,
 
 @pytest.mark.parametrize("quantized", [False, True])
 @pytest.mark.parametrize("case", [_odd_columns, _efb, _clipped_start])
-def test_words_kernel_packed_unpacked_agree(rng, case, quantized):
+def test_words_kernel_packed_unpacked_agree(rng, case, quantized,
+                                            monkeypatch):
     """With the Pallas kernel (interpreted here) packed words go to it as
     they are gathered and it takes each byte out itself; unpacked uint8
     rows go through ``hist_pallas_rm``. Same row blocks, same sums: the
@@ -297,9 +301,169 @@ def test_words_kernel_packed_unpacked_agree(rng, case, quantized):
         rng, case, L=8, hist_rm_backend="pallas", quantized=quantized,
         stochastic_rounding=False)
     unpacked = _grow_with_order(compact, meta, bundle, jnp.asarray(rm), gh)
+    # this is about the two kernels on the same row blocks: the first
+    # split's masked pass over the table (packed words only, other row
+    # blocks: tested below) stays out of it
+    monkeypatch.setattr(grower_mod, "first_split_dense_rows",
+                        lambda rows, words, cols: rows)
     packed = _grow_with_order(
         dataclasses.replace(compact, packed_cols=rm.shape[1]), meta, bundle,
         jnp.asarray(pack_words(rm)), gh)
     _assert_same_growth(packed, unpacked)
     assert packed[0].num_leaves == 8
     _assert_case_exercised(case, packed[0], bundle, X)
+
+
+# ---- the first split runs dense --------------------------------------------
+
+def _categorical(rng):
+    # a 9-valued categorical column whose odd values carry the signal
+    n = 3000
+    X = rng.normal(size=(n, 6))
+    X[:, 0] = rng.integers(0, 9, size=n)
+    y = 3.0 * (X[:, 0] % 2) + 0.2 * X[:, 3]
+    return X, y, {"categorical": [0]}
+
+
+def _default_bin(rng):
+    # zeros are missing values (missing_type "zero"): they sit in the
+    # column's default bin, carry the low labels, and the root sends them
+    # left by ``default_left``
+    n = 3000
+    X = rng.normal(size=(n, 6))
+    X[:, 5] = np.abs(X[:, 5]) + 0.5
+    X[rng.random(n) < 0.4, 5] = 0.0
+    y = np.where(X[:, 5] == 0.0, -4.0, X[:, 5]) + 0.1 * X[:, 1]
+    return X, y, {"params": {"zero_as_missing": True}}
+
+
+def _lopsided(rng):
+    # the root split sends 1 % of the rows one way
+    X = rng.normal(size=(3000, 6))
+    X[:, 2] = rng.random(3000) < 0.01
+    y = 50.0 * X[:, 2] + 0.2 * X[:, 0]
+    return X, y, {"min_bucket": 32}
+
+
+@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("case", [_odd_columns, _categorical, _efb,
+                                  _default_bin])
+def test_first_split_partition_in_place(rng, case, packed):
+    """A tree's first split reads its column in place (``order`` is the
+    identity there): ``order`` after it is the stable partition of the rows
+    by the leaf the full-row grower, which gathers nothing and keeps no
+    ``order``, gives each, and ``nL`` its left count: the same integers as
+    the gathered partition wrote, on a numerical, a categorical and a
+    bundled column and with ``default_left`` deciding the default bin."""
+    compact, meta, bundle, phys, rm, gh, X = _tables(rng, case, L=2)
+    if packed:
+        compact = dataclasses.replace(compact, packed_cols=rm.shape[1])
+        rm = pack_words(rm)
+    tree, leaf_id, order = _grow_with_order(compact, meta, bundle,
+                                            jnp.asarray(rm), gh)
+    t_f, l_f = jax.jit(make_tree_grower(
+        dataclasses.replace(compact, row_sched="full", packed_cols=0), meta,
+        bundle=bundle))(jnp.asarray(phys), gh)
+    l_f = np.asarray(l_f)
+    assert tree.num_leaves == 2
+    np.testing.assert_array_equal(leaf_id, l_f)
+    np.testing.assert_array_equal(order, np.argsort(l_f, kind="stable"))
+    assert tree.leaf_count[0] == (l_f == 0).sum()          # nL
+    assert 0 < tree.leaf_count[0] < X.shape[0]
+    f = int(tree.split_feature[0])
+    if case is _categorical:
+        assert tree.cat_count[0] > 0
+    if case is _efb:
+        group = np.asarray(bundle["group"])
+        assert np.bincount(group)[group[f]] > 1
+    if case is _default_bin:
+        assert f == 5 and tree.default_left[0]
+        assert int(np.asarray(meta.missing_type)[f]) == 1
+        assert ((X[:, 5] == 0.0) == (l_f == 0)).all()
+
+
+def _same_tree(a, b, exact):
+    """Structure, thresholds and counts equal; values to float32's
+    tolerance, or every array bit for bit."""
+    for name in a._fields:
+        x, y = getattr(a, name), getattr(b, name)
+        if x is None or name == "first_split_dense":
+            continue
+        if exact or x.dtype.kind in "ib":
+            np.testing.assert_array_equal(x, y, err_msg=name)
+        elif name.endswith("_count"):
+            np.testing.assert_array_equal(x, y, err_msg=name)
+        else:
+            np.testing.assert_allclose(x, y, rtol=2e-5, atol=1e-6,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("case", [_odd_columns, _efb, _clipped_start])
+def test_first_split_dense_trees_match_gathered(rng, case, quantized,
+                                                monkeypatch):
+    """Whole trees on the words kernel (interpreted), 3000 and 2500 rows:
+    the first split's smaller child as one masked pass over the table in
+    place against the same child gathered. The same rows are summed in
+    another order: structure, thresholds, counts, ``leaf_id`` and ``order``
+    equal, values to float32's tolerance, and on int8 gradients every
+    array bit for bit."""
+    compact, meta, bundle, _, rm, gh, X = _tables(
+        rng, case, L=8, hist_rm_backend="pallas", quantized=quantized,
+        stochastic_rounding=False)
+    compact = dataclasses.replace(compact, packed_cols=rm.shape[1])
+    words = jnp.asarray(pack_words(rm))
+    dense = _grow_with_order(compact, meta, bundle, words, gh)
+    monkeypatch.setattr(grower_mod, "first_split_dense_rows",
+                        lambda rows, words, cols: rows)
+    gathered = _grow_with_order(compact, meta, bundle, words, gh)
+    assert dense[0].first_split_dense == 1
+    assert gathered[0].first_split_dense == 0
+    np.testing.assert_array_equal(dense[2], gathered[2])
+    np.testing.assert_array_equal(dense[1], gathered[1])
+    _same_tree(dense[0], gathered[0], exact=quantized)
+    assert dense[0].num_leaves == 8
+    _assert_case_exercised(case, dense[0], bundle, X)
+
+
+@pytest.mark.parametrize("case,backend,resume,dense", [
+    (_odd_columns, "pallas", False, 1),      # balanced: the masked pass
+    (_lopsided, "pallas", False, 0),         # 1 % / 99 %: gathered
+    (_odd_columns, "scatter", False, 0),     # no kernel that reads in place
+    (_odd_columns, "scatter", True, 0),      # hybrid handoff past step 0
+], ids=["balanced", "lopsided", "scatter", "hybrid_resume"])
+def test_first_split_rule(rng, case, backend, resume, dense):
+    """What the first split takes, from what the code sees: the masked
+    pass where the kernel reads the table in place and the smaller
+    child's bucket is over the rule's line; the gathered call for a
+    lopsided split and for a backend that pays per row (its partition is
+    in place all the same); neither once a hybrid handoff resumes past
+    step 0, where ``order`` is an argsort. The tree is the full-row
+    grower's in every case."""
+    compact, meta, bundle, phys, rm, gh, X = _tables(
+        rng, case, L=8, hist_rm_backend=backend)
+    R = rm.shape[0]
+    assert R & (R - 1)                        # not a power of two
+    t_f, l_f = jax.jit(make_tree_grower(
+        dataclasses.replace(compact, row_sched="full"), meta,
+        bundle=bundle))(jnp.asarray(phys), gh)
+    if resume:
+        from lightgbm_tpu.core.hybrid_grower import make_hybrid_grower
+        tree, leaf_id = jax.tree.map(np.asarray, jax.jit(make_hybrid_grower(
+            compact, meta, bundle=bundle, handoff_depth=2))(
+                jnp.asarray(rm), gh))
+    else:
+        tree, leaf_id, _ = _grow_with_order(
+            dataclasses.replace(compact, packed_cols=rm.shape[1]), meta,
+            bundle, jnp.asarray(pack_words(rm)), gh)
+    np.testing.assert_array_equal(leaf_id, np.asarray(l_f))
+    assert tree.first_split_dense == dense
+    assert tree.num_leaves == 8
+    if case is _lopsided:
+        # the smaller child's bucket is under the rule's line of 115 rows
+        sides = [tree.internal_count[c] if c >= 0 else tree.leaf_count[~c]
+                 for c in (int(tree.left_child[0]),
+                           int(tree.right_child[0]))]
+        assert int(tree.split_feature[0]) == 2 and min(sides) <= 64
+        assert grower_mod.first_split_dense_rows(R, 2, 6) >= 64
+    _same_tree(tree, jax.tree.map(np.asarray, t_f), exact=False)

@@ -205,3 +205,51 @@ def test_engine_runs_the_plan():
         (want.hist_rm_backend, want.level_hist_backend,
          want.partition_mode, want.pack)
     assert eng._plan == want
+
+
+# ---- the first split's rule ------------------------------------------------
+
+# (packed words, columns) -> the rows of the table that one of the rule's
+# lines lies at: a bucket over it takes the masked pass over the table
+FIRST_SPLIT_LINES = [
+    (7, 28, 6.90),      # chip_smoke.py's table: about R/7
+    (17, 67, 3.94),     # criteo-share: about R/4
+    (35, 137, 2.85),    # MS LTR's width (ledger, PR 27): about R/3
+    (175, 700, 1.99),   # Expo's width: about R/2
+]
+
+
+@pytest.mark.parametrize("words,cols,share", FIRST_SPLIT_LINES,
+                         ids=[f"{w}words" for w, _, _ in FIRST_SPLIT_LINES])
+def test_first_split_rule_line(words, cols, share):
+    from lightgbm_tpu.core.plan import first_split_dense_rows
+    line = first_split_dense_rows(M2, words, cols)
+    assert M2 / line == pytest.approx(share, abs=0.01)
+    # in the cell's ladder of buckets: both buckets a smaller child of
+    # more than 262,144 rows can fall into are over the line at 67
+    # columns and under; only the upper one from 137 columns on
+    over = [b for b in (1048576, 524288, 262144) if b > line]
+    assert over == ([1048576, 524288] if cols <= 67 else [1048576])
+    # the line scales with the rows and no bucket of a tiny child passes
+    assert first_split_dense_rows(M2 // 2, words, cols) == \
+        pytest.approx(line / 2, abs=1)
+
+
+def test_first_split_rule_rises_with_the_width():
+    """A wider row makes the kernel's pass over the whole table dearer
+    faster than it makes the gather dearer: the line moves up, towards
+    half the rows, and each of its constants names the ledger line it
+    stands on."""
+    import inspect
+    from lightgbm_tpu.core import plan
+    lines = [plan.first_split_dense_rows(M2, -(-c // 4), c)
+             for c in range(4, 2049, 4)]
+    assert all(a < b for a, b in zip(lines, lines[1:]))
+    assert 0 < lines[0] and lines[-1] < M2
+    source = inspect.getsource(plan)
+    above = source[:source.index("def first_split_dense_rows")]
+    for constant in ("ROW_GATHER_NS_INDEX", "ROW_GATHER_NS_WORD",
+                     "GH_GATHER_NS_INDEX", "KERNEL_NS_COLUMN_ROW"):
+        assert f"\n{constant} = " in above
+    block = above[above.index("# The first split's smaller child"):]
+    assert "ledger, PR 31" in block and "ledger, PR 27" in block

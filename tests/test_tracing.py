@@ -356,3 +356,65 @@ def test_sync_blocks_only_when_enabled(enabled):
     with t.section("step", sync=lambda: asked.append(1) or ()):
         pass
     assert bool(asked) == enabled
+
+
+# ---- the counters: how often the first split ran dense ---------------------
+
+def lopsided_table(rows=3000, cols=10):
+    """One column sends 1 % of the rows one way and decides the label."""
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(rows, cols)).astype(np.float32)
+    X[:, 3] = rng.random(rows) < 0.01
+    return X, X[:, 3].copy()
+
+
+@pytest.mark.parametrize("make,steps,dense", [(table, 3, 3),
+                                              (lopsided_table, 1, 0)],
+                         ids=["balanced", "lopsided"])
+def test_first_split_dense_is_counted_beside_the_trees(make, steps, dense):
+    """``first_split_dense`` / ``trees``: on a balanced table every tree's
+    first split histograms its smaller child in one masked pass over the
+    table in place (the words kernel, interpreted here), on a lopsided
+    one none does; the table prints both. The flag comes to the host in
+    the fetch that brings the tree, ``Tree::ToHost``: the asynchronous
+    path fetches what it fetched, as often as it did."""
+    X, y = make()
+    counters = timer.global_timer.counters
+    before = dict(trees=counters["trees"],
+                  first_split_dense=counters["first_split_dense"])
+    booster = lgb.Booster({**PARAMS, "tpu_hist_kernel": "pallas",
+                           "min_data_in_leaf": 5, "tpu_min_bucket": 32},
+                          lgb.Dataset(X, label=y))
+    engine = booster._engine
+    assert engine._async_on() and engine.grower_cfg.packed_cols
+    fetched = []
+    with mock.patch.object(jax, "device_get", side_effect=lambda x: (
+            fetched.append(x), jax.tree.map(np.asarray, x))[1]):
+        for _ in range(steps):
+            booster.update()
+        assert counters["trees"] == before["trees"]    # nothing came yet
+        trees = engine.models                          # the flush
+    assert len(trees) == steps and all(t.num_leaves > 1 for t in trees)
+    assert counters["trees"] - before["trees"] == steps
+    assert counters["first_split_dense"] - before["first_split_dense"] \
+        == dense
+    assert [t.first_split_dense for t in trees] == [bool(dense)] * steps
+    # one fetch of the leaf counts (the stop check) and one of the trees
+    kinds = [type(x).__name__ for x in fetched]
+    assert kinds.count("TreeArrays") == 1 and len(fetched) == 2, kinds
+    lines = timer.global_timer.table().splitlines()
+    at = lines.index(next(ln for ln in lines if ln.split() ==
+                          ["counter", "count"]))
+    assert {ln.split()[0]: int(ln.split()[1]) for ln in lines[at + 1:]} \
+        == dict(counters)
+
+
+def test_table_prints_counters_without_sections():
+    t = timer.Timer()
+    assert t.table() == "(no timing sections recorded)"
+    t.count("trees", 3)
+    t.count("first_split_dense")
+    assert [ln.split() for ln in t.table().splitlines()] == [
+        ["counter", "count"], ["trees", "3"], ["first_split_dense", "1"]]
+    t.reset()
+    assert t.table() == "(no timing sections recorded)"
