@@ -36,7 +36,7 @@ from ..ops.split import (FeatureMeta, SplitHyperParams, SplitRecord,
                          calculate_splitted_leaf_output, forced_split_record,
                          meta_has_categorical, pack_record_rows)
 from ..utils import timer
-from .plan import first_split_dense_rows
+from .plan import first_split_dense_rows, rows_held_twice
 from .tree import TreeArrays
 
 
@@ -733,6 +733,15 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     block_rows=cfg.block_rows, dtype=cfg.hist_dtype)
             else:
                 hist_leaf = hist_rm
+            # a table of wide rows is held twice (core/plan.py,
+            # ``rows_held_twice``): the barrier makes the row gather's
+            # operand a value of its own, so the compiler lays it out
+            # row-major once, before the split loop, where it would
+            # otherwise re-lay the word-major table in every branch of the
+            # histogram's switch, once a split (PERF.md section 6, PR 33)
+            held_twice = words_kernel and rows_held_twice(Wp)
+            bins_rows = lax.optimization_barrier(bins_t) if held_twice \
+                else bins_t
 
             def unpack_rows(w):
                 """uint32 [S, Wp] packed words -> int32 [S, Fp] bins, for
@@ -829,7 +838,43 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     return order, nL
                 return part
 
+            # a table held twice gathers at most half its rows at a time,
+            # the largest bucket that holds no more: the gathered block and
+            # its transpose then cost no more than the table's second copy
+            # (at 400,000 x 2,000 the 262,144 bucket's two blocks were 1 GB
+            # of the temporaries, and the peak stood 6 % over the parent's)
+            rows_chunk = max((S for S in sizes if 2 * S <= R), default=R)
+
+            def hist_rows(idx, ghw):
+                """A bucket of a table held twice: whole rows out of the
+                row-major copy (``order`` holds row numbers: none to fill),
+                the block re-laid ``[Wp, n]`` for the kernel, ``rows_chunk``
+                rows at a time, the blocks' histograms added up."""
+                def block(i, g):
+                    with timer.stage("hist_gather"):
+                        blk = jnp.take(bins_rows, i, axis=0, mode="clip").T
+                    return hist_leaf(blk, g)
+
+                n = idx.shape[0]
+                if n <= rows_chunk:
+                    return block(idx, ghw)
+
+                def body(c, h):
+                    part = functools.partial(lax.dynamic_slice_in_dim,
+                                             start_index=c * rows_chunk,
+                                             slice_size=rows_chunk)
+                    return h + block(part(idx), part(ghw))
+
+                first = jax.eval_shape(block, idx[:rows_chunk],
+                                       ghw[:rows_chunk])
+                return lax.fori_loop(0, n // rows_chunk, body,
+                                     jnp.zeros(first.shape, first.dtype))
+
             def make_histb(S):
+                # a table held twice is not gathered whole a third time: the
+                # bucket of every row reads it in place, as ``hist_first``
+                in_place = held_twice and S == R
+
                 def hb(order, start, rows, ghv, *split):
                     """O(rows_in_leaf) histogram over the gathered segment
                     (≡ indexed Bin::ConstructHistogram, dense_bin.hpp;
@@ -839,17 +884,34 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     totals ride along (multival hists lack the
                     default-bin mass, so totals can't come from them).
                     ``split`` is what ``hist_first`` below reads, where
-                    one switch holds both."""
+                    one switch holds both.
+
+                    What the compiled module does with the packed rows (my
+                    TPU compiles, PERF.md section 6, PR 33). At 17 words
+                    (2 M x 67) the gather reads the word-major table the
+                    loop carries, a word at a time, and its ``[S, 17]``
+                    result is relabelled ``[17, S]`` for the kernel. At
+                    500 words (400,000 x 2,000) the gather wants whole
+                    rows: it reads the row-major copy made before the loop
+                    (``bins_rows``; without it the compiler copied the
+                    table in every branch, once a split), writes
+                    ``[S, 500]`` row-major, and a transposing copy of the
+                    bucket makes the kernel's ``[500, S]``; both are in
+                    this stage."""
                     with timer.stage("hist_gather"):
                         start_c = jnp.clip(start, 0, max(R - S, 0))
                         delta = start - start_c
                         idx = lax.dynamic_slice(order, (start_c,), (S,))
-                        if mv_mode:
+                        if in_place:
+                            blk = bins_cm
+                        elif mv_mode:
                             from ..ops.hist_multival import take_rows
                             blk = take_rows(bins_t, idx)
+                        elif held_twice:
+                            blk = None          # ``hist_rows`` gathers it
                         elif words_kernel:
-                            # [Wp, S]: the gather already writes its
-                            # result word-major on the chip
+                            # [Wp, S]: a relabelling of what the gather
+                            # wrote, where it reads the word-major table
                             blk = jnp.take(bins_t, idx, axis=0).T
                         elif packed:
                             # gather packed words (4x fewer elements), unpack
@@ -857,13 +919,21 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                             blk = unpack_rows(jnp.take(bins_t, idx, axis=0))
                         else:
                             blk = jnp.take(bins_t, idx, axis=0)
-                        ghg = jnp.take(ghv, idx, axis=0)
+                        if not in_place:
+                            ghg = jnp.take(ghv, idx, axis=0)
                         pos = jnp.arange(S, dtype=jnp.int32)
                         w = ((pos >= delta) &
-                             (pos < delta + rows)).astype(ghg.dtype)
-                        ghw = ghg * w[:, None]
+                             (pos < delta + rows)).astype(ghv.dtype)
+                        if in_place:
+                            # the bucket is ``order`` whole: the segment's
+                            # weights go to their rows, no row comes to them
+                            ghw = ghv * jnp.zeros(R, ghv.dtype).at[idx].set(
+                                w, unique_indices=True)[:, None]
+                        else:
+                            ghw = ghg * w[:, None]
                     with timer.stage("hist_kernel"):
-                        h = hist_leaf(blk, ghw)
+                        h = hist_rows(idx, ghw) if blk is None else \
+                            hist_leaf(blk, ghw)
                         if local_pool:
                             return h, jnp.sum(ghw.astype(hist_dtype), axis=0)
                     return h

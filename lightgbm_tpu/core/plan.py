@@ -79,6 +79,33 @@ GH_GATHER_NS_INDEX = 4.3
 # root's call 23.3 ms for 2 M rows read in place), 0.174 ns a column a row
 KERNEL_NS_COLUMN_ROW = 0.174
 
+# The packed words of a row from which the compact grower holds the table
+# twice: the narrowest row at which the compiler was seen to re-lay a table
+# held once inside the split loop (my TPU compiles, PR 33,
+# `scripts/tpu_compile_grower.py`, PERF.md section 6). Held once, the
+# word-major table is copied row-major at the head of every branch of the
+# histogram's bucket switch, once a split, at 41, 42, 43, 44, 47, 48, 56, 64,
+# 100, 127, 128, 175, 250 and 500 words a row at 400,000 rows and at 64 at
+# 1 M, and read as it is, a word at a time, at 35 and 40 (400,000 and 1 M
+# rows), at 41 at 1 M and at 17 at 2 M: the line moves with the rows, and
+# what the compiler weighs is not known. Held twice the module has no such
+# copy at any of 35, 41, 64, 100, 127, 128, 175, 250 and 500 words, and
+# smaller temporaries at every one; where the compiler keeps the word-major
+# table (35 words; 41 at 1 M rows) the second value is the same buffer and
+# no copy is made at all. Tables that fit VMEM row-major (200,000 rows: 17,
+# 35 and 64 words) are re-laid there once a split at any width, and are left
+# as they were.
+HELD_TWICE_FROM_WORDS = 41
+# Such a table's rows come whole out of its row-major copy and the bucket is
+# re-laid for the kernel: `epsilon.train` at 500 words and 2,000 columns
+# (my chip run, PR 33; PERF.md section 6: `train.stage.hist_gather_ms` 32.55,
+# the `gh` rows and the transposes in it, over about 1.66 M padded rows a
+# tree) pays 19.6 ns a row all told: the `gh` rows' 4.3 and 0.031 ns a word,
+# a tenth of what the word-major gather's fit would say at that width (300).
+# One width priced it: under 128 words a row-major row is padded to a lane
+# tile, and what the gather pays there was not read.
+ROWS_GATHER_NS_WORD = 0.031
+
 _TRUTHY = ("true", "1", "yes", "on")
 
 
@@ -89,12 +116,28 @@ def first_split_dense_rows(num_rows: int, num_words: int,
     kernel; the gathered call costs the bucket's rows of the gather and of
     the kernel: dense when ``bucket x (gather + kernel) > num_rows x
     kernel``. A wider table moves the line up, towards half the rows
-    (about R/7 at 28 columns, R/4 at 67, R/3 at 137, R/2 at 700): rough,
-    two widths priced it."""
+    (about R/7 at 28 columns, R/4 at 67, R/3 at 137 and at 160); a table
+    held twice (``rows_held_twice``: from 41 words) gathers whole rows for a
+    tenth of that, and the line lies at 0.83 R and over: a smaller child's
+    bucket passes it only when the bucket is most of the table, so nearly
+    every first split is gathered. Rough: three widths priced it (17 and 35
+    words the word-major gather, 500 the row-major one; between 41 and 499
+    the row-major price is drawn through that one point), and the kernel's
+    price stood at all three (0.172 ns a column a row at 2,000 columns)."""
     kernel = KERNEL_NS_COLUMN_ROW * num_cols
-    gather = (ROW_GATHER_NS_INDEX + ROW_GATHER_NS_WORD * num_words +
-              GH_GATHER_NS_INDEX)
+    if rows_held_twice(num_words):
+        gather = ROWS_GATHER_NS_WORD * num_words + GH_GATHER_NS_INDEX
+    else:
+        gather = (ROW_GATHER_NS_INDEX + ROW_GATHER_NS_WORD * num_words +
+                  GH_GATHER_NS_INDEX)
     return math.floor(num_rows * kernel / (gather + kernel))
+
+
+def rows_held_twice(num_words: int) -> bool:
+    """Whether the compact grower keeps a row-major copy of the packed table
+    beside the word-major one the column fetch and the in-place kernel
+    read."""
+    return num_words >= HELD_TWICE_FROM_WORDS
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1033,6 +1033,14 @@ class GBDT:
                         f"histogram pool ({pool_bytes >> 20} MB) exceeds "
                         "the budget; computing per-split child histograms "
                         "without a pool")
+            # for the tracing's table, the sizes of this set-up: the bytes
+            # of the histogram pool as just decided, and the 32-bit words of
+            # the packed table
+            slots = {"none": 0, "bounded": self.grower_cfg.pool_slots}.get(
+                self.grower_cfg.hist_pool, cfg.num_leaves)
+            global_timer.note("pool_bytes", slots * slot_bytes)
+            global_timer.note("table_words", self.bins_rf.size
+                              if self._packed_cols else 0)
         self._setup_cegb(train)
         self._bins_mv_dev = None
         if self.feature_meta is None:
@@ -2449,11 +2457,12 @@ class GBDT:
         (ref: tree.h kCategoricalMask=1, kDefaultLeftMask=2, missing type in
         bits 2-3; Tree::Split stores RealThreshold = bin upper bound).
         Every tree a grower grew passes here once, on the host: the place
-        of the tracing's two counters (utils/timer.py)."""
+        of the tracing's per-tree counters (utils/timer.py)."""
         global_timer.count("trees")
         global_timer.count("first_split_dense", host.first_split_dense)
         mappers = self.train_set.bin_mappers
         n_int = host.num_leaves - 1
+        global_timer.count("splits", n_int)
         thr_real = np.zeros(n_int, np.float64)
         dtype_bits = np.zeros(n_int, np.int32)
         miss_enum = {"none": 0, "zero": 1, "nan": 2}

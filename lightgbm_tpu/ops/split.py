@@ -776,18 +776,26 @@ def _select_across_features(scan: dict, meta: FeatureMeta,
         eps_h = jnp.asarray([0.0, K_EPSILON, 0.0], jnp.float32)
         svec = jnp.stack([sum_g, sum_h2, n_f])
         # right side at threshold t = sfx[:, f, t + 1]; t + 1 is always
-        # in range (valid reverse u <= hi <= B-1; forward t <= B-2)
-        pr = lax.dynamic_slice(
-            scan["sfx"], (jnp.int32(0), best_f, best_t_w + 1),
-            (3, 1, 1)).reshape(3)
+        # in range (valid reverse u <= hi <= B-1; forward t <= B-2).
+        # The winning feature's rows [3, B] first, the entry out of those:
+        # one (3, 1, 1) slice of the whole [3, F, B] array made the TPU
+        # compiler lay that array out with the three channels minor, 128
+        # lanes for 3, and write and reverse it so once a split: 1,021 of
+        # 1,094 ms of ``split_scan`` an iteration at 2,000 columns
+        # (PERF.md section 6, PR 33)
+        def entry(sums, t):
+            rows = lax.dynamic_index_in_dim(sums, best_f, axis=1,
+                                            keepdims=False)      # [3, B]
+            return lax.dynamic_index_in_dim(rows, t, axis=1,
+                                            keepdims=False)      # [3]
+
+        pr = entry(scan["sfx"], best_t_w + 1)
         rvec_r = pr + eps_h
         lvec_r = svec - rvec_r
         if scan["use_fwd"] is None:
             lvec, rvec = lvec_r, rvec_r
         else:
-            pf = lax.dynamic_slice(
-                scan["pfx_fwd"], (jnp.int32(0), best_f, best_t_w),
-                (3, 1, 1)).reshape(3)
+            pf = entry(scan["pfx_fwd"], best_t_w)
             lvec_f = pf + eps_h
             rvec_f = svec - lvec_f
             uf = sel(scan["use_fwd"])

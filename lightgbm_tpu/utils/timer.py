@@ -11,10 +11,14 @@ clock — and (b) a record ``(name, start, end, parent, iteration)`` on
 ``time.perf_counter`` in a bounded deque, beside totals and counts that
 ``table()`` prints. Under the sections ``table()`` prints the **counters**
 (``global_timer.count(name)``): what the device decided, read off what comes
-to the host anyway. There are two, counted where a grown tree becomes a
-``HostTree`` (``models/gbdt.py``): ``trees``, and ``first_split_dense``, those
-whose first split histogrammed its smaller child in one masked pass over the
-table in place (``TreeArrays.first_split_dense``, ``core/grower.py``).
+to the host anyway. Three are counted where a grown tree becomes a
+``HostTree`` (``models/gbdt.py``, ``_finalize_tree``): ``trees``;
+``first_split_dense``, those whose first split histogrammed its smaller child
+in one masked pass over the table in place (``TreeArrays.first_split_dense``,
+``core/grower.py``); and ``splits``, the trees' real splits. Two are sizes,
+set and not added (``global_timer.note``) at each set-up (``_setup_train``):
+``pool_bytes``, the histogram pool as the budget left it, and
+``table_words``, the 32-bit words of the packed table.
 ``LIGHTGBM_TPU_TIMETAG`` (or ``global_timer.enabled = True``) turns on only
 the ``sync=`` barrier and the table printed at the end of training.
 
@@ -128,6 +132,11 @@ class Timer:
     def count(self, name: str, n: int = 1) -> None:
         """Add ``n`` to the counter ``name``."""
         self.counters[name] += int(n)
+
+    def note(self, name: str, value: int) -> None:
+        """Set the counter ``name`` to ``value``: a size, which a second
+        booster in the process replaces and does not add to."""
+        self.counters[name] = int(value)
 
     def reset(self) -> None:
         self._total.clear()

@@ -3,8 +3,8 @@
 print what the compiler did with the table: minutes in the sandbox, no chip.
 
     JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \\
-        python scripts/tpu_compile_grower.py --rows 200000 [--unpacked] \\
-        [--text /root/scratch/grow.hlo.txt]
+        python scripts/tpu_compile_grower.py --rows 200000 [--cols 67] \\
+        [--leaves 255] [--unpacked] [--text /root/scratch/grow.hlo.txt]
 
 It prints the compile seconds, the compiler's memory analysis (the
 temporaries are the block the chip reports as ``peak_bytes_reserved``) and,
@@ -14,9 +14,15 @@ none is HBM. It also lists every ``copy`` and ``bitcast`` of an array of the
 table's size, with the computation it sits in: a copy inside a
 ``branch_*`` computation is paid once a split. The Pallas kernels are
 compiled by Mosaic as on the chip (``pallas_custom_calls``). Nothing runs,
-so it gives no time. Layouts change with the row count (at 200,000 rows the row gather
-reads a row-major copy, at 2 M the word-major parameter): what a PR claims
-of the cell it checks at ``--rows 2000000`` (seven minutes).
+so it gives no time. The last line, ``table_sized_copies_in_loop <n>``,
+counts the copies and transposing fusions of an array of the table's size
+in the computations the split loop's body reaches: each is paid once a
+split. Layouts change with the shape (at 200,000 x 67 the row gather reads a
+row-major copy, at 2 M x 67 the word-major parameter; at 400,000 x 2,000 the
+parent of PR 33 copied the table in every branch of the histogram's switch):
+what a PR claims of a cell it checks at that cell's shape, ``--rows 2000000``
+(seven minutes) for `criteo-share.train` and ``--rows 400000 --cols 2000``
+(under a minute) for `epsilon.train`.
 """
 import argparse
 import math
@@ -40,6 +46,55 @@ from lightgbm_tpu.utils import timer
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?(%[^\s(]+) \(.*\{\s*$")
 _DIMS = re.compile(r"\[([\d,]+)\]")
+# what an instruction calls: body=%b, calls=%f, branch_computations={%a, %b}
+_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|true_computation|false_computation)"
+    r"=(%[^\s,)}]+)|branch_computations=\{([^}]*)\}")
+
+
+def _elements(shape):
+    dims = _DIMS.search(shape)
+    return math.prod(int(d) for d in dims.group(1).split(",")) if dims else 0
+
+
+def copies_in_loop(text, table_elements):
+    """The instructions that re-lay an array of the table's size once a
+    split: in every computation that a ``while`` body reaches (the branches
+    of its conditionals among them), each ``copy`` of ``table_elements``
+    elements and each fusion of that size whose computation holds such a
+    ``copy`` or ``transpose``. Returns [(computation, name, shape)]."""
+    body_of, calls, relays, bodies, comp = {}, {}, set(), [], None
+    for line in text.splitlines():
+        started = _COMPUTATION.match(line)
+        if started:
+            comp = started.group(1)
+            body_of[comp], calls[comp] = [], set()
+            continue
+        m = timer._INSTRUCTION.match(line)
+        if not m or comp is None:
+            continue
+        name, shape, opcode, _ = m.groups()
+        called = set()
+        for one, many in _CALLED.findall(line):
+            called.update([one] if one else timer._OPERAND.findall(many))
+        calls[comp] |= called
+        sized = not shape.startswith("(") and \
+            _elements(shape) == table_elements
+        if sized and opcode in ("copy", "transpose"):
+            relays.add(comp)
+        body_of[comp].append((name, shape, opcode, called, sized))
+        if opcode == "while":
+            bodies.extend(called)       # its condition copies no table
+    reached, todo = set(), list(bodies)
+    while todo:
+        c = todo.pop()
+        if c not in reached and c in calls:
+            reached.add(c)
+            todo.extend(calls[c])
+    return [(c, name, shape) for c in sorted(reached)
+            for name, shape, opcode, called, sized in body_of[c]
+            if sized and (opcode == "copy" or
+                          (opcode == "fusion" and called & relays))]
 
 
 def report(text, table_elements, stage):
@@ -61,16 +116,18 @@ def report(text, table_elements, stage):
             operand = timer._OPERAND.findall(operands)[0]
             print(f"gather {name} = {shape}  in {comp}\n"
                   f"    operand {operand} = {shape_of.get(operand)}")
-        elif opcode in ("copy", "bitcast") and not shape.startswith("("):
-            dims = _DIMS.search(shape)
-            if dims and math.prod(
-                    int(d) for d in dims.group(1).split(",")) == table_elements:
-                print(f"{opcode} {name} = {shape}  in {comp}")
+        elif (opcode in ("copy", "bitcast") and not shape.startswith("(")
+              and _elements(shape) == table_elements):
+            print(f"{opcode} {name} = {shape}  in {comp}")
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=200000)
+    ap.add_argument("--cols", type=int, default=67,
+                    help="columns of the table: 67 is `criteo-share`'s, "
+                         "2000 `epsilon`'s")
+    ap.add_argument("--leaves", type=int, default=255)
     ap.add_argument("--unpacked", action="store_true")
     ap.add_argument("--stage", default="partition_fetch")
     ap.add_argument("--text", help="write the compiled module's text here")
@@ -82,13 +139,14 @@ def main():
     # kernels: compile them as the chip would (until PR 30 this script
     # compiled the interpreter's loops in the custom calls' place)
     hist_pallas.default_interpret = lambda: False
-    F, B = 67, 255                      # `criteo-share`'s shape
+    F, B = args.cols, 255
     meta = FeatureMeta(num_bin=jnp.full((F,), B, jnp.int32),
                        missing_type=jnp.zeros((F,), jnp.int32),
                        default_bin=jnp.zeros((F,), jnp.int32),
                        is_categorical=jnp.zeros((F,), bool))
-    # what `criteo-share.train` resolves its `auto` parameters to on a v5e
-    cfg = GrowerConfig(num_leaves=255, num_bin=B,
+    # what `criteo-share.train` and `epsilon.train` resolve their `auto`
+    # parameters to on a v5e
+    cfg = GrowerConfig(num_leaves=args.leaves, num_bin=B,
                        row_sched="compact", hist_rm_backend="pallas",
                        partition_mode="auto", min_bucket=2048,
                        packed_cols=0 if args.unpacked else F)
@@ -115,6 +173,10 @@ def main():
             f.write(text)
     print(f"pallas_custom_calls {text.count('tpu_custom_call')}")
     report(text, args.rows * width, args.stage)
+    in_loop = copies_in_loop(text, args.rows * width)
+    for comp, name, shape in in_loop:
+        print(f"in the split loop: {name} = {shape}  in {comp}")
+    print(f"table_sized_copies_in_loop {len(in_loop)}")
 
 
 if __name__ == "__main__":

@@ -215,7 +215,13 @@ FIRST_SPLIT_LINES = [
     (7, 28, 6.90),      # chip_smoke.py's table: about R/7
     (17, 67, 3.94),     # criteo-share: about R/4
     (35, 137, 2.85),    # MS LTR's width (ledger, PR 27): about R/3
-    (175, 700, 1.99),   # Expo's width: about R/2
+    (40, 160, 2.69),    # the widest table held once (my TPU compiles, PR 33)
+    # from 41 words the compiler re-lays a table held once at every split:
+    # it is held twice and whole rows are gathered out of the row-major
+    # copy for a tenth of the price, a price read at 500 words alone
+    (41, 164, 1.20),    # the narrowest table held twice: no reading behind it
+    (175, 700, 1.08),   # Expo's width: no reading behind it either
+    (500, 2000, 1.06),  # Epsilon's (my chip run, PR 33, priced it)
 ]
 
 
@@ -229,7 +235,9 @@ def test_first_split_rule_line(words, cols, share):
     # more than 262,144 rows can fall into are over the line at 67
     # columns and under; only the upper one from 137 columns on
     over = [b for b in (1048576, 524288, 262144) if b > line]
-    assert over == ([1048576, 524288] if cols <= 67 else [1048576])
+    assert over == ([1048576, 524288] if cols <= 67 else
+                    [1048576] if words < plan_mod.HELD_TWICE_FROM_WORDS
+                    else [])
     # the line scales with the rows and no bucket of a tiny child passes
     assert first_split_dense_rows(M2 // 2, words, cols) == \
         pytest.approx(line / 2, abs=1)
@@ -249,7 +257,30 @@ def test_first_split_rule_rises_with_the_width():
     source = inspect.getsource(plan)
     above = source[:source.index("def first_split_dense_rows")]
     for constant in ("ROW_GATHER_NS_INDEX", "ROW_GATHER_NS_WORD",
-                     "GH_GATHER_NS_INDEX", "KERNEL_NS_COLUMN_ROW"):
+                     "GH_GATHER_NS_INDEX", "KERNEL_NS_COLUMN_ROW",
+                     "HELD_TWICE_FROM_WORDS", "ROWS_GATHER_NS_WORD"):
         assert f"\n{constant} = " in above
     block = above[above.index("# The first split's smaller child"):]
     assert "ledger, PR 31" in block and "ledger, PR 27" in block
+    assert "my chip run, PR 33" in block
+
+
+def test_epsilon_takes_the_cells_path_and_gathers_its_first_split():
+    """400,000 x 2,000 on a chip resolves as the cell we have (every
+    ``tpu_*`` auto): the Pallas kernel on packed words. At 500 words the
+    table is held twice, and no smaller child of a first split (at most
+    200,000 rows, the 262,144 bucket) is over the rule's line."""
+    got = make_plan(platform="tpu", num_data=400_000, num_bin_max=251,
+                    quantized=False, hist_dtype="float32",
+                    tree_learner="serial", storage="dense",
+                    row_sched="compact")
+    assert (got.hist_rm_backend, got.level_hist_backend, got.partition_mode,
+            got.pack, got.hist_reduce, got.notes) == \
+        ("pallas", "einsum", "auto", True, "allreduce", ())
+    assert plan_mod.rows_held_twice(500)
+    assert not plan_mod.rows_held_twice(17)
+    assert plan_mod.first_split_dense_rows(400_000, 500, 2000) > 262_144
+    # the word-major gather's fit, had it priced this width: dense from
+    # 131,073 rows up, 137.5 ms of kernel for a child of 95
+    assert 131_072 < 400_000 * 348 / (348 + 20.6 + 0.55 * 500 + 4.3) \
+        < 262_144
