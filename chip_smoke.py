@@ -250,7 +250,8 @@ def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
     """hist_pallas_rm (f32 bf16-triple, bf16, int8) and hist_pallas_words
     (f32, int8: the packed words the training leg's grower hands it, 7
     words of 28 columns, so the word axis ends inside the kernel's 8-word
-    tile) at the smoke's shape and hist_level (f32, int8) at the depth-10
+    tile; f32 again with a live row range, the dead row blocks poisoned)
+    at the smoke's shape and hist_level (f32, int8) at the depth-10
     level shape: lowered for
     this backend, shown to hold a Mosaic call, run, and compared with
     exact host sums (the scatter formulations run beside them for the
@@ -317,6 +318,20 @@ def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
     run("hist_pallas_words/f32", words, (words_cm, gh), refs["f32"], ref_abs)
     run("hist_pallas_words/int8", words, (words_cm, gh_i8), refs["int8"],
         None)
+    # a leaf's segment inside its bucket: the range starts and ends inside
+    # row blocks, ``gh`` is zero outside it as the grower hands it, and NaN
+    # in the blocks the kernel must not read
+    lo, hi = R // 3 + 77, R // 2 + 13
+    at = np.arange(R)
+    dead = (at // block_rows < lo // block_rows) | \
+        (at // block_rows > (hi - 1) // block_rows)
+    gh_seg = host["f32"] * ((at >= lo) & (at < hi))[:, None]
+    run("hist_pallas_words/f32/live",
+        lambda w, g, a, b: words(w, g, live=(a, b)),
+        (words_cm, jnp.asarray(np.where(dead[:, None], np.nan, gh_seg)),
+         jnp.int32(lo), jnp.int32(hi)),
+        _host_hist(bins_host, gh_seg, B),
+        _host_hist(bins_host, np.abs(gh_seg), B), live_rows=hi - lo)
 
     n_nodes = sizes.level_nodes
     rows = np.arange(R, dtype=np.uint32)

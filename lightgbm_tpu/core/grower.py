@@ -190,6 +190,11 @@ class GrowState(NamedTuple):
     # bool scalar: this tree's first split histogrammed its smaller child
     # in one masked pass over the table in place (compact scheduling)
     first_dense: jnp.ndarray = None
+    # i32 [L-1, 2, 2]: the (start, rows) of the segments split ``i``
+    # histogrammed with a gathered call, at most its two children; rows -1
+    # where there was none (compact scheduling; summed after the loop into
+    # ``TreeArrays.hist_rows``)
+    hist_calls: jnp.ndarray = None
 
 
 def _set(arr, idx, val, cond):
@@ -424,7 +429,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
         if mv_mode:
             from ..ops.hist_multival import hist_multival as _hist_mv
 
-            def hist_rm(sb, ghv):
+            def hist_rm(sb, ghv, live=None, block_rows=None):
                 return _hist_mv(sb, ghv, B)
         else:
             hist_rm = functools.partial(hist_rowmajor, num_bin=B,
@@ -726,13 +731,35 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             # 4x the bytes of the words and a transpose away from the
             # kernel's layout
             words_kernel = packed and cfg.hist_rm_backend == "pallas"
+            from ..ops.hist_pallas import (fit_tiles, hist_pallas_words,
+                                           live_row_blocks, words_block_rows)
             if words_kernel:
-                from ..ops.hist_pallas import hist_pallas_words
                 hist_leaf = functools.partial(
                     hist_pallas_words, num_bin=B, num_cols=Fp,
                     block_rows=cfg.block_rows, dtype=cfg.hist_dtype)
             else:
                 hist_leaf = hist_rm
+            # the Pallas kernel reads a bucket's live row blocks alone;
+            # every other backend every row of what it is given
+            skips_blocks = words_kernel or (
+                cfg.hist_rm_backend == "pallas" and not mv_mode and
+                fit_tiles(8, B, cfg.block_rows)[2])
+
+            def bucket_block(S):
+                """The kernel's row block for a gathered bucket of ``S``
+                rows: ``cfg.block_rows``, and a quarter of the bucket where
+                that is less, as the kernel's entry resolves it. The
+                padding the kernel can skip comes in whole row blocks: a
+                2,048-row bucket in two blocks of 1,024 skips half of
+                itself or nothing (PERF.md section 6, PR 34: 512-row blocks
+                there save 8 ms an iteration of 83 on 2,000 columns; a grid
+                step more costs 0.26 us a column tile, 256 rows less
+                88 us)."""
+                if not skips_blocks:
+                    return cfg.block_rows
+                asked = min(cfg.block_rows, max(128, S // 4))
+                return words_block_rows(asked, B) if words_kernel \
+                    else fit_tiles(8, B, asked)[1]
             # a table of wide rows is held twice (core/plan.py,
             # ``rows_held_twice``): the barrier makes the row gather's
             # operand a value of its own, so the compiler lays it out
@@ -845,28 +872,33 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             # of the temporaries, and the peak stood 6 % over the parent's)
             rows_chunk = max((S for S in sizes if 2 * S <= R), default=R)
 
-            def hist_rows(idx, ghw):
+            def hist_rows(idx, ghw, live):
                 """A bucket of a table held twice: whole rows out of the
                 row-major copy (``order`` holds row numbers: none to fill),
                 the block re-laid ``[Wp, n]`` for the kernel, ``rows_chunk``
-                rows at a time, the blocks' histograms added up."""
-                def block(i, g):
+                rows at a time, the blocks' histograms added up. Each
+                block's kernel takes the live rows that fall in it."""
+                n = idx.shape[0]
+
+                def block(i, g, lv):
                     with timer.stage("hist_gather"):
                         blk = jnp.take(bins_rows, i, axis=0, mode="clip").T
-                    return hist_leaf(blk, g)
+                    return hist_leaf(blk, g, live=lv,
+                                     block_rows=bucket_block(n))
 
-                n = idx.shape[0]
                 if n <= rows_chunk:
-                    return block(idx, ghw)
+                    return block(idx, ghw, live)
 
                 def body(c, h):
                     part = functools.partial(lax.dynamic_slice_in_dim,
                                              start_index=c * rows_chunk,
                                              slice_size=rows_chunk)
-                    return h + block(part(idx), part(ghw))
+                    # the kernel cuts a range to its operand's rows
+                    return h + block(part(idx), part(ghw),
+                                     tuple(x - c * rows_chunk for x in live))
 
                 first = jax.eval_shape(block, idx[:rows_chunk],
-                                       ghw[:rows_chunk])
+                                       ghw[:rows_chunk], live)
                 return lax.fori_loop(0, n // rows_chunk, body,
                                      jnp.zeros(first.shape, first.dtype))
 
@@ -932,8 +964,17 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                         else:
                             ghw = ghg * w[:, None]
                     with timer.stage("hist_kernel"):
-                        h = hist_rows(idx, ghw) if blk is None else \
-                            hist_leaf(blk, ghw)
+                        # the kernel skips the row blocks that hold nothing
+                        # but the bucket's padding; in place the segment's
+                        # rows lie scattered over the table
+                        if in_place:
+                            h = hist_leaf(blk, ghw)
+                        elif blk is None:
+                            h = hist_rows(idx, ghw, (delta, delta + rows))
+                        else:
+                            h = hist_leaf(blk, ghw,
+                                          live=(delta, delta + rows),
+                                          block_rows=bucket_block(S))
                         if local_pool:
                             return h, jnp.sum(ghw.astype(hist_dtype), axis=0)
                     return h
@@ -956,6 +997,27 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     if local_pool:
                         return h, jnp.sum(ghw.astype(hist_dtype), axis=0)
                 return h
+
+            def rows_counted(calls):
+                """int32 [3] over a tree's gathered calls (``hist_calls``,
+                once a tree, after the loop): the segments' rows, the
+                buckets' rows in the row blocks the kernel read for them
+                (``hb``'s range), the buckets' rows."""
+                start, rows = calls[..., 0].ravel(), calls[..., 1].ravel()
+                b = jnp.sum(sizes_arr >= rows[:, None], axis=1) - 1
+                S = sizes_arr[b]
+                read = S
+                if skips_blocks:
+                    block = jnp.asarray([bucket_block(s) for s in sizes])[b]
+                    delta = start - jnp.clip(start, 0, jnp.maximum(R - S, 0))
+                    _, n = live_row_blocks((delta, delta + rows), S, block)
+                    read = jnp.minimum(n * block, S)
+                made = rows >= 0
+                if held_twice:
+                    # its bucket of every row is read in place
+                    made &= b > 0
+                return jnp.sum(jnp.where(made, jnp.stack([rows, read, S]),
+                                         0), axis=1, dtype=jnp.int32)
 
             # the last partition branch is a tree's first split, where
             # ``order`` is still the identity: same integers out, nothing
@@ -1001,6 +1063,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             return fm
 
         inf = jnp.float32(jnp.inf)
+        no_calls = jnp.full((L - 1, 2, 2), -1, jnp.int32)
         if use_rand:
             et_key = jax.random.fold_in(
                 rng_key if rng_key is not None else jax.random.PRNGKey(0),
@@ -1011,6 +1074,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             state, start_step = init
             if state.first_dense is None:
                 state = state._replace(first_dense=jnp.asarray(False))
+            if compact and state.hist_calls is None:
+                state = state._replace(hist_calls=no_calls)
         else:
             start_step = 0
             # ---- root (ref: LeafSplits::Init + first FindBestSplits) ----
@@ -1117,6 +1182,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     meta.num_bin.astype(jnp.int32)[None, :] - 1,
                     (L, F)).copy() if use_mc_inter else None),
                 first_dense=jnp.asarray(False),
+                hist_calls=no_calls if compact else None,
             )
 
         def body(i, state: GrowState) -> GrowState:
@@ -1326,6 +1392,9 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                         lambda: (state.order, jnp.int32(0),
                                  jnp.zeros((Fp, B, 3), hist_dtype),
                                  jnp.zeros((Fp, B, 3), hist_dtype)))
+                    # a hit gathers the smaller child, a miss both
+                    lsm_hit = nL_raw <= rows_l - nL_raw
+                    gathered = jnp.stack([~have | lsm_hit, ~have | ~lsm_hit])
                     left_smaller = jnp.asarray(True)  # unused downstream
                     hist_small = None
                 elif pool_none:
@@ -1365,6 +1434,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                         hist_right_c = reduce_hist(hist_right_c, rctx)
                     left_smaller = jnp.asarray(True)  # unused downstream
                     hist_small = None
+                    gathered = jnp.asarray([True, True])
                 else:
                     if distributed:
                         # the smaller side must be agreed mesh-wide: pick
@@ -1411,6 +1481,9 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                                      jnp.asarray(True), jnp.asarray(False),
                                      jnp.zeros((Fp, B, 3), hist_dtype)))
                     first_dense = state.first_dense | took_dense
+                    # the smaller child's, unless it took the masked pass
+                    gathered = ~took_dense & jnp.stack([left_smaller,
+                                                        ~left_smaller])
                     if distributed:
                         pick = lambda a, b: jnp.where(left_smaller, a, b)
                         small_ctx = (pick(rec.left_sum_gradient,
@@ -1421,14 +1494,18 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                                      pick(rec.left_output,
                                           rec.right_output))
                         hist_small = reduce_hist(hist_small, small_ctx)
-                seg = _set_rows2(
-                    state.seg, l, new_leaf,
-                    jnp.stack([start_l, nL_raw]),
-                    jnp.stack([start_l + nL_raw, rows_l - nL_raw]),
-                    proceed)
+                kids = jnp.stack([jnp.stack([start_l, nL_raw]),
+                                  jnp.stack([start_l + nL_raw,
+                                             rows_l - nL_raw])])
+                seg = _set_rows2(state.seg, l, new_leaf, kids[0], kids[1],
+                                 proceed)
+                hist_calls = lax.dynamic_update_index_in_dim(
+                    state.hist_calls,
+                    jnp.where((proceed & gathered)[:, None], kids, -1), i, 0)
             else:
                 order = state.order
                 seg = state.seg
+                hist_calls = None
                 left_smaller = rec.left_count <= rec.right_count
                 small_leaf = jnp.where(left_smaller, l, new_leaf)
                 pick = lambda a, b: jnp.where(left_smaller, a, b)
@@ -1870,7 +1947,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 path_mask=path_mask, forced_ok=forced_ok, order=order,
                 seg=seg, leaf_flo=leaf_flo, leaf_fhi=leaf_fhi,
                 lsum=lsum, slot_map=slot_map, slot_stamp=slot_stamp,
-                slot_owner=slot_owner, first_dense=first_dense)
+                slot_owner=slot_owner, first_dense=first_dense,
+                hist_calls=hist_calls)
 
         state = lax.fori_loop(start_step, L - 1, body, state)
 
@@ -1901,6 +1979,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             cat_count=i32c(N_CCNT) if has_cat else None,
             cat_bins=state.tree_cat,
             first_split_dense=state.first_dense.astype(jnp.int32),
+            hist_rows=rows_counted(state.hist_calls) if compact else None,
         )
         if compact:
             # rebuild per-row leaf ids from the final segments: mark each

@@ -75,8 +75,12 @@ ROW_GATHER_NS_INDEX = 20.6
 ROW_GATHER_NS_WORD = 0.55
 # the gh rows f32[S,3] out of VMEM: 34.3 ms over the same 7.9 M indices
 GH_GATHER_NS_INDEX = 4.3
-# the Pallas kernel, whatever the bucket: 11.65 ns a row at 67 columns (the
-# root's call 23.3 ms for 2 M rows read in place), 0.174 ns a column a row
+# the Pallas kernel, a row it reads: 11.65 ns at 67 columns (the root's call
+# 23.3 ms for 2 M rows read in place), 0.174 ns a column a row; one price for
+# every call, the small buckets' too (PERF.md section 6, PR 34). A call that
+# reads the table in place pays it for every row; a gathered call for the
+# row blocks that overlap its leaf's segment, not for the bucket's padding
+# (since PR 34: `ops/hist_pallas.py`, `_hist_call`'s live range)
 KERNEL_NS_COLUMN_ROW = 0.174
 
 # The packed words of a row from which the compact grower holds the table
@@ -123,7 +127,13 @@ def first_split_dense_rows(num_rows: int, num_words: int,
     every first split is gathered. Rough: three widths priced it (17 and 35
     words the word-major gather, 500 the row-major one; between 41 and 499
     the row-major price is drawn through that one point), and the kernel's
-    price stood at all three (0.172 ns a column a row at 2,000 columns)."""
+    price stood at all three (0.172 ns a column a row at 2,000 columns).
+
+    The rule still prices the gathered call's kernel by its bucket. Since
+    PR 34 that call pays for its live row blocks alone, so the rule
+    over-prices it by the bucket's padding and leans towards the dense
+    pass; neither measured shape turns on it (PERF.md section 6, PR 34),
+    and the gather's part, which is the larger, does pay for the bucket."""
     kernel = KERNEL_NS_COLUMN_ROW * num_cols
     if rows_held_twice(num_words):
         gather = ROWS_GATHER_NS_WORD * num_words + GH_GATHER_NS_INDEX

@@ -88,6 +88,14 @@ class TreeArrays(NamedTuple):
     # 0 where it gathered the child's rows; None from every other maker.
     # What the device decided, for utils/timer's count; no part of the model
     first_split_dense: jnp.ndarray = None  # i32 scalar
+    # the compact grower's gathered histogram calls of this tree, summed:
+    # (the segments' rows, the rows of the row blocks the kernel read for
+    # them, the buckets' rows). ``1 - read / bucket`` is the share of a
+    # bucket the kernel skipped, ``1 - live / bucket`` the share of every
+    # gather of that ladder that is padding. For utils/timer's counters, as
+    # ``first_split_dense`` (on a mesh, the first shard's); None from every
+    # other maker
+    hist_rows: jnp.ndarray = None  # i32 [3]
 
     @staticmethod
     def empty(max_leaves: int, max_cat: int = 0) -> "TreeArrays":
@@ -150,6 +158,7 @@ class HostTree:
         self.max_depth = max_leaf_depth(self.left_child, self.right_child,
                                         self.num_leaves)
         self.first_split_dense = bool(a.get("first_split_dense", 0))
+        self.hist_rows = tuple(int(x) for x in a.get("hist_rows", (0, 0, 0)))
         # per-node category-BIN sets from the grower (inner representation,
         # ref: cat_threshold_inner_); -1 padded, empty for numerical nodes
         if "cat_bins" in a and n_int:
@@ -195,6 +204,7 @@ class HostTree:
         self.shrinkage = 1.0
         self.max_depth = 0
         self.first_split_dense = False
+        self.hist_rows = (0, 0, 0)
         self.threshold_real = np.zeros(0, np.float64)
         self.decision_type = np.zeros(0, np.int32)
         self.is_linear = False

@@ -2460,6 +2460,8 @@ class GBDT:
         of the tracing's per-tree counters (utils/timer.py)."""
         global_timer.count("trees")
         global_timer.count("first_split_dense", host.first_split_dense)
+        for name, rows in zip(("live", "read", "bucket"), host.hist_rows):
+            global_timer.count("hist_rows_" + name, rows)
         mappers = self.train_set.bin_mappers
         n_int = host.num_leaves - 1
         global_timer.count("splits", n_int)

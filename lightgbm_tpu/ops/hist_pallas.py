@@ -78,11 +78,15 @@ def _row_of_words(block, f):
     return (word >> (8 * (f % 4))) & 0xFF
 
 
-def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
+def _hist_kernel(live_ref, bins_ref, gh_ref, out_ref, *, feature_tile: int,
                  num_bin_padded: int, fetch, tiles: int, live_in_last: int,
                  int8_mode: bool = False, interpret: bool = False):
     """One (feature-tile, row-block) grid step.
 
+    live_ref: int32 [2] in SMEM (scalar prefetch): the first live row block
+              and how many follow it. Row step ``j`` holds block
+              ``live_ref[0] + j`` (``_hist_call``'s index maps) and adds
+              nothing from ``live_ref[1]`` on
     bins_ref: int32 [FT, RB] feature-major bins, or uint32 [FT/4, RB]
               word-major packed words; ``fetch(block, f)`` takes feature
               ``f``'s int32 [1, RB] row out of either
@@ -101,54 +105,62 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, feature_tile: int,
     one [Bp, RB] one-hot (~0.5 MB at Bp=256, RB=512) instead of the full
     [RB, FT*Bp] expansion.
     """
-    j = pl.program_id(1)
+    # both read here: the interpreter resolves a program id at the kernel's
+    # top level, not inside a branch of a branch
+    i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    bins = bins_ref[:]                              # [FT, RB] / [FT/4, RB]
-    gh = gh_ref[:]                                  # [Cp, RB]
-    rb = bins.shape[1]
-    # iota_b[b, r] = b; onehot_f[b, r] = (bins[f, r] == b)
-    iota_b = lax.broadcasted_iota(jnp.int32, (num_bin_padded, rb), 0)
+    # one guard a kernel, around everything a step does with its blocks: a
+    # step past the live ones names the block the step before it held, so
+    # nothing was fetched for it, and it builds no one-hot
+    @pl.when(j < live_ref[1])
+    def _():
+        bins = bins_ref[:]                          # [FT, RB] / [FT/4, RB]
+        gh = gh_ref[:]                              # [Cp, RB]
+        rb = bins.shape[1]
+        # iota_b[b, r] = b; onehot_f[b, r] = (bins[f, r] == b)
+        iota_b = lax.broadcasted_iota(jnp.int32, (num_bin_padded, rb), 0)
 
-    if int8_mode:
-        onehot_dtype, acc_dtype = jnp.int8, jnp.int32
-    else:
-        # f32 inputs arrive pre-decomposed into bf16 channel triples (see
-        # _hist_call) — the kernel always contracts at native bf16
-        # MXU rate with f32 accumulation. The interpreter backend (CPU
-        # tests) lacks bf16 dots; f32 compute there is numerically
-        # identical (bf16 values are exact in f32).
-        onehot_dtype, acc_dtype = jnp.bfloat16, jnp.float32
-        if interpret:
-            onehot_dtype = jnp.float32
-            gh = gh.astype(jnp.float32)
+        if int8_mode:
+            onehot_dtype, acc_dtype = jnp.int8, jnp.int32
+        else:
+            # f32 inputs arrive pre-decomposed into bf16 channel triples
+            # (see _hist_call) — the kernel always contracts at native bf16
+            # MXU rate with f32 accumulation. The interpreter backend (CPU
+            # tests) lacks bf16 dots; f32 compute there is numerically
+            # identical (bf16 values are exact in f32).
+            onehot_dtype, acc_dtype = jnp.bfloat16, jnp.float32
+            if interpret:
+                onehot_dtype = jnp.float32
+                gh = gh.astype(jnp.float32)
 
-    def add(f):
-        row = fetch(bins, f)                                 # [1, RB]
-        onehot_f = (row == iota_b).astype(onehot_dtype)      # [Bp, RB]
-        # contract over rows: [Cp, RB] x [Bp, RB] -> [Cp, Bp]
-        hist_f = lax.dot_general(
-            gh, onehot_f, (((1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype)
-        sl = slice(f * num_bin_padded, (f + 1) * num_bin_padded)
-        out_ref[:, sl] += hist_f
+        def add(f):
+            row = fetch(bins, f)                                 # [1, RB]
+            onehot_f = (row == iota_b).astype(onehot_dtype)      # [Bp, RB]
+            # contract over rows: [Cp, RB] x [Bp, RB] -> [Cp, Bp]
+            hist_f = lax.dot_general(
+                gh, onehot_f, (((1,), (1,)), ((), ())),
+                preferred_element_type=acc_dtype)
+            sl = slice(f * num_bin_padded, (f + 1) * num_bin_padded)
+            out_ref[:, sl] += hist_f
 
-    def features(n):
-        for f in range(n):
-            add(f)
+        def features(n):
+            for f in range(n):
+                add(f)
 
-    if tiles == 1 or live_in_last == feature_tile:
-        features(live_in_last)
-    else:
-        # two straight-line branches, not a guard around each feature that
-        # the last tile lacks: 29 guards a kernel, 12 kernels, took 2 s
-        # more to trace and lower, at every start of a training process
-        lax.cond(pl.program_id(0) < tiles - 1,
-                 functools.partial(features, feature_tile),
-                 functools.partial(features, live_in_last))
+        if tiles == 1 or live_in_last == feature_tile:
+            features(live_in_last)
+        else:
+            # two straight-line branches, not a guard around each feature
+            # that the last tile lacks: 29 guards a kernel, 12 kernels, took
+            # 2 s more to trace and lower, at every start of a training
+            # process
+            lax.cond(i < tiles - 1,
+                     functools.partial(features, feature_tile),
+                     functools.partial(features, live_in_last))
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -175,8 +187,17 @@ def bf16_triple(gh: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([hi, mid, lo], axis=1).astype(jnp.bfloat16)
 
 
+def live_row_blocks(live, num_rows: int, block_rows: int) -> tuple:
+    """(first, count) of the ``block_rows`` row blocks that overlap rows
+    ``live = (lo, hi)``, cut to an operand of ``num_rows`` rows."""
+    lo = jnp.clip(live[0], 0, num_rows)
+    hi = jnp.clip(live[1], lo, num_rows)
+    first = lo // block_rows
+    return first, (hi + block_rows - 1) // block_rows - first
+
+
 def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
-               feature_tile, block_rows, fetch, interpret):
+               feature_tile, block_rows, fetch, interpret, live=None):
     """The ``pallas_call`` and what both entries do around it: the bf16
     triple split, ``gh`` padded and transposed, the re-sum.
 
@@ -185,6 +206,15 @@ def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
     lies behind it, and ``gh`` is zero there. ``bins_block`` is its
     block's sublane extent (``feature_tile`` bins rows, or the words that
     hold them).
+
+    live: ``(lo, hi)``, traced int32 scalars: ``gh`` is zero outside rows
+    ``[lo, hi)`` (a leaf's segment inside its bucket), and only the row
+    blocks that overlap them are fetched and histogrammed. The grid stays
+    the static ``(tiles, row blocks)``: the first live block and their
+    count reach the index maps and the kernel as prefetched scalars, row
+    step ``j`` holds live block ``j``, and a step past the last names that
+    block again, which the pipeline does not fetch twice. ``None`` is every
+    row: the same kernel, told that every block is live.
     """
     R, C = gh.shape
     int8_mode = gh.dtype == jnp.int8
@@ -210,6 +240,16 @@ def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
         # padded rows carry gh = 0 so they accumulate nothing
         gh_t = jnp.pad(gh, ((0, Rp - R), (0, Cp - Cin))).T    # [Cp, Rp]
 
+    blocks = Rp // block_rows
+    live_blocks = jnp.stack(live_row_blocks(
+        (0, R) if live is None else live, R, block_rows)).astype(jnp.int32)
+
+    def row_block(j, live_ref):
+        # a dead step stays on the last live block; an empty range on one
+        # inside the operand
+        last = jnp.maximum(live_ref[1], 1) - 1
+        return jnp.minimum(live_ref[0] + jnp.minimum(j, last), blocks - 1)
+
     kernel = functools.partial(
         _hist_kernel, feature_tile=feature_tile, num_bin_padded=Bp,
         fetch=fetch, tiles=tiles,
@@ -217,20 +257,26 @@ def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
         int8_mode=int8_mode, interpret=interpret)
     out = pl.pallas_call(
         kernel,
-        grid=(tiles, Rp // block_rows),
-        in_specs=[
-            pl.BlockSpec((bins_block, block_rows), lambda i, j: (i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((Cp, block_rows), lambda i, j: (0, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((Cp, feature_tile * Bp), lambda i, j: (0, i),
-                               memory_space=pltpu.VMEM),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tiles, blocks),
+            in_specs=[
+                pl.BlockSpec((bins_block, block_rows),
+                             lambda i, j, lv: (i, row_block(j, lv)),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((Cp, block_rows),
+                             lambda i, j, lv: (0, row_block(j, lv)),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((Cp, feature_tile * Bp),
+                                   lambda i, j, lv: (0, i),
+                                   memory_space=pltpu.VMEM),
+        ),
         out_shape=jax.ShapeDtypeStruct((Cp, Fp * Bp), acc_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(bins_op, gh_t)
+    )(live_blocks, bins_op, gh_t)
 
     # [Cp, Fp*Bp] -> [Fp, Bp, Cp] -> [F, num_bin, C]
     hist = out.reshape(Cp, Fp, Bp).transpose(1, 2, 0)
@@ -248,7 +294,7 @@ def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
                                              "feature_tile", "interpret"))
 def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                       block_rows: int, feature_tile: int,
-                      interpret: bool) -> jnp.ndarray:
+                      interpret: bool, live=None) -> jnp.ndarray:
     F, R = bins_fm.shape
     feature_tile = max(8, _pad_to(feature_tile, 8))
     block_rows = _pad_to(block_rows, 128)
@@ -260,7 +306,7 @@ def _hist_pallas_impl(bins_fm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
             bins_fm = jnp.pad(bins_fm, ((0, Fp - F), (0, Rp - R)))
         bins_fm = bins_fm.astype(jnp.int32)
     return _hist_call(bins_fm, feature_tile, gh, num_bin, Fp, feature_tile,
-                      block_rows, _row_of_bins, interpret)[:F]
+                      block_rows, _row_of_bins, interpret, live)[:F]
 
 
 _WORD_TILE = 8      # words a tile: one sublane tile of 32-bit elements
@@ -270,7 +316,7 @@ _WORD_TILE = 8      # words a tile: one sublane tile of 32-bit elements
                                              "block_rows", "interpret"))
 def _hist_pallas_words(words_cm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                        num_cols: int, block_rows: int,
-                       interpret: bool) -> jnp.ndarray:
+                       interpret: bool, live=None) -> jnp.ndarray:
     block_rows = _pad_to(block_rows, 128)
     # the (8, block_rows) tile of 32-bit elements hist_pallas_rm reads, now
     # 32 features. Where the word axis ends inside a tile (17 words: the
@@ -278,7 +324,8 @@ def _hist_pallas_words(words_cm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     # operand, and the kernel fetches only the words its live columns
     # lie in
     return _hist_call(words_cm, _WORD_TILE, gh, num_bin, num_cols,
-                      4 * _WORD_TILE, block_rows, _row_of_words, interpret)
+                      4 * _WORD_TILE, block_rows, _row_of_words, interpret,
+                      live)
 
 
 # the kernel's VMEM residents stay within ~4 MB of 32-bit elements, which
@@ -320,14 +367,29 @@ def fit_tiles(feature_tile: int, num_bin: int,
         _resident(feature_tile, block_rows, Bp) <= _VMEM_BUDGET_ELEMS
 
 
+def words_block_rows(block_rows: int, num_bin: int) -> int:
+    """The row block ``hist_pallas_words`` runs when asked for
+    ``block_rows``. Its feature tile is the word tile's 32 columns whatever
+    the budget says, so only the rows give way (from 2,730 up); a byte's
+    256 bins fit at 128 rows."""
+    Bp = _pad_to(num_bin, 128)
+    block_rows = max(128, _pad_to(block_rows, 128))
+    while block_rows > 128 and \
+            _resident(_WORD_TILE * 4, block_rows, Bp) > _VMEM_BUDGET_ELEMS:
+        block_rows //= 2
+    return block_rows
+
+
 def hist_pallas(bins_t: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                 block_rows: int = 1024, feature_tile: int = 8,
-                interpret: bool | None = None) -> jnp.ndarray:
+                interpret: bool | None = None, live=None) -> jnp.ndarray:
     """Histogram [F, num_bin, C] over feature-major [F, R] bins.
 
     Same contract as hist_xla (ops/histogram.py). `interpret=None` picks
     the Pallas interpreter on the CPU backend only (``default_interpret``;
     the kernel itself is identical) and compiled mode everywhere else.
+    ``live``: the rows outside which ``gh`` is zero (``_hist_call``); the
+    kernel alone makes use of it.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -340,14 +402,15 @@ def hist_pallas(bins_t: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
         return hist_xla(bins_t, gh, num_bin, block_rows)
     # jaxlint: disable=JL001 — interpret is a static Python flag
     return _hist_pallas_impl(bins_t, gh, num_bin, block_rows, feature_tile,
-                             bool(interpret))
+                             bool(interpret), live)
 
 
 def hist_pallas_rm(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                    block_rows: int = 512, feature_tile: int = 8,
-                   interpret: bool | None = None) -> jnp.ndarray:
+                   interpret: bool | None = None, live=None) -> jnp.ndarray:
     """Row-major histogram [F, num_bin, C] over a gathered [S, F] block —
-    the compact scheduler's layout (same contract as hist_rowmajor).
+    the compact scheduler's layout (same contract as hist_rowmajor;
+    ``live`` as ``hist_pallas`` takes it).
 
     The tile-legal kernel wants lane-aligned rows, so the block is
     transposed to feature-major first; XLA fuses the u8 transpose into
@@ -368,18 +431,20 @@ def hist_pallas_rm(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
         bins_fm = bins_rm.T
     # jaxlint: disable=JL001 — interpret is a static Python flag
     return _hist_pallas_impl(bins_fm, gh, num_bin, block_rows,
-                             feature_tile, bool(interpret))
+                             feature_tile, bool(interpret), live)
 
 
 def hist_pallas_words(words_cm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                       num_cols: int, block_rows: int = 512,
                       dtype: str = "float32",
-                      interpret: bool | None = None) -> jnp.ndarray:
+                      interpret: bool | None = None,
+                      live=None) -> jnp.ndarray:
     """Histogram [num_cols, num_bin, C] over bit-packed rows as the table
     stores them: ``words_cm`` uint32 [ceil(num_cols / 4), S] word-major
     (rows on the lane axis), byte ``k`` of word ``w`` = column ``4w + k``.
     ``dtype`` as ``hist_rowmajor`` takes it: "bfloat16" rounds a float
-    ``gh`` to bf16 first.
+    ``gh`` to bf16 first. ``live`` as ``hist_pallas`` takes it: a leaf's
+    segment inside its gathered bucket.
 
     Equal bit for bit to ``hist_rowmajor(backend="pallas")`` on the
     unpacked rows wherever the two read the same row blocks (any
@@ -401,13 +466,7 @@ def hist_pallas_words(words_cm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                          "packed bin is a byte")
     if dtype in ("bfloat16", "bf16") and gh.dtype != jnp.int8:
         gh = gh.astype(jnp.bfloat16)
-    # the feature tile is the word tile's 32 columns whatever the budget
-    # says, so only the rows give way (from 2,730 up); a byte's 256 bins
-    # fit at 128 rows
-    block_rows = max(128, _pad_to(block_rows, 128))
-    while block_rows > 128 and \
-            _resident(_WORD_TILE * 4, block_rows, Bp) > _VMEM_BUDGET_ELEMS:
-        block_rows //= 2
     # jaxlint: disable=JL001 — interpret is a static Python flag
-    return _hist_pallas_words(words_cm, gh, num_bin, num_cols, block_rows,
-                              bool(interpret))
+    return _hist_pallas_words(words_cm, gh, num_bin, num_cols,
+                              words_block_rows(block_rows, num_bin),
+                              bool(interpret), live)

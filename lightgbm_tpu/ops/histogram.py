@@ -84,7 +84,7 @@ def hist_xla(bins_t: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
 
 def hist_rowmajor(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                   block_rows: int = 4096, dtype: str = "float32",
-                  backend: str = "einsum") -> jnp.ndarray:
+                  backend: str = "einsum", live=None) -> jnp.ndarray:
     """Histogram over a ROW-MAJOR [S, F] bin block (the gathered-leaf layout
     of the compact scheduler — rows of one leaf gathered contiguously, so a
     leaf histogram costs O(rows_in_leaf) like the reference's
@@ -96,6 +96,9 @@ def hist_rowmajor(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     reference GPU backend's float histograms (doc: GPU-Performance.rst).
     backend: "einsum" (one-hot matmul, the TPU path) or "scatter"
     (true scatter-add, the natural CPU kernel).
+    live: ``(lo, hi)``, the rows outside which the caller zeroed ``gh``;
+    the Pallas kernel skips the row blocks outside them
+    (``hist_pallas._hist_call``), the other backends read every row.
     Returns f32 [F, num_bin, C].
     """
     S, F = bins_rm.shape
@@ -131,7 +134,8 @@ def hist_rowmajor(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
             # f32 accumulation (f32 inputs take the exact bf16-triple
             # decomposition inside the kernel instead)
             gh = gh.astype(jnp.bfloat16)
-        return hist_pallas_rm(bins_rm, gh, num_bin, block_rows=block_rows)
+        return hist_pallas_rm(bins_rm, gh, num_bin, block_rows=block_rows,
+                              live=live)
     if backend != "einsum":
         raise ValueError(f"unknown hist_rowmajor backend {backend!r}; "
                          "expected einsum | scatter | pallas")

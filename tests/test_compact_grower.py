@@ -387,8 +387,8 @@ def _same_tree(a, b, exact):
     tolerance, or every array bit for bit."""
     for name in a._fields:
         x, y = getattr(a, name), getattr(b, name)
-        if x is None or name == "first_split_dense":
-            continue
+        if x is None or name in ("first_split_dense", "hist_rows"):
+            continue                # what the grower did, not the tree
         if exact or x.dtype.kind in "ib":
             np.testing.assert_array_equal(x, y, err_msg=name)
         elif name.endswith("_count"):
@@ -467,3 +467,143 @@ def test_first_split_rule(rng, case, backend, resume, dense):
         assert int(tree.split_feature[0]) == 2 and min(sides) <= 64
         assert grower_mod.first_split_dense_rows(R, 2, 6) >= 64
     _same_tree(tree, jax.tree.map(np.asarray, t_f), exact=False)
+
+
+# ---- the kernel is told the segment's rows inside its bucket ---------------
+
+def _no_live_range(monkeypatch):
+    """The kernel's entries as they were: every row block of the bucket."""
+    from lightgbm_tpu.ops import hist_pallas
+    for name in ("hist_pallas_words", "hist_pallas_rm"):
+        entry = getattr(hist_pallas, name)
+        monkeypatch.setattr(
+            hist_pallas, name,
+            lambda *a, live=None, entry=entry, **kw: entry(*a, **kw))
+
+
+LIVE_CASES = {
+    # (case, packed, table held twice, histogram pool)
+    "words": (_odd_columns, True, False, "full"),
+    "words_efb": (_efb, True, False, "full"),
+    "words_clipped_at_the_end": (_clipped_start, True, False, "full"),
+    "words_held_twice": (_odd_columns, True, True, "full"),
+    "words_held_twice_no_pool": (_odd_columns, True, True, "none"),
+    "unpacked": (_odd_columns, False, False, "full"),
+    "unpacked_clipped_at_the_end": (_clipped_start, False, False, "full"),
+}
+
+
+def _grow_live_case(rng, name, monkeypatch, **grower):
+    case, packed, twice, pool = LIVE_CASES[name]
+    compact, meta, bundle, _, rm, gh, X = _tables(
+        rng, case, L=8, **{"hist_rm_backend": "pallas", "hist_pool": pool,
+                           "block_rows": 128, **grower})
+    monkeypatch.setattr(grower_mod, "rows_held_twice", lambda words: twice)
+    if packed:
+        compact = dataclasses.replace(compact, packed_cols=rm.shape[1])
+        rm = pack_words(rm)
+    grow = lambda: _grow_with_order(compact, meta, bundle,  # noqa: E731
+                                    jnp.asarray(rm), gh)
+    return grow, compact, case, bundle, X
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("name", list(LIVE_CASES))
+def test_live_range_grows_the_same_tree(rng, name, quantized, monkeypatch):
+    """``hb`` hands the (interpreted) kernel its segment's rows inside the
+    bucket and the kernel skips the row blocks outside them: the tree,
+    ``leaf_id`` and ``order`` are what the kernel over every block grows,
+    bit for bit, on packed words and unpacked bytes, with a segment clipped
+    at the end of the table (``delta`` > 0), and with a table held twice,
+    whose 2048 bucket is gathered as two blocks of 1024 rows, each handed
+    the range cut to it."""
+    grow, compact, case, bundle, X = _grow_live_case(
+        rng, name, monkeypatch, quantized=quantized,
+        stochastic_rounding=False)
+    ranged = grow()
+    _no_live_range(monkeypatch)
+    every = grow()
+    _assert_same_growth(ranged, every)
+    assert ranged[0].num_leaves == 8
+    assert 0 < ranged[0].hist_rows[1] < ranged[0].hist_rows[2]
+    _assert_case_exercised(case, ranged[0], bundle, X)
+
+
+def _gathered_calls(tree, R, sizes, both):
+    """(start, rows, bucket) of a tree's gathered histogram calls, from
+    its own child counts: node ``i`` splits the segment of the leaf its
+    chain of left children ends in, the left child keeps the segment's
+    start, and the smaller child (``both``: each child) is histogrammed in
+    the smallest bucket that holds it."""
+    def count(c):
+        return int(tree.internal_count[c] if c >= 0 else tree.leaf_count[~c])
+
+    seg, calls = {0: (0, R)}, []
+    for i in range(int(tree.num_leaves) - 1):
+        leaf = int(tree.left_child[i])
+        while leaf >= 0:
+            leaf = int(tree.left_child[leaf])
+        start, rows = seg[~leaf]
+        nl = count(int(tree.left_child[i]))
+        assert nl + count(int(tree.right_child[i])) == rows
+        seg[~leaf], seg[i + 1] = (start, nl), (start + nl, rows - nl)
+        kids = [seg[~leaf], seg[i + 1]]
+        if not both:
+            kids = kids[:1] if nl <= rows - nl else kids[1:]
+        calls.append([(s, n, min(S for S in sizes if S >= n))
+                      for s, n in kids])
+    return calls
+
+
+@pytest.mark.parametrize("name,first_dense", [
+    ("words", True), ("words", False), ("words_clipped_at_the_end", False),
+    ("words_held_twice", False), ("words_held_twice_no_pool", False),
+    ("unpacked", False), ("scatter", False), ("words_blocks_of_512", False),
+    ("words_held_twice_blocks_of_512", False)])
+def test_hist_rows_counts_the_gathered_calls(rng, name, first_dense,
+                                             monkeypatch):
+    """``TreeArrays.hist_rows`` against the tree's own child counts:
+    ``live`` the rows of the children that were histogrammed, ``bucket``
+    their buckets', ``read`` the rows of the bucket in the row blocks that
+    overlap the segment, so ``live <= read <= bucket``. A row block is what
+    the configuration asks (128 rows here, 512 in the last two cases) and a
+    quarter of the bucket where that is less: of buckets of 3000, 2048,
+    1024, 512 and 256 rows the last three take 256, 128 and 128. The calls
+    that read the table in place are not gathered and not counted: a first
+    split's masked pass, a table held twice in its bucket of every row. A
+    backend with no kernel reads every row of its bucket."""
+    asked = 128
+    if name == "scatter":
+        grow, compact, *_ = _grow_live_case(rng, "words", monkeypatch,
+                                            hist_rm_backend="scatter")
+    elif name.endswith("_blocks_of_512"):
+        asked, name = 512, name[:-len("_blocks_of_512")]
+        grow, compact, *_ = _grow_live_case(rng, name, monkeypatch,
+                                            block_rows=512)
+    else:
+        grow, compact, *_ = _grow_live_case(rng, name, monkeypatch)
+    if not first_dense:
+        monkeypatch.setattr(grower_mod, "first_split_dense_rows",
+                            lambda rows, words, cols: rows)
+    tree, leaf_id, _ = grow()
+    R = leaf_id.shape[0]
+    assert tree.first_split_dense == first_dense
+    sizes = grower_mod._bucket_sizes(R, compact.min_bucket)
+    twice, pool = LIVE_CASES.get(name, LIVE_CASES["words"])[2:]
+    calls = _gathered_calls(tree, R, sizes, both=pool == "none")
+    want = np.zeros(3, np.int64)
+    for start, rows, S in sum(calls[1 if first_dense else 0:], []):
+        if twice and S == R:
+            continue
+        delta = start - min(max(start, 0), R - S)
+        block = min(asked, max(128, S // 4))
+        blocks = -(-(delta + rows) // block) - delta // block
+        read = S if name == "scatter" else min(block * blocks, S)
+        want += (rows, read, S)
+    live, read, bucket = (int(x) for x in tree.hist_rows)
+    assert (live, read, bucket) == tuple(want)
+    assert 0 < live <= read <= bucket
+    if name != "scatter":
+        assert read < bucket and live < read
+    host = HostTree(tree, np.arange(tree.split_feature.shape[0] + 8))
+    assert host.hist_rows == (live, read, bucket)

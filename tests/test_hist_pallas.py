@@ -190,7 +190,7 @@ def test_hist_pallas_words_rows_give_way_to_the_vmem_budget(monkeypatch):
         hist_pallas._VMEM_BUDGET_ELEMS >= hist_pallas._resident(32, 2048, 256)
     ran = []
     monkeypatch.setattr(hist_pallas, "_hist_pallas_words",
-                        lambda w, gh, B, F, block_rows, interp:
+                        lambda w, gh, B, F, block_rows, interp, live:
                         ran.append(block_rows))
     words = jnp.zeros((3, 300), jnp.uint32)
     gh = jnp.zeros((300, 3), jnp.float32)
@@ -215,3 +215,82 @@ def test_hist_pallas_words_dtype_matches_rowmajor(rng):
             np.asarray(hist_rowmajor(jnp.asarray(rm.astype(np.int32)), gh,
                                      B, block_rows=512, dtype=dtype,
                                      backend="pallas")))
+
+
+# ---- a live row range: a leaf's segment inside its bucket ------------------
+
+# 3000 rows in blocks of 512: five whole blocks and one of 440 rows
+LIVE_RANGES = {
+    "inside_one_block": (600, 900),
+    "whole_blocks": (512, 2048),
+    "starts_and_ends_inside_blocks": (700, 2300),
+    "into_the_short_last_block": (2500, 3000),
+    "first_row_only": (0, 1),
+    "empty": (1024, 1024),
+    "empty_inside_a_block": (1000, 1000),
+    "empty_at_the_end": (3000, 3000),
+    "whole": (0, 3000),
+}
+
+
+def _live_case(rng, gh_dtype, lo, hi, S=3000, F=37, B=255):
+    rm = rng.integers(0, B, size=(S, F)).astype(np.uint8)
+    inside = (np.arange(S) >= lo) & (np.arange(S) < hi)
+    if gh_dtype == "int8":
+        gh = rng.integers(-8, 8, size=(S, 3)).astype(np.int8)
+    else:
+        gh = rng.normal(size=(S, 3)).astype(np.float32)
+    gh = jnp.asarray(gh * inside[:, None].astype(gh.dtype)).astype(gh_dtype)
+    return rm, gh, inside
+
+
+@pytest.mark.parametrize("gh_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("name", list(LIVE_RANGES))
+def test_hist_pallas_words_live_range_bit_for_bit(rng, name, gh_dtype):
+    """Told which rows carry weight, the kernel leaves out the row blocks
+    outside them, which would have added exact zeros: the same bits as the
+    call over every block, from both entries the grower uses."""
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_rm, hist_pallas_words
+
+    lo, hi = LIVE_RANGES[name]
+    rm, gh, inside = _live_case(rng, gh_dtype, lo, hi)
+    F, B = rm.shape[1], 255
+    words = jnp.asarray(pack_words(rm).T)
+    live = (jnp.int32(lo), jnp.int32(hi))
+    every = hist_pallas_words(words, gh, B, F, block_rows=512)
+    out = hist_pallas_words(words, gh, B, F, block_rows=512, live=live)
+    assert out.dtype == every.dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(every))
+    np.testing.assert_array_equal(
+        np.asarray(hist_pallas_rm(jnp.asarray(rm.astype(np.int32)), gh, B,
+                                  block_rows=512, live=live)),
+        np.asarray(every))
+    assert np.asarray(out).any() == bool(inside.any())
+
+
+@pytest.mark.parametrize("name", list(LIVE_RANGES))
+def test_hist_pallas_words_dead_blocks_are_not_read(rng, name):
+    """The proof that a dead block is skipped and not added as zeros: NaN
+    in ``gh`` everywhere outside the live blocks leaves the histogram
+    finite and equal."""
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_words, live_row_blocks
+
+    lo, hi = LIVE_RANGES[name]
+    rm, gh, _ = _live_case(rng, "float32", lo, hi)
+    F, B, S = rm.shape[1], 255, rm.shape[0]
+    first, n = (int(x) for x in live_row_blocks((lo, hi), S, 512))
+    assert n == -(-hi // 512) - lo // 512
+    block = np.arange(S) // 512
+    dead = (block < first) | (block >= first + n)
+    assert dead.any() or name == "whole"
+    words = jnp.asarray(pack_words(rm).T)
+    want = hist_pallas_words(words, gh, B, F, block_rows=512)
+    poisoned = jnp.where(dead[:, None], jnp.nan, gh)
+    out = np.asarray(hist_pallas_words(
+        words, poisoned, B, F, block_rows=512,
+        live=(jnp.int32(lo), jnp.int32(hi))))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, np.asarray(want))
+    if dead.any():
+        assert np.isnan(np.asarray(hist_pallas_words(
+            words, poisoned, B, F, block_rows=512))).any()

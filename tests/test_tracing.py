@@ -188,7 +188,9 @@ def test_packed_pallas_grower_hands_the_kernel_words_not_unpacked_rows(
                 assert not unpacked, (scope, eqn.primitive.name, aval)
         if eqn.primitive.name == "pallas_call":
             assert "lgbm.hist_kernel" in scope, scope
-            words = eqn.invars[0].aval
+            # behind the two prefetched scalars that name its live blocks
+            live, words = (v.aval for v in eqn.invars[:2])
+            assert live.shape == (2,) and live.dtype == np.int32, live
             assert words.dtype in (np.uint32, np.int32), words
             assert words.shape[0] in (W, -(-W // 8) * 8), words
             assert words.shape[1] in buckets, words
@@ -380,8 +382,8 @@ def test_first_split_dense_is_counted_beside_the_trees(make, steps, dense):
     path fetches what it fetched, as often as it did."""
     X, y = make()
     counters = timer.global_timer.counters
-    before = dict(trees=counters["trees"],
-                  first_split_dense=counters["first_split_dense"])
+    rows = ("hist_rows_live", "hist_rows_read", "hist_rows_bucket")
+    before = {k: counters[k] for k in ("trees", "first_split_dense") + rows}
     booster = lgb.Booster({**PARAMS, "tpu_hist_kernel": "pallas",
                            "min_data_in_leaf": 5, "tpu_min_bucket": 32},
                           lgb.Dataset(X, label=y))
@@ -399,6 +401,11 @@ def test_first_split_dense_is_counted_beside_the_trees(make, steps, dense):
     assert counters["first_split_dense"] - before["first_split_dense"] \
         == dense
     assert [t.first_split_dense for t in trees] == [bool(dense)] * steps
+    # the gathered histogram calls' rows, added up over the trees: the
+    # segments', those in the row blocks the kernel read, the buckets'
+    added = [counters[k] - before[k] for k in rows]
+    assert added == [sum(t.hist_rows[k] for t in trees) for k in range(3)]
+    assert 0 < added[0] <= added[1] <= added[2]
     # one fetch of the leaf counts (the stop check) and one of the trees
     kinds = [type(x).__name__ for x in fetched]
     assert kinds.count("TreeArrays") == 1 and len(fetched) == 2, kinds
