@@ -51,7 +51,7 @@ from ..core.plan import make_plan
 from ..core.objective import ObjectiveFunction, CustomObjective, K_EPSILON
 from ..core.tree import HostTree, TreeArrays, host_tree_to_arrays
 from ..io.dataset_core import BinnedDataset
-from ..ops.split import FeatureMeta, SplitHyperParams
+from ..ops.split import MISSING_ENUM, FeatureMeta, SplitHyperParams
 from ..ops.forest import ServingEngine
 from ..ops.predict import depth_steps, tree_leaf_bins
 from ..utils import log
@@ -1041,6 +1041,11 @@ class GBDT:
             global_timer.note("pool_bytes", slots * slot_bytes)
             global_timer.note("table_words", self.bins_rf.size
                               if self._packed_cols else 0)
+            # the split scan's forward half is compiled in when any column
+            # has a bin for the missing (ops/split.py, static_fwd_dead)
+            global_timer.note("scan_directions", 1 + int(
+                self.feature_meta is not None and np.any(np.asarray(
+                    self.feature_meta.missing_type) != MISSING_ENUM["none"])))
         self._setup_cegb(train)
         self._bins_mv_dev = None
         if self.feature_meta is None:
@@ -2497,6 +2502,11 @@ class GBDT:
             if host.default_left[i]:
                 dtype_bits[i] |= 2
             dtype_bits[i] |= miss_enum[m.missing_type] << 2
+        numeric = (dtype_bits & 1) == 0
+        global_timer.count("splits_missing_right", np.count_nonzero(
+            numeric & ((dtype_bits & 2) == 0)))
+        global_timer.count("splits_on_missing",
+                           np.count_nonzero(dtype_bits >> 2))
         host.threshold_real = thr_real
         host.decision_type = dtype_bits
         host.num_cat = len(cat_words)

@@ -11,20 +11,26 @@ clock — and (b) a record ``(name, start, end, parent, iteration)`` on
 ``time.perf_counter`` in a bounded deque, beside totals and counts that
 ``table()`` prints. Under the sections ``table()`` prints the **counters**
 (``global_timer.count(name)``): what the device decided, read off what comes
-to the host anyway. Six are counted where a grown tree becomes a
+to the host anyway. Eight are counted where a grown tree becomes a
 ``HostTree`` (``models/gbdt.py``, ``_finalize_tree``): ``trees``;
 ``first_split_dense``, those whose first split histogrammed its smaller child
 in one masked pass over the table in place (``TreeArrays.first_split_dense``,
-``core/grower.py``); ``splits``, the trees' real splits; and the rows of the
+``core/grower.py``); ``splits``, the trees' real splits;
+``splits_missing_right``, the numerical splits among them whose
+``default_left`` is false (the forward scan's winners: the missing go right),
+and ``splits_on_missing``, those on a column whose ``missing_type`` is not
+none; and the rows of the
 compact grower's gathered histogram calls (``TreeArrays.hist_rows``):
 ``hist_rows_live``, the leaves' segments; ``hist_rows_read``, the rows in the
 row blocks the kernel read for them; ``hist_rows_bucket``, the buckets the
 segments were padded to. ``1 - read / bucket`` is the share of the buckets
 the kernel skipped, ``1 - live / bucket`` the share of every gathered index
-that is padding. Two are sizes,
+that is padding. Three are notes,
 set and not added (``global_timer.note``) at each set-up (``_setup_train``):
-``pool_bytes``, the histogram pool as the budget left it, and
-``table_words``, the 32-bit words of the packed table.
+``pool_bytes``, the histogram pool as the budget left it,
+``table_words``, the 32-bit words of the packed table, and
+``scan_directions``, 2 where a column has a bin for the missing and the
+split scan's forward half is compiled in (``ops/split.py``), else 1.
 ``LIGHTGBM_TPU_TIMETAG`` (or ``global_timer.enabled = True``) turns on only
 the ``sync=`` barrier and the table printed at the end of training.
 

@@ -4,7 +4,8 @@ print what the compiler did with the table: minutes in the sandbox, no chip.
 
     JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \\
         python scripts/tpu_compile_grower.py --rows 200000 [--cols 67] \\
-        [--leaves 255] [--unpacked] [--text /root/scratch/grow.hlo.txt]
+        [--leaves 255] [--bins 255] [--missing 0] [--unpacked] \\
+        [--text /root/scratch/grow.hlo.txt]
 
 It prints the compile seconds, the compiler's memory analysis (the
 temporaries are the block the chip reports as ``peak_bytes_reserved``) and,
@@ -22,7 +23,15 @@ row-major copy, at 2 M x 67 the word-major parameter; at 400,000 x 2,000 the
 parent of PR 33 copied the table in every branch of the histogram's switch):
 what a PR claims of a cell it checks at that cell's shape, ``--rows 2000000``
 (seven minutes) for `criteo-share.train` and ``--rows 400000 --cols 2000``
-(under a minute) for `epsilon.train`.
+(under a minute) for `epsilon.train`, ``--rows 1000000 --cols 968 --bins 251
+--missing 1`` for `bosch.train`. ``--missing <share>`` gives that share of
+the columns (the first ones) a NaN bin, their last: with any such column the
+split scan's forward direction is in the module, as it is for a table with
+missing values, and ``scan_directions`` says which was compiled. The line
+``channel_minor_scan_copies`` counts the arrays shaped like the scan's prefix
+or suffix sums, ``[.., 3, cols, bins]``, that the compiler laid out with the
+three channels minor (128 lanes for 3), anywhere in the module: PR 33 found
+one, written and reversed once a split.
 """
 import argparse
 import math
@@ -36,12 +45,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 
 from lightgbm_tpu.core.grower import GrowerConfig, make_tree_grower
 from lightgbm_tpu.ops import hist_pallas
-from lightgbm_tpu.ops.split import FeatureMeta
+from lightgbm_tpu.ops.split import MISSING_ENUM, FeatureMeta
 from lightgbm_tpu.utils import timer
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?(%[^\s(]+) \(.*\{\s*$")
@@ -97,6 +107,32 @@ def copies_in_loop(text, table_elements):
                           (opcode == "fusion" and called & relays))]
 
 
+_LAYOUT = re.compile(r"\[([\d,]+)\]\{([\d,]+)")
+
+
+def channel_minor_scan_arrays(text, cols, bins):
+    """The instructions whose result is shaped like the scan's cumulative
+    sums, ``[.., 3, cols, bins]``, and laid out with the three channels
+    minor: [(computation, name, shape)]. Such an array fills 128 lanes for
+    3, and the scan that made one wrote and reversed it once a split."""
+    found, comp = [], None
+    for line in text.splitlines():
+        started = _COMPUTATION.match(line)
+        if started:
+            comp = started.group(1)
+            continue
+        m = timer._INSTRUCTION.match(line)
+        lay = m and not m.group(2).startswith("(") and \
+            _LAYOUT.search(m.group(2))
+        if not lay:
+            continue
+        dims = [int(d) for d in lay.group(1).split(",")]
+        if dims[-3:] == [3, cols, bins] and \
+                int(lay.group(2).split(",")[0]) == len(dims) - 3:
+            found.append((comp, m.group(1), m.group(2)))
+    return found
+
+
 def report(text, table_elements, stage):
     """The lines of the compiled module that say where the stage's gathers
     read from and what was copied."""
@@ -128,6 +164,11 @@ def main():
                     help="columns of the table: 67 is `criteo-share`'s, "
                          "2000 `epsilon`'s")
     ap.add_argument("--leaves", type=int, default=255)
+    ap.add_argument("--bins", type=int, default=255,
+                    help="bins of every column, a NaN bin included")
+    ap.add_argument("--missing", type=float, default=0.0,
+                    help="share of the columns with a NaN bin (missing_type "
+                         "nan): any puts the forward scan in the module")
     ap.add_argument("--unpacked", action="store_true")
     ap.add_argument("--stage", default="partition_fetch")
     ap.add_argument("--text", help="write the compiled module's text here")
@@ -139,9 +180,12 @@ def main():
     # kernels: compile them as the chip would (until PR 30 this script
     # compiled the interpreter's loops in the custom calls' place)
     hist_pallas.default_interpret = lambda: False
-    F, B = args.cols, 255
+    F, B = args.cols, args.bins
+    with_nan = np.arange(F) < round(args.missing * F)
     meta = FeatureMeta(num_bin=jnp.full((F,), B, jnp.int32),
-                       missing_type=jnp.zeros((F,), jnp.int32),
+                       missing_type=jnp.asarray(
+                           np.where(with_nan, MISSING_ENUM["nan"],
+                                    MISSING_ENUM["none"]), jnp.int32),
                        default_bin=jnp.zeros((F,), jnp.int32),
                        is_categorical=jnp.zeros((F,), bool))
     # what `criteo-share.train` and `epsilon.train` resolve their `auto`
@@ -176,6 +220,11 @@ def main():
     in_loop = copies_in_loop(text, args.rows * width)
     for comp, name, shape in in_loop:
         print(f"in the split loop: {name} = {shape}  in {comp}")
+    minor = channel_minor_scan_arrays(text, F, B)
+    for comp, name, shape in minor:
+        print(f"channels minor: {name} = {shape}  in {comp}")
+    print(f"scan_directions {2 if with_nan.any() else 1}")
+    print(f"channel_minor_scan_copies {len(minor)}")
     print(f"table_sized_copies_in_loop {len(in_loop)}")
 
 

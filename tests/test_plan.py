@@ -221,6 +221,7 @@ FIRST_SPLIT_LINES = [
     # copy for a tenth of the price, a price read at 500 words alone
     (41, 164, 1.20),    # the narrowest table held twice: no reading behind it
     (175, 700, 1.08),   # Expo's width: no reading behind it either
+    (242, 968, 1.07),   # Bosch's (1,000,000 rows: the second width run, PR 35)
     (500, 2000, 1.06),  # Epsilon's (my chip run, PR 33, priced it)
 ]
 
@@ -263,6 +264,26 @@ def test_first_split_rule_rises_with_the_width():
     block = above[above.index("# The first split's smaller child"):]
     assert "ledger, PR 31" in block and "ledger, PR 27" in block
     assert "my chip run, PR 33" in block
+
+
+def test_bosch_takes_the_cells_path_and_gathers_its_first_split():
+    """1,000,000 x 968 with a NaN bin in every column (251 bins) on a chip,
+    every ``tpu_*`` auto: the Pallas kernel on packed words, 242 of them a
+    row, between the two widths the held-twice path had been run at (17 held
+    once, 500 held twice). The table is held twice; a first split's smaller
+    child has at most 500,000 rows, the 524,288 bucket, and the rule's line
+    lies at 0.93 R: gathered, two blocks of 262,144 rows."""
+    got = make_plan(platform="tpu", num_data=1_000_000, num_bin_max=251,
+                    quantized=False, hist_dtype="float32",
+                    tree_learner="serial", storage="dense",
+                    row_sched="compact")
+    assert (got.hist_rm_backend, got.level_hist_backend, got.partition_mode,
+            got.pack, got.hist_reduce, got.notes) == \
+        ("pallas", "einsum", "auto", True, "allreduce", ())
+    assert -(-968 // 4) == 242 and plan_mod.rows_held_twice(242)
+    line = plan_mod.first_split_dense_rows(1_000_000, 242, 968)
+    assert 524_288 < line < 1_000_000
+    assert line / 1_000_000 == pytest.approx(0.9345, abs=1e-3)
 
 
 def test_epsilon_takes_the_cells_path_and_gathers_its_first_split():
