@@ -269,7 +269,7 @@ def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
     bins_host = np.ascontiguousarray(eng.train_set.bins.T)   # [R, F] u8
     bins_rm = jnp.asarray(bins_host)
     bins_fm = jnp.asarray(eng.train_set.bins)                # [F, R] u8
-    R = bins_host.shape[0]
+    R, F = bins_host.shape
     grad, hess = eng._gh_fn(eng.score)
     gh = jnp.stack([grad.reshape(-1), hess.reshape(-1),
                     jnp.ones(R, jnp.float32)], axis=1)       # [R, 3] f32
@@ -290,9 +290,14 @@ def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
         compiled = lowered.compile()
         t1 = time.perf_counter()
         out = jax.block_until_ready(compiled(*args))
+        t2 = time.perf_counter()
+        # the price core/plan.py holds (KERNEL_NS_COLUMN_ROW) is this, read
+        # on a second call of the loaded program: seconds over rows x columns
+        jax.block_until_ready(compiled(*args))
+        again = time.perf_counter() - t2
         rec = dict(_hist_err(out, ref, ref_abs), **extra,
-                   compile_s=round(t1 - t0, 2),
-                   run_s=round(time.perf_counter() - t1, 4))
+                   compile_s=round(t1 - t0, 2), run_s=round(t2 - t1, 4),
+                   ns_col_row=round(again / (R * F) * 1e9, 4))
         report[name] = rec
         print(f"[smoke]   {name}: {json.dumps(rec)}", flush=True)
         ok = rec.get("exact", False) if ref_abs is None else \
@@ -309,7 +314,6 @@ def _kernels(eng, sizes: Sizes, on_chip: bool) -> dict:
     run("hist_pallas_rm/f32", rm, (bins_rm, gh), refs["f32"], ref_abs)
     run("hist_pallas_rm/bf16", rm, (bins_rm, gh_bf16), refs["bf16"], ref_abs)
     run("hist_pallas_rm/int8", rm, (bins_rm, gh_i8), refs["int8"], None)
-    F = bins_host.shape[1]
     padded = np.zeros((R, -(-F // 4) * 4), np.uint8)
     padded[:, :F] = bins_host           # byte k of word w = column 4w + k
     words_cm = jnp.asarray(np.ascontiguousarray(padded.view(np.uint32).T))

@@ -432,10 +432,13 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             def hist_rm(sb, ghv, live=None, block_rows=None):
                 return _hist_mv(sb, ghv, B)
         else:
+            # gh's third column is the engine's count, 0 or 1, and stays
+            # so under every mask here: the Pallas kernel is told
             hist_rm = functools.partial(hist_rowmajor, num_bin=B,
                                         block_rows=cfg.block_rows,
                                         dtype=cfg.hist_dtype,
-                                        backend=cfg.hist_rm_backend)
+                                        backend=cfg.hist_rm_backend,
+                                        count_in_bf16=True)
     # Distributed mode: collectives (psum over the mesh's data axis) must
     # not sit inside divergent control flow. In full mode the per-split
     # histogram pass is masked instead of branched; in compact mode the
@@ -736,7 +739,8 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             if words_kernel:
                 hist_leaf = functools.partial(
                     hist_pallas_words, num_bin=B, num_cols=Fp,
-                    block_rows=cfg.block_rows, dtype=cfg.hist_dtype)
+                    block_rows=cfg.block_rows, dtype=cfg.hist_dtype,
+                    count_in_bf16=True)
             else:
                 hist_leaf = hist_rm
             # the Pallas kernel reads a bucket's live row blocks alone;

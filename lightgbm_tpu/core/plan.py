@@ -75,13 +75,18 @@ ROW_GATHER_NS_INDEX = 20.6
 ROW_GATHER_NS_WORD = 0.55
 # the gh rows f32[S,3] out of VMEM: 34.3 ms over the same 7.9 M indices
 GH_GATHER_NS_INDEX = 4.3
-# the Pallas kernel, a row it reads: 11.65 ns at 67 columns (the root's call
-# 23.3 ms for 2 M rows read in place), 0.174 ns a column a row; one price for
+# the Pallas kernel, a row it reads, a column: read from the root's call,
+# which reads the table in place, on the three cells (my chip runs, PR 36,
+# PERF.md section 5): 39.40 ms for 400,000 rows of 2,000 columns, 0.0493;
+# 47.77 ms for 1,000,000 of 968, 0.0494; 7.055 ms for 2,000,000 of 67,
+# 0.0527 (a row costs about 0.2 ns beside its columns). `chip_smoke.py`
+# prints the same quotient for each kernel entry, at 28 columns and with the
+# call's wrapper in it (0.0897 for `hist_pallas_words`). One price for
 # every call, the small buckets' too (PERF.md section 6, PR 34). A call that
 # reads the table in place pays it for every row; a gathered call for the
 # row blocks that overlap its leaf's segment, not for the bucket's padding
 # (since PR 34: `ops/hist_pallas.py`, `_hist_call`'s live range)
-KERNEL_NS_COLUMN_ROW = 0.174
+KERNEL_NS_COLUMN_ROW = 0.050
 
 # The packed words of a row from which the compact grower holds the table
 # twice: the narrowest row at which the compiler was seen to re-lay a table
@@ -119,15 +124,15 @@ def first_split_dense_rows(num_rows: int, num_words: int,
     tree's first split. The dense pass costs ``num_rows`` rows of the
     kernel; the gathered call costs the bucket's rows of the gather and of
     the kernel: dense when ``bucket x (gather + kernel) > num_rows x
-    kernel``. A wider table moves the line up, towards half the rows
-    (about R/7 at 28 columns, R/4 at 67, R/3 at 137 and at 160); a table
-    held twice (``rows_held_twice``: from 41 words) gathers whole rows for a
-    tenth of that, and the line lies at 0.83 R and over: a smaller child's
-    bucket passes it only when the bucket is most of the table, so nearly
-    every first split is gathered. Rough: three widths priced it (17 and 35
-    words the word-major gather, 500 the row-major one; between 41 and 499
-    the row-major price is drawn through that one point), and the kernel's
-    price stood at all three (0.172 ns a column a row at 2,000 columns).
+    kernel``. A wider table moves the line up (about R/21 at 28 columns,
+    R/11 at 67, R/7 at 137 and at 160); a table held
+    twice (``rows_held_twice``: from 41 words) gathers whole rows for a
+    tenth of that, and the line lies at 0.60 R and over (0.80 R at 968
+    columns, 0.83 R at 2,000): a smaller child's bucket passes it only
+    when the bucket is most of the table, so nearly every first split is
+    gathered. Rough: three widths priced it (17 and 35 words the word-major
+    gather, 500 the row-major one; between 41 and 499 the row-major price
+    is drawn through that one point).
 
     The rule still prices the gathered call's kernel by its bucket. Since
     PR 34 that call pays for its live row blocks alone, so the rule

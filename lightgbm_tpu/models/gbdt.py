@@ -1046,6 +1046,15 @@ class GBDT:
             global_timer.note("scan_directions", 1 + int(
                 self.feature_meta is not None and np.any(np.asarray(
                     self.feature_meta.missing_type) != MISSING_ENUM["none"])))
+            # which Pallas kernel a run had: the rows of the operand a
+            # column's contraction holds still (ops/hist_pallas.py); 0
+            # where another backend builds the histograms
+            from ..ops.hist_pallas import expanded_rows
+            global_timer.note("hist_expanded_rows", expanded_rows(
+                self.grower_cfg.num_bin,
+                not self.grower_cfg.quantized and
+                self.grower_cfg.hist_dtype == "float32", count_in_bf16=True)
+                if self.grower_cfg.hist_rm_backend == "pallas" else 0)
         self._setup_cegb(train)
         self._bins_mv_dev = None
         if self.feature_meta is None:

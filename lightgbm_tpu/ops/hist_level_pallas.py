@@ -26,8 +26,9 @@ Layout trick — segment-ALIGNED rows, one owner per block:
   node's accumulator stays pinned in VMEM across its whole row range
   and is written back exactly once — the revisit-free accumulation
   contract Pallas TPU requires.
-- the kernel body is the proven one-hot MXU contraction of
-  ``ops/hist_pallas.py`` (bf16 hi/mid/lo triple decomposition for f32
+- the kernel body is a one-hot MXU contraction, every byte compared with
+  every bin (``ops/hist_pallas.py`` factorises the bin instead and is 3.5
+  times cheaper a column; bf16 hi/mid/lo triple decomposition for f32
   inputs — exact ~24-bit accumulation at native bf16 rate; int8 one-hot
   with EXACT int32 accumulation for quantized gradients), zero-inited
   via ``pl.when`` on the first block of each owner.
@@ -35,8 +36,8 @@ Layout trick — segment-ALIGNED rows, one owner per block:
 Padding cost: ≤ ``(n_nodes + 1) * block_rows`` dead rows (gh = 0, so
 they accumulate nothing). ``level_tiles`` caps ``block_rows`` so the
 pad stays ~25% of R at the deepest levels and the VMEM residents
-(bins tile + pinned accumulator + one [Bp, RB] one-hot) fit the same
-~4 MB budget as ``fit_tiles``; infeasible shapes (huge num_bin) report
+(bins tile + pinned accumulator + one [Bp, RB] one-hot) fit
+``fit_tiles``' ~4 MB budget; infeasible shapes (huge num_bin) report
 ``ok=False`` and callers fall back to the blocks composition.
 
 Transients are O(R): one padded u8 gather [Rp, F], its i32 feature-major
@@ -68,8 +69,8 @@ def level_tiles(feature_tile: int, num_bin: int, block_rows: int,
                 n_nodes: int, num_rows: int) -> tuple:
     """Fit (feature_tile, block_rows) for the level kernel.
 
-    Same VMEM residents (and the same ~4 MB budget) as
-    ``hist_pallas.fit_tiles``; additionally caps ``block_rows`` so the
+    The ~4 MB budget of ``hist_pallas.fit_tiles`` over this kernel's own
+    residents (``_resident``); additionally caps ``block_rows`` so the
     segment-alignment padding — at most ``(n_nodes + 1) * block_rows``
     dead rows — stays around a quarter of the real row count at deep
     levels (1024 nodes at 1M rows: 256-row blocks, ≤ ~26% pad).
@@ -78,7 +79,18 @@ def level_tiles(feature_tile: int, num_bin: int, block_rows: int,
     must use the blocks composition instead.
     """
     pad_cap = max(128, (num_rows // max(4 * n_nodes, 1)) // 128 * 128)
-    return fit_tiles(feature_tile, num_bin, min(block_rows, pad_cap))
+    return fit_tiles(feature_tile, num_bin, min(block_rows, pad_cap),
+                     resident=_resident)
+
+
+def _resident(feature_tile: int, block_rows: int, num_bin: int) -> int:
+    """This kernel's residents, in 32-bit elements: its ``[Bp, RB]``
+    one-hot, where ``hist_pallas._resident`` counts that kernel's two
+    operands."""
+    Bp = _pad_to(num_bin, 128)
+    return (feature_tile * block_rows       # bins tile
+            + 32 * feature_tile * Bp        # accumulator (Cp<=32)
+            + Bp * block_rows)              # one-hot
 
 
 def _hist_level_kernel(owner_ref, bins_ref, gh_ref, out_ref, *,
@@ -95,8 +107,8 @@ def _hist_level_kernel(owner_ref, bins_ref, gh_ref, out_ref, *,
     The accumulator is zero-initialized on the FIRST block of each
     owner (j == 0 or an owner change); because owners are
     non-decreasing in j, a node's bank is never revisited after
-    write-back. Contraction shape is identical to
-    ``hist_pallas._hist_kernel``.
+    write-back. The contraction is ``gh [Cp, RB]`` against a ``[Bp, RB]``
+    one-hot.
     """
     j = pl.program_id(1)
     prev = owner_ref[jnp.maximum(j - 1, 0)]

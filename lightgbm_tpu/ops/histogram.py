@@ -84,7 +84,8 @@ def hist_xla(bins_t: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
 
 def hist_rowmajor(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
                   block_rows: int = 4096, dtype: str = "float32",
-                  backend: str = "einsum", live=None) -> jnp.ndarray:
+                  backend: str = "einsum", live=None,
+                  count_in_bf16: bool = False) -> jnp.ndarray:
     """Histogram over a ROW-MAJOR [S, F] bin block (the gathered-leaf layout
     of the compact scheduler — rows of one leaf gathered contiguously, so a
     leaf histogram costs O(rows_in_leaf) like the reference's
@@ -99,6 +100,10 @@ def hist_rowmajor(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
     live: ``(lo, hi)``, the rows outside which the caller zeroed ``gh``;
     the Pallas kernel skips the row blocks outside them
     (``hist_pallas._hist_call``), the other backends read every row.
+    count_in_bf16: ``gh``'s last column is 0 or 1 (any value bfloat16 holds
+    exactly); the Pallas kernel then leaves its mid and lo parts out of the
+    float32 triple (``hist_pallas._hist_call``), the other backends do
+    nothing with it.
     Returns f32 [F, num_bin, C].
     """
     S, F = bins_rm.shape
@@ -135,7 +140,7 @@ def hist_rowmajor(bins_rm: jnp.ndarray, gh: jnp.ndarray, num_bin: int,
             # decomposition inside the kernel instead)
             gh = gh.astype(jnp.bfloat16)
         return hist_pallas_rm(bins_rm, gh, num_bin, block_rows=block_rows,
-                              live=live)
+                              live=live, count_in_bf16=count_in_bf16)
     if backend != "einsum":
         raise ValueError(f"unknown hist_rowmajor backend {backend!r}; "
                          "expected einsum | scatter | pallas")

@@ -25,12 +25,15 @@ compact grower's gathered histogram calls (``TreeArrays.hist_rows``):
 row blocks the kernel read for them; ``hist_rows_bucket``, the buckets the
 segments were padded to. ``1 - read / bucket`` is the share of the buckets
 the kernel skipped, ``1 - live / bucket`` the share of every gathered index
-that is padding. Three are notes,
+that is padding. Four are notes,
 set and not added (``global_timer.note``) at each set-up (``_setup_train``):
 ``pool_bytes``, the histogram pool as the budget left it,
-``table_words``, the 32-bit words of the packed table, and
+``table_words``, the 32-bit words of the packed table,
 ``scan_directions``, 2 where a column has a bin for the missing and the
-split scan's forward half is compiled in (``ops/split.py``), else 1.
+split scan's forward half is compiled in (``ops/split.py``), else 1, and
+``hist_expanded_rows``, the rows of the operand a column's contraction
+holds still in the Pallas histogram kernel (``ops/hist_pallas.py``,
+``expanded_rows``; 0 where another backend builds the histograms).
 ``LIGHTGBM_TPU_TIMETAG`` (or ``global_timer.enabled = True``) turns on only
 the ``sync=`` barrier and the table printed at the end of training.
 

@@ -6,6 +6,8 @@ On the CPU test mesh the kernel runs under the Pallas interpreter; the
 kernel body (and therefore the arithmetic) is identical to compiled TPU
 mode.
 """
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -190,7 +192,7 @@ def test_hist_pallas_words_rows_give_way_to_the_vmem_budget(monkeypatch):
         hist_pallas._VMEM_BUDGET_ELEMS >= hist_pallas._resident(32, 2048, 256)
     ran = []
     monkeypatch.setattr(hist_pallas, "_hist_pallas_words",
-                        lambda w, gh, B, F, block_rows, interp, live:
+                        lambda w, gh, B, F, block_rows, interp, live, c7:
                         ran.append(block_rows))
     words = jnp.zeros((3, 300), jnp.uint32)
     gh = jnp.zeros((300, 3), jnp.float32)
@@ -294,3 +296,122 @@ def test_hist_pallas_words_dead_blocks_are_not_read(rng, name):
     if dead.any():
         assert np.isnan(np.asarray(hist_pallas_words(
             words, poisoned, B, F, block_rows=512))).any()
+
+
+# ---- the factorised body against exact host sums ---------------------------
+# A bin is 32 * hi + lo (``hist_pallas._LO``): the kernel contracts a one-hot
+# of ``lo`` against ``gh`` masked by ``hi``. ``gh`` here is made of small
+# dyadic values, so
+# every partial sum is exact in f32 and the order of accumulation cannot
+# show: the kernel must equal the host's f64 sums to the last bit.
+
+def _exact_gh(rng, S, gh_dtype, count01=False):
+    if gh_dtype == "int8":
+        gh = rng.integers(-8, 8, size=(S, 3)).astype(np.int8)
+    else:
+        # k / 4, |k| <= 32: exact in bfloat16, and so are their f32 sums
+        gh = (rng.integers(-32, 33, size=(S, 3)) / 4.0).astype(np.float32)
+    if count01:
+        gh[:, 2] = rng.integers(0, 2, size=S)
+    return gh
+
+
+def _host_sums(rm, gh, B):
+    out = np.zeros((rm.shape[1], B, gh.shape[1]))
+    for f in range(rm.shape[1]):
+        for c in range(gh.shape[1]):
+            out[f, :, c] = np.bincount(rm[:, f], weights=gh[:, c].astype(
+                np.float64), minlength=B)[:B]
+    return out
+
+
+def _words_hist(rm, gh, B, gh_dtype, **kw):
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_words
+    return np.asarray(hist_pallas_words(
+        jnp.asarray(pack_words(rm).T), jnp.asarray(gh).astype(gh_dtype), B,
+        rm.shape[1], block_rows=512, **kw))
+
+
+@pytest.mark.parametrize("gh_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("B", [255, 63])
+def test_factorised_body_equals_exact_host_sums(rng, B, gh_dtype):
+    """Both entries, 255 bins (8 high parts) and 63 (8 sublanes of high
+    parts, two in use), a last row block of 476 rows, a last tile of 5
+    columns."""
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_rm
+    S, F = 1500, 37
+    rm = rng.integers(0, B, size=(S, F)).astype(np.uint8)
+    gh = _exact_gh(rng, S, gh_dtype)
+    want = _host_sums(rm, gh, B)
+    out = _words_hist(rm, gh, B, gh_dtype)
+    assert out.shape == (F, B, 3)
+    assert out.dtype == (np.int32 if gh_dtype == "int8" else np.float32)
+    np.testing.assert_array_equal(out.astype(np.float64), want)
+    np.testing.assert_array_equal(np.asarray(hist_pallas_rm(
+        jnp.asarray(rm.astype(np.int32)), jnp.asarray(gh).astype(gh_dtype),
+        B, block_rows=512)).astype(np.float64), want)
+
+
+# the bins either side of a change of the high part, whether the byte is cut
+# 4 + 4 or 5 + 3 (``_LO`` 16 or 32), the first and the last
+NIBBLE_EDGES = [0, 15, 16, 17, 31, 32, 33, 127, 128, 223, 224, 239, 240, 254,
+                255]
+
+
+@pytest.mark.parametrize("gh_dtype", ["float32", "bfloat16", "int8"])
+def test_factorised_body_on_the_nibbles_edges(rng, gh_dtype):
+    """Every row's byte is one of the bins where the low part wraps and
+    the high part steps; 256 bins, so that byte 255 is a bin. No other bin
+    gets anything."""
+    S, F, B = 1100, 9, 256
+    rm = rng.choice(np.asarray(NIBBLE_EDGES, np.uint8), size=(S, F))
+    gh = _exact_gh(rng, S, gh_dtype)
+    out = _words_hist(rm, gh, B, gh_dtype).astype(np.float64)
+    np.testing.assert_array_equal(out, _host_sums(rm, gh, B))
+    others = np.setdiff1d(np.arange(B), NIBBLE_EDGES)
+    assert not out[:, others].any() and out[:, NIBBLE_EDGES].any()
+
+
+@pytest.mark.parametrize("gh_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("B", [251, 255, 256, 63])
+def test_factorised_body_every_byte_the_last_bin(rng, B, gh_dtype):
+    """`bosch.train`'s commonest block: every cell the NaN bin, the table's
+    last (250 of 251 there). The whole of ``gh`` lands in one bin of every
+    column."""
+    S, F = 1024, 12
+    rm = np.full((S, F), B - 1, np.uint8)
+    rm[:, 5] = rng.integers(0, B, size=S)       # one column that is not
+    gh = _exact_gh(rng, S, gh_dtype)
+    out = _words_hist(rm, gh, B, gh_dtype).astype(np.float64)
+    np.testing.assert_array_equal(out, _host_sums(rm, gh, B))
+    total = gh.astype(np.float64).sum(axis=0)
+    for f in (0, 4, 6, 11):
+        np.testing.assert_array_equal(out[f, B - 1], total)
+        assert not out[f, :B - 1].any()
+
+
+@pytest.mark.parametrize("entry", ["words", "rm"])
+@pytest.mark.parametrize("B", [255, 63])
+def test_count_in_bf16_leaves_out_two_channels_bit_for_bit(rng, B, entry):
+    """The grower's third column is 0/1, so its mid and lo parts in the
+    bf16 triple are zero: told so, the kernel contracts seven channels and
+    not nine, and every sum is the sum it was. Normal ``g`` and ``h``, whose
+    three parts are all in use."""
+    from lightgbm_tpu.ops.hist_pallas import hist_pallas_rm, hist_pallas_words
+    S, F = 1500, 37
+    rm = rng.integers(0, B, size=(S, F)).astype(np.uint8)
+    gh = rng.normal(size=(S, 3)).astype(np.float32)
+    gh[:, 2] = rng.integers(0, 2, size=S)
+    gh[:, :2] *= gh[:, 2:]                    # as the grower masks a leaf
+    if entry == "words":
+        fn = functools.partial(hist_pallas_words, jnp.asarray(
+            pack_words(rm).T), jnp.asarray(gh), B, F, block_rows=512)
+    else:
+        fn = functools.partial(hist_pallas_rm, jnp.asarray(
+            rm.astype(np.int32)), jnp.asarray(gh), B, block_rows=512)
+    nine, seven = np.asarray(fn()), np.asarray(fn(count_in_bf16=True))
+    assert seven.shape == nine.shape == (F, B, 3) and nine.any()
+    np.testing.assert_array_equal(seven, nine)
+    np.testing.assert_array_equal(
+        seven[:, :, 2].astype(np.float64),
+        _host_sums(rm, gh[:, 2:].astype(np.float64), B)[:, :, 0])

@@ -363,10 +363,12 @@ def test_sync_blocks_only_when_enabled(enabled):
 # ---- the counters: how often the first split ran dense ---------------------
 
 def lopsided_table(rows=3000, cols=10):
-    """One column sends 1 % of the rows one way and decides the label."""
+    """One column sends 0.4 % of the rows one way and decides the label:
+    under the 32-row bucket, which the first split's rule (its line at 55
+    rows here) keeps gathered."""
     rng = np.random.default_rng(5)
     X = rng.normal(size=(rows, cols)).astype(np.float32)
-    X[:, 3] = rng.random(rows) < 0.01
+    X[:, 3] = rng.random(rows) < 0.004
     return X, X[:, 3].copy()
 
 
@@ -414,6 +416,36 @@ def test_first_split_dense_is_counted_beside_the_trees(make, steps, dense):
                           ["counter", "count"]))
     assert {ln.split()[0]: int(ln.split()[1]) for ln in lines[at + 1:]} \
         == dict(counters)
+
+
+# (parameters, the note): the float32 triple's channels, seven since the
+# grower's count is 0 or 1, over the eight high parts of 255 bins (and the
+# one sublane tile 63 bins' two still take), the three channels of bfloat16
+# and of quantized int8 gradients, and a backend that is not the Pallas kernel
+EXPANDED_ROWS = [
+    ({"tpu_hist_kernel": "pallas"}, 7 * 8),
+    ({"tpu_hist_kernel": "pallas", "max_bin": 63}, 7 * 8),
+    ({"tpu_hist_kernel": "pallas", "tpu_hist_dtype": "bfloat16"}, 3 * 8),
+    ({"tpu_hist_kernel": "pallas", "use_quantized_grad": True}, 3 * 8),
+    ({"tpu_hist_kernel": "einsum"}, 0),
+    ({}, 0),
+]
+
+
+@pytest.mark.parametrize("params,rows", EXPANDED_ROWS, ids=[
+    "f32", "f32-63bins", "bf16", "int8", "einsum", "cpu-auto"])
+def test_hist_expanded_rows_says_which_kernel_a_run_had(params, rows):
+    """Set at set-up beside ``scan_directions``: the rows of the operand a
+    column's contraction holds still in the Pallas kernel (the one-hot it
+    had until PR 36 would read 256), 0 where the kernel does not run; a
+    second booster sets it anew."""
+    X, y = table(rows=600)
+    timer.global_timer.note("hist_expanded_rows", -1)
+    max_bin = params.get("max_bin", 255)
+    lgb.Booster({**PARAMS, **params},
+                lgb.Dataset(X, label=y, params={"max_bin": max_bin}))
+    assert timer.global_timer.counters["hist_expanded_rows"] == rows
+    assert rows < 256
 
 
 def test_table_prints_counters_without_sections():
