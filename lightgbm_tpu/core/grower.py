@@ -728,7 +728,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
         F = int(meta.num_bin.shape[0]) if bundled else Fp
 
         if quantized:
-            with timer.stage("gradients"):
+            with timer.stage("gh_quantize"):
                 gh, conv = quantize_gradients(cfg, gh, rng_key,
                                               reduce_max=reduce_max,
                                               localize_key=localize_key)
@@ -760,6 +760,19 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     hist_pallas_words, num_bin=B, num_cols=Fp,
                     block_rows=cfg.block_rows, dtype=cfg.hist_dtype,
                     count_in_bf16=True)
+                if quantized:
+                    # a leaf's gh rows are gathered by index, and the TPU
+                    # gathers no rows out of an int8 [R, 3]: the compiler
+                    # re-laid it row-major, 128 lanes a row, once a split
+                    # (51 MB at 400,000 rows; PERF.md section 6).
+                    # int32 rows gather as float32 ones do; the kernel
+                    # takes each call's rows back as int8
+                    with timer.stage("gh_quantize"):
+                        gh = gh.astype(jnp.int32)
+                    int8_leaf = hist_leaf
+
+                    def hist_leaf(blk, g, **kw):
+                        return int8_leaf(blk, g.astype(jnp.int8), **kw)
             else:
                 hist_leaf = hist_rm
             # the Pallas kernel reads a bucket's live row blocks alone;

@@ -78,10 +78,12 @@ import jax
 from . import log
 
 SCOPE = "lgbm."
-# every operation of the training step that does work sits in one of these
+# every operation of the training step that does work sits in one of these;
+# ``gh_quantize`` (the scales, the rounding and the int8 cast of
+# ``use_quantized_grad``) only in a quantized program
 STAGES = ("gradients", "hist_gather", "hist_kernel", "hist_subtract",
           "split_scan", "partition_fetch", "partition_order", "tree_update",
-          "score_update")
+          "score_update", "gh_quantize")
 AMBIGUOUS = "(ambiguous)"
 MAX_RECORDS = 4096
 

@@ -5,7 +5,7 @@ print what the compiler did with the table: minutes in the sandbox, no chip.
     JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \\
         python scripts/tpu_compile_grower.py --rows 200000 [--cols 67] \\
         [--leaves 255] [--bins 255] [--missing 0] [--unpacked] \\
-        [--text /root/scratch/grow.hlo.txt]
+        [--quantized 4] [--text grow.hlo.txt]
 
 It prints the compile seconds, the compiler's memory analysis (the
 temporaries are the block the chip reports as ``peak_bytes_reserved``) and,
@@ -275,6 +275,10 @@ def main():
                     help="share of the columns with a NaN bin (missing_type "
                          "nan): any puts the forward scan in the module")
     ap.add_argument("--unpacked", action="store_true")
+    ap.add_argument("--quantized", type=int, default=0,
+                    help="levels of quantized gradients (use_quantized_grad, "
+                         "rounded to the nearest): 4 is "
+                         "`epsilon-quantized`'s, 0 float32 gradients")
     ap.add_argument("--stage", default="partition_fetch")
     ap.add_argument("--text", help="write the compiled module's text here")
     args = ap.parse_args()
@@ -293,12 +297,15 @@ def main():
                                     MISSING_ENUM["none"]), jnp.int32),
                        default_bin=jnp.zeros((F,), jnp.int32),
                        is_categorical=jnp.zeros((F,), bool))
+    quantized = dict(quantized=True, quant_bins=args.quantized,
+                     stochastic_rounding=False) if args.quantized else {}
     # what `criteo-share.train` and `epsilon.train` resolve their `auto`
     # parameters to on a v5e
     cfg = GrowerConfig(num_leaves=args.leaves, num_bin=B,
                        row_sched="compact", hist_rm_backend="pallas",
                        partition_mode="auto", min_bucket=2048,
-                       packed_cols=0 if args.unpacked else F)
+                       packed_cols=0 if args.unpacked else F,
+                       **quantized)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])

@@ -47,10 +47,10 @@ def fresh_compiles():
         compilation_cache.reset_cache()
 
 
-def grown(steps=3):
+def grown(steps=3, **params):
     X, y = table()
     with fresh_compiles():
-        booster = lgb.Booster(dict(PARAMS), lgb.Dataset(X, label=y))
+        booster = lgb.Booster({**PARAMS, **params}, lgb.Dataset(X, label=y))
         for _ in range(steps):
             booster.update()
     return booster
@@ -75,10 +75,21 @@ def compile_events():
     return seen
 
 
-def test_stage_map_holds_every_stage_of_the_path(booster):
-    found = collections.Counter(timer.stage_map().values())
-    assert found
-    assert set(timer.STAGES) <= set(found)
+@pytest.mark.parametrize("quantized", [False, True])
+def test_stage_map_holds_every_stage_of_the_path(booster, quantized):
+    """Every stage is in the map; ``gh_quantize`` only while a program
+    that quantizes its gradients (``use_quantized_grad``) lives."""
+    import gc
+    path = grown(use_quantized_grad=True, stochastic_rounding=False) \
+        if quantized else booster
+    assert path._engine._async_on()
+    found = set(timer.stage_map().values())
+    assert set(timer.STAGES) - {"gh_quantize"} <= found
+    assert ("gh_quantize" in found) == quantized
+    if quantized:
+        del path
+        gc.collect()
+        assert "gh_quantize" not in set(timer.stage_map().values())
 
 
 def test_stage_map_leaves_few_working_instructions_unnamed(booster):
