@@ -1047,14 +1047,18 @@ class GBDT:
                 self.feature_meta is not None and np.any(np.asarray(
                     self.feature_meta.missing_type) != MISSING_ENUM["none"])))
             # which Pallas kernel a run had: the rows of the operand a
-            # column's contraction holds still (ops/hist_pallas.py); 0
-            # where another backend builds the histograms
-            from ..ops.hist_pallas import expanded_rows
-            global_timer.note("hist_expanded_rows", expanded_rows(
-                self.grower_cfg.num_bin,
-                not self.grower_cfg.quantized and
-                self.grower_cfg.hist_dtype == "float32", count_in_bf16=True)
-                if self.grower_cfg.hist_rm_backend == "pallas" else 0)
+            # column's contraction holds still and the lanes a column has
+            # in its output (ops/hist_pallas.py); 0 where another backend
+            # builds the histograms
+            from ..ops.hist_pallas import expanded_rows, output_lanes
+            pallas = self.grower_cfg.hist_rm_backend == "pallas"
+            float32_gh = not self.grower_cfg.quantized and \
+                self.grower_cfg.hist_dtype == "float32"
+            for name, of in (("hist_expanded_rows", expanded_rows),
+                             ("hist_output_lanes", output_lanes)):
+                global_timer.note(name, of(
+                    self.grower_cfg.num_bin, float32_gh, count_in_bf16=True)
+                    if pallas else 0)
         self._setup_cegb(train)
         self._bins_mv_dev = None
         if self.feature_meta is None:

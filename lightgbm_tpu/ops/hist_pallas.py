@@ -8,9 +8,13 @@ no fast scatter-add, so the scatter is reformulated as a one-hot matmul
 explicit VMEM residency and without comparing a bin with every bin:
 
 - grid = (feature tiles, row blocks); the row-block axis is innermost and
-  maps to the SAME output block, so the [lo, FT*Np] accumulator stays
+  maps to the SAME output block, so the [FT/P, lo, 128] accumulator stays
   pinned in VMEM across the whole row loop — zero HBM traffic for partial
-  histograms (XLA's scan materializes the [F, B, C] carry each step).
+  histograms (XLA's scan materializes the [F, B, C] carry each step). A
+  column has the lanes its channels use, ``L`` (``_operand_shape``): 64 for
+  float32 ``gh``'s seven channels, 32 for three, so ``P`` = two or four
+  columns share a lane tile and the output is what the histogram needs,
+  not 128 lanes a column.
 - per step and feature: a bin is ``_LO * hi + lo`` and ``[bin == b] =
   [hi_r == hi] * [lo_r == lo]``, so the kernel builds the one-hot of the
   low part alone, ``[lo, RB]``, and ``A [channels * num_hi, RB]``, each
@@ -19,8 +23,8 @@ explicit VMEM residency and without comparing a bin with every bin:
   the MXU with f32/int32 accumulation: ``H[c, hi, lo] = sum_r A[c, hi, r]
   * onehot_lo[lo, r]``. The terms of every sum are the terms a [Bp, RB]
   one-hot gives (`ops/hist_level_pallas.py` builds that one: 256 rows a
-  column through the MXU where this kernel pushes 32 + 56, and a v5e prices
-  a column by those rows, PERF.md section 6, PR 36).
+  column through the MXU where this kernel pushes 32 + 128, ``A`` padded to
+  a lane tile; PERF.md section 6).
 
 TPU tiling rules (measured on v5e: blocks whose last two dims are not
 multiples of (sublane, lane) = (8, 128) for 32-bit types fail to lower):
@@ -110,9 +114,13 @@ def _hist_kernel(live_ref, bins_ref, gh_ref, out_ref, *, feature_tile: int,
               ``f``'s int32 [1, RB] row out of either
     gh_ref:   bf16/int8 [Cp, RB] — transposed, channel-padded, leaf-masked;
               the first ``channels`` rows are read
-    out_ref:  f32/int32 [lo, FT*Np] — accumulator, pinned across row
-              blocks; feature ``f``'s ``Np`` lanes hold, at lane
-              ``c * num_hi + hi`` of sublane ``l``, channel ``c`` of bin
+    out_ref:  f32/int32 [FT/P, lo, W] — accumulator, pinned across row
+              blocks: the tile's lane tiles, ``W = max(L, 128)`` lanes
+              each, ``P = W // L`` columns a lane tile. Feature ``f`` has
+              ``L`` lanes (``_operand_shape``) of lane tile ``f % (FT/P)``
+              from lane ``f // (FT/P) * L`` on (slot ``s`` holds the
+              tile's ``s``-th run of ``FT/P`` columns); its lane ``c *
+              num_hi + hi`` of sublane ``l`` holds channel ``c`` of bin
               ``lo * hi + l``
 
     ``live_in_last``: how many features of the last of the ``tiles``
@@ -126,9 +134,13 @@ def _hist_kernel(live_ref, bins_ref, gh_ref, out_ref, *, feature_tile: int,
     sublane's and zero elsewhere (a select: every value is ``gh``'s own or
     zero, so every sum adds the terms a ``[Bp, RB]`` one-hot would). Their
     contraction over the row axis on the MXU is the feature's histogram,
-    ``[lo, channels * num_hi]``, stored to a static lane slice of the
-    accumulator. Every op is Mosaic-friendly by construction: static row
-    slices broadcast against 2D iotas, no gather, no transpose, no reshape.
+    ``[lo, channels * num_hi]``. ``A`` is one lane tile wide (or the
+    column's ``L`` lanes, where they are more), its rows first and zeros
+    after; the contraction's lanes are rotated to the column's slot
+    (``pltpu.roll``), so the columns of a tile land on disjoint lanes and
+    are summed exactly before the one add into the accumulator's lane
+    tile. Every op is Mosaic-friendly by construction: static row slices
+    broadcast against 2D iotas, no gather, no transpose, no reshape.
     """
     # both read here: the interpreter resolves a program id at the kernel's
     # top level, not inside a branch of a branch
@@ -145,7 +157,9 @@ def _hist_kernel(live_ref, bins_ref, gh_ref, out_ref, *, feature_tile: int,
     def _():
         bins = bins_ref[:]                          # [FT, RB] / [FT/4, RB]
         rb = bins.shape[1]
-        lanes = out_ref.shape[1] // feature_tile    # Np
+        width = out_ref.shape[2]                    # W: rows of A
+        tile_cols = out_ref.shape[0]                # FT / P lane tiles
+        lanes = width * tile_cols // feature_tile   # L
         iota_lo = lax.broadcasted_iota(jnp.int32, (lo, rb), 0)
         iota_hi = lax.broadcasted_iota(jnp.int32, (num_hi, rb), 0)
 
@@ -165,25 +179,33 @@ def _hist_kernel(live_ref, bins_ref, gh_ref, out_ref, *, feature_tile: int,
         rep = [jnp.broadcast_to(gh[c:c + 1, :], (num_hi, rb))
                for c in range(channels)]
         zero = jnp.zeros((num_hi, rb), wide)
-        dead = lanes - channels * num_hi
+        dead = width - channels * num_hi
         tail = [jnp.zeros((dead, rb), wide)] if dead else []
 
-        def add(f):
+        def hist(f):
             row = fetch(bins, f)                                 # [1, RB]
             oh_lo = ((row & (lo - 1)) == iota_lo).astype(narrow)  # [lo, RB]
             keep = (row >> (lo.bit_length() - 1)) == iota_hi  # [num_hi, RB]
             a = jnp.concatenate(
                 [jnp.where(keep, r, zero) for r in rep] + tail,
-                axis=0).astype(narrow)                           # [Np, RB]
-            # contract over rows: [lo, RB] x [Np, RB] -> [lo, Np]
-            hist_f = lax.dot_general(
+                axis=0).astype(narrow)                        # [width, RB]
+            # contract over rows: [lo, RB] x [width, RB] -> [lo, width]
+            h = lax.dot_general(
                 oh_lo, a, (((1,), (1,)), ((), ())),
                 preferred_element_type=acc_dtype)
-            out_ref[:, f * lanes:(f + 1) * lanes] += hist_f
+            # the live rows lead ``A`` as they do with a lane tile a column
+            # (placed at the slot inside ``A``, the kernel took 40 % longer
+            # on a v5e, PERF.md section 6); the result's lanes turn to the
+            # column's slot, the zeros with them
+            at = f // tile_cols * lanes
+            return pltpu.roll(h, at, 1) if at else h
 
         def features(n):
-            for f in range(n):
-                add(f)
+            # a lane tile's columns fill disjoint lanes: their sum is exact
+            for t in range(min(n, tile_cols)):
+                cols = range(t, n, tile_cols)
+                out_ref[t] += functools.reduce(
+                    lambda acc, f: acc + hist(f), cols[1:], hist(cols[0]))
 
         if tiles == 1 or live_in_last == feature_tile:
             features(live_in_last)
@@ -260,6 +282,7 @@ def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
     int8_mode = gh.dtype == jnp.int8
     f32_mode = gh.dtype == jnp.float32
     acc_dtype = jnp.int32 if int8_mode else jnp.float32
+    n = C
     if f32_mode:
         # Full f32 accuracy at native bf16 MXU rate: contract all 3C
         # bf16 component channels in ONE matmul and re-sum the component
@@ -277,7 +300,8 @@ def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
     # sublane-align the channel axis per dtype tile: (16,128) bf16,
     # (32,128) int8
     Cp = 32 if int8_mode else _pad_to(max(Cin, 16), 16)
-    num_hi, Np = _operand_shape(num_bin, Cin)
+    num_hi, L = _operand_shape(num_bin, Cin)
+    W = max(L, 128)
     Rp = _pad_to(R, block_rows)
     tiles = pl.cdiv(num_features, feature_tile)
     Fp = tiles * feature_tile
@@ -314,28 +338,51 @@ def _hist_call(bins_op, bins_block, gh, num_bin, num_features,
                              lambda i, j, lv: (0, row_block(j, lv)),
                              memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((_LO, feature_tile * Np),
-                                   lambda i, j, lv: (0, i),
+            out_specs=pl.BlockSpec((feature_tile * L // W, _LO, W),
+                                   lambda i, j, lv: (i, 0, 0),
                                    memory_space=pltpu.VMEM),
         ),
-        out_shape=jax.ShapeDtypeStruct((_LO, Fp * Np), acc_dtype),
+        out_shape=jax.ShapeDtypeStruct((Fp * L // W, _LO, W), acc_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(live_blocks, bins_op, gh_t)
+    return _unpack(out, feature_tile, L, num_hi, Cin, C, n)[
+        :num_features, :num_bin]
 
-    # [lo, Fp*Np] -> [Fp, hi, lo, Cin] -> [F, num_bin, Cin]
-    hist = out.reshape(_LO, Fp, Np)[:, :, :Cin * num_hi]
-    hist = hist.reshape(_LO, Fp, Cin, num_hi).transpose(1, 3, 0, 2)
-    hist = hist.reshape(Fp, num_hi * _LO, Cin)[:num_features, :num_bin, :]
-    if f32_mode:
-        # re-sum the bf16 hi/mid/lo component histograms in f32; a column
-        # without mid and lo parts is its hi part
-        sums = (hist[:, :, 0:n] + hist[:, :, C:C + n] +
-                hist[:, :, C + n:C + 2 * n])
-        return sums if n == C else jnp.concatenate(
-            [sums, hist[:, :, n:C]], axis=2)
-    return hist[:, :, :C]
+
+def _unpack(out, feature_tile, L, num_hi, Cin, C, n):
+    """The kernel's output ``[T, lo, W]`` (``_hist_kernel``'s ``out_ref``)
+    as the histogram ``[Fp, num_hi * lo, C]``. With ``Cin > C`` its
+    channels are the bf16 triple's parts, ``C`` hi parts then ``n`` mid and
+    ``n`` lo ones, re-summed in f32, ``(hi + mid) + lo``; a channel without
+    mid and lo parts is its hi part.
+
+    Two transposes, neither of them with a lane dimension under 128: every
+    lane becomes a row, where the triple is re-summed and the bins are
+    rows; then the bins become the lanes, as the grower's pool lays a
+    histogram out."""
+    T, _, W = out.shape
+    P = W // L
+    tiles = T * P // feature_tile
+    hist = out.transpose(2, 1, 0).reshape(P, L, _LO, T)    # [P, L, lo, T]
+
+    def part(a, b):
+        return hist[:, a * num_hi:b * num_hi]
+    # jaxlint: disable=JL002 — Cin and C are the call's channel counts,
+    # Python ints
+    if Cin > C:
+        sums = part(0, n) + part(C, C + n) + part(C + n, C + 2 * n)
+        hist = sums if n == C else jnp.concatenate([sums, part(n, C)], 1)
+    else:
+        hist = part(0, C)
+    bins = num_hi * _LO
+    # [P, C, bins, T] -> [C, T, P, bins]; lane tile t of feature tile i
+    # holds column i * FT + s * FT / P + t in slot s
+    hist = hist.reshape(P, C, bins, T).transpose(1, 3, 0, 2)
+    hist = hist.reshape(C, tiles, feature_tile // P, P, bins)
+    return hist.transpose(0, 1, 3, 2, 4).reshape(
+        C, tiles * feature_tile, bins).transpose(1, 2, 0)
 
 
 # The benchmark finds the kernel's events in a device trace by the name of
@@ -392,20 +439,39 @@ _MAX_CHANNELS = 9
 
 def _operand_shape(num_bin: int, channels: int) -> tuple:
     """(num_hi, lanes) of a column's contraction: the sublanes a bin's high
-    part runs over, whole tiles of 32-bit elements, and the lanes a column
-    has in the output, ``channels * num_hi`` of them in use."""
+    part runs over, whole tiles of 32-bit elements, and the lanes ``L`` a
+    column has in the accumulator and the output, ``channels * num_hi`` of
+    them in use: a power of two from 16 up to 64, so that ``128 // L``
+    columns share a lane tile (and 8 columns, the least feature tile, fill
+    whole ones), else whole lane tiles."""
     num_hi = _pad_to(pl.cdiv(num_bin, _LO), 8)
-    return num_hi, _pad_to(channels * num_hi, 128)
+    live = channels * num_hi
+    if live > 64:
+        return num_hi, _pad_to(live, 128)
+    return num_hi, max(16, 1 << (live - 1).bit_length())
+
+
+def _channels(float32_gh: bool, count_in_bf16: bool) -> int:
+    """A call's channels: from float32 ``gh`` the triple's nine, seven with
+    ``count_in_bf16``; else three."""
+    return 3 if not float32_gh else _MAX_CHANNELS - 2 * count_in_bf16
 
 
 def expanded_rows(num_bin: int, float32_gh: bool,
                   count_in_bf16: bool = False) -> int:
-    """Rows of ``A``, the operand a column's contraction holds still: the
-    call's channels (from float32 ``gh`` the triple's nine, seven with
-    ``count_in_bf16``; else three) over the high parts of ``num_bin`` bins.
+    """Rows of ``A`` in use, the operand a column's contraction holds
+    still: the call's channels over the high parts of ``num_bin`` bins.
     The ``[Bp, RB]`` one-hot had 256."""
-    channels = 3 if not float32_gh else _MAX_CHANNELS - 2 * count_in_bf16
+    channels = _channels(float32_gh, count_in_bf16)
     return channels * _operand_shape(num_bin, channels)[0]
+
+
+def output_lanes(num_bin: int, float32_gh: bool,
+                 count_in_bf16: bool = False) -> int:
+    """Lanes a column has in the kernel's output: 64 for float32 ``gh``
+    with ``count_in_bf16``, 32 for bfloat16 or int8, 128 for the whole
+    triple's nine channels."""
+    return _operand_shape(num_bin, _channels(float32_gh, count_in_bf16))[1]
 
 
 def _resident(feature_tile: int, block_rows: int, num_bin: int) -> int:

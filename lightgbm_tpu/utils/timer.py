@@ -32,15 +32,18 @@ bucket and every row at a dense first split, so ``1 - live / read`` is the
 share of the partition's indices that are padding. The two ``part_rows_*``
 are counted only for a tree whose grower recorded them (the compact one):
 a masked or level grower's tree, or a loaded one, adds nothing to them,
-not a zero. Four are notes,
+not a zero. Five are notes,
 set and not added (``global_timer.note``) at each set-up (``_setup_train``):
 ``pool_bytes``, the histogram pool as the budget left it,
 ``table_words``, the 32-bit words of the packed table,
 ``scan_directions``, 2 where a column has a bin for the missing and the
-split scan's forward half is compiled in (``ops/split.py``), else 1, and
+split scan's forward half is compiled in (``ops/split.py``), else 1,
 ``hist_expanded_rows``, the rows of the operand a column's contraction
 holds still in the Pallas histogram kernel (``ops/hist_pallas.py``,
-``expanded_rows``; 0 where another backend builds the histograms).
+``expanded_rows``), and ``hist_output_lanes``, the lanes a column has in
+that kernel's output (``output_lanes``: 64 on float32 gradients, 32 on
+bfloat16 or quantized ones); both 0 where another backend builds the
+histograms.
 ``LIGHTGBM_TPU_TIMETAG`` (or ``global_timer.enabled = True``) turns on only
 the ``sync=`` barrier and the table printed at the end of training.
 

@@ -42,7 +42,11 @@ least of them, 3 where the f32 sums are kept to float32 against the 0/1
 matrix, 1 where they were cut to bf16. ``conditionals`` counts the
 module's ``conditional``s, and ``part_record_in_branches`` the writes of the
 partition's counter that sit in one of their branches (0: it is written at
-the split loop's level).
+the split loop's level). ``hist_output_bytes`` is the largest histogram
+kernel output in the split loop, and ``hist_output_copies_in_loop`` counts
+the instructions there that re-lay such an output whole, or the histogram
+switch's ``[cols, bins, 3]`` result (listed above it): a copy the pool's
+layout asks of the switch is paid once a split.
 """
 import argparse
 import math
@@ -81,7 +85,7 @@ def _elements(shape):
 def _instructions(text):
     """Every instruction of the module: (computation, name, result shape,
     opcode, the computations it calls, those of them that are a
-    ``conditional``'s branches)."""
+    ``conditional``'s branches, its operands)."""
     comp = None
     for line in text.splitlines():
         started = _COMPUTATION.match(line)
@@ -91,14 +95,15 @@ def _instructions(text):
         m = timer._INSTRUCTION.match(line)
         if not m or comp is None:
             continue
-        name, shape, opcode, _ = m.groups()
+        name, shape, opcode, operands = m.groups()
         called, branches = set(), set()
         for one, many in _CALLED.findall(line):
             if one:
                 called.add(one)
             else:
                 branches.update(timer._OPERAND.findall(many))
-        yield comp, name, shape, opcode, called | branches, branches
+        yield (comp, name, shape, opcode, called | branches, branches,
+               timer._OPERAND.findall(operands))
 
 
 def _reached(calls, roots):
@@ -119,7 +124,7 @@ def copies_in_loop(text, table_elements):
     elements and each fusion of that size whose computation holds such a
     ``copy`` or ``transpose``. Returns [(computation, name, shape)]."""
     body_of, calls, relays, bodies = {}, {}, set(), []
-    for comp, name, shape, opcode, called, _ in _instructions(text):
+    for comp, name, shape, opcode, called, _, _ in _instructions(text):
         calls.setdefault(comp, set()).update(called)
         sized = not shape.startswith("(") and \
             _elements(shape) == table_elements
@@ -135,6 +140,51 @@ def copies_in_loop(text, table_elements):
                           (opcode == "fusion" and called & relays))]
 
 
+_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "s8": 1, "u8": 1,
+          "pred": 1}
+
+
+def hist_outputs_in_loop(text, cols, bins):
+    """What the split loop does with the histogram kernel's output: the
+    bytes of the largest ``_hist_pallas*`` custom call's result in the
+    computations a ``while`` body reaches, and the ``copy`` instructions
+    there whose operand (through bitcasts and tuple elements) is such a
+    result or a ``conditional``'s ``[cols, bins, 3]`` one, the histogram's
+    switch, and the ``reshape`` and ``transpose`` ones, which re-lay it as
+    a copy does. Returns (bytes, [(computation, name, shape)])."""
+    calls, made, bodies, copies = {}, {}, [], []
+    for comp, name, shape, opcode, called, _, operands in \
+            _instructions(text):
+        calls.setdefault(comp, set()).update(called)
+        if opcode == "while":
+            bodies.extend(called)
+        made[name] = (comp, shape, opcode, operands[:1])
+    loop = _reached(calls, bodies)
+
+    def origin(name):
+        while made[name][2] in ("bitcast", "get-tuple-element") and \
+                made[name][3]:
+            name = made[name][3][0]
+        return name
+
+    def kernel(name):
+        return made[name][2] == "custom-call" and \
+            name.startswith("%_hist_pallas")
+
+    largest = max((_elements(shape) * _BYTES[shape.split("[")[0]]
+                   for name, (comp, shape, _, _) in made.items()
+                   if comp in loop and kernel(name)), default=0)
+    for name, (comp, shape, opcode, operand) in made.items():
+        if comp not in loop or not operand or \
+                opcode not in ("copy", "reshape", "transpose"):
+            continue
+        src = origin(operand[0])
+        if kernel(src) or made[src][2] == "conditional" and \
+                shape.split("{")[0].endswith(f"[{cols},{bins},3]"):
+            copies.append((comp, name, shape))
+    return largest, sorted(copies)
+
+
 def in_branches(text, shape, opcode):
     """The ``opcode`` instructions whose result is ``shape`` (layout
     aside) in the computations that a ``conditional``'s branches reach,
@@ -142,7 +192,8 @@ def in_branches(text, shape, opcode):
     name)], conditionals). What sits in a branch is paid where the branch
     runs, and a ``conditional`` in the split loop once a split."""
     calls, found, roots, conditionals = {}, [], [], 0
-    for comp, name, result, op, called, branches in _instructions(text):
+    for comp, name, result, op, called, branches, _ in \
+            _instructions(text):
         calls.setdefault(comp, set()).update(called)
         roots.extend(branches)
         conditionals += op == "conditional"
@@ -354,6 +405,11 @@ def main():
     print(f"channel_minor_scan_copies {len(minor)}")
     print(f"scan_contraction_min_passes "
           f"{min((p for *_, p in dots if p is not None), default=0):.2f}")
+    out_bytes, out_copies = hist_outputs_in_loop(text, F, B)
+    for comp, name, shape in out_copies:
+        print(f"histogram output copied: {name} = {shape}  in {comp}")
+    print(f"hist_output_bytes {out_bytes}")
+    print(f"hist_output_copies_in_loop {len(out_copies)}")
     print(f"table_sized_copies_in_loop {len(in_loop)}")
 
 

@@ -459,6 +459,30 @@ def test_hist_expanded_rows_says_which_kernel_a_run_had(params, rows):
     assert rows < 256
 
 
+# (parameters, the note): the lanes a column has in the kernel's output,
+# 64 for the float32 triple's seven channels over eight high parts (at 63
+# bins too), 32 for three channels of bfloat16 or int8 gradients, 0 where
+# another backend builds the histograms
+OUTPUT_LANES = [(params, lanes) for (params, _), lanes in
+                zip(EXPANDED_ROWS, [64, 64, 32, 32, 0, 0])]
+
+
+@pytest.mark.parametrize("params,lanes", OUTPUT_LANES, ids=[
+    "f32", "f32-63bins", "bf16", "int8", "einsum", "cpu-auto"])
+def test_hist_output_lanes_says_what_the_kernel_wrote(params, lanes):
+    """Set beside ``hist_expanded_rows``: the lanes a column has in the
+    Pallas kernel's output (a whole lane tile of 128 when every column had
+    its own), 0 where the kernel does not run; a second booster sets it
+    anew."""
+    X, y = table(rows=600)
+    timer.global_timer.note("hist_output_lanes", -1)
+    max_bin = params.get("max_bin", 255)
+    lgb.Booster({**PARAMS, **params},
+                lgb.Dataset(X, label=y, params={"max_bin": max_bin}))
+    assert timer.global_timer.counters["hist_output_lanes"] == lanes
+    assert lanes in (0, 32, 64)
+
+
 def test_table_prints_counters_without_sections():
     t = timer.Timer()
     assert t.table() == "(no timing sections recorded)"
