@@ -1,7 +1,8 @@
 """Standing proof that the main path starts and runs on the chip.
 
 One process drives train -> kernels -> scan sums -> predict -> explain ->
-serve at the full width of the model the repo benches (synthetic Higgs shape: 28
+serve -> DART (async against sync and the plain reference's walk) at the
+full width of the model the repo benches (synthetic Higgs shape: 28
 features, max_bin=255, num_leaves=255, binary) through the entry points a
 user calls (``lgb.train`` / ``Booster.update`` / ``Booster.predict`` /
 ``Booster.serve``), checks every result against the repo's own host
@@ -48,6 +49,7 @@ PARAMS = {
     "verbose": -1,
 }
 STEADY_ITERS = 5
+DART_ITERS = 24
 SERVE_CLIENTS = 4
 SERVE_REQUEST_ROWS = (1, 32, 4096)
 EXPLAIN_ROWS = 32
@@ -542,6 +544,85 @@ def _serve(bst, X, n_devices: int) -> dict:
     return report
 
 
+def _dart(X, y) -> dict:
+    """``boosting=dart`` (drop settings raised so that most iterations
+    drop) for DART_ITERS iterations with a fifth of four columns missing,
+    on the asynchronous device path and on the synchronous one: the two
+    paths' training scores agree, and both agree with the plain
+    reference's float64 walk of the model (``benchmark/references/
+    gbdt_dart.py``, on codes made from the model's own thresholds)."""
+    import importlib.util
+    import os
+
+    import jax
+
+    import lightgbm_tpu as lgb
+    X = X.copy()
+    X[:, :4][np.random.default_rng(1).random((len(X), 4)) < 0.2] = np.nan
+    params = dict(PARAMS, boosting="dart", drop_rate=0.3, skip_drop=0.25)
+    got = {}
+    for mode in ("true", "false"):
+        t0 = time.perf_counter()
+        bst = lgb.Booster(dict(params, tpu_async_boosting=mode),
+                          lgb.Dataset(X, label=y))
+        dropped = 0
+        for _ in range(DART_ITERS):
+            bst.update()
+            dropped += len(bst._engine.drop_index)
+        jax.block_until_ready(bst._engine.score)
+        got[mode] = {"async": bool(bst._engine._async_on()),
+                     "seconds": round(time.perf_counter() - t0, 2),
+                     "dropped": dropped,
+                     "score": np.asarray(bst._engine.score[0], np.float64),
+                     "model": bst.model_to_string()}
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "references", "gbdt_dart.py")
+    spec = importlib.util.spec_from_file_location("smoke_gbdt_dart", path)
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    # a column's codes: how many of the models' thresholds lie under a
+    # value (the thresholds are each column's bin bounds, so a code decides
+    # every split as the value does)
+    cuts = [set() for _ in range(X.shape[1])]
+    for g in got.values():
+        feats = []
+        for line in g["model"].splitlines():
+            key, _, value = line.partition("=")
+            if key == "split_feature":
+                feats = [int(f) for f in value.split()]
+            elif key == "threshold":
+                for f, r in zip(feats, value.split()):
+                    cuts[f].add(float(r))
+    cuts = [np.asarray(sorted(c), np.float64) for c in cuts]
+    check(max(len(c) for c in cuts) < ref.MISSING,
+          "a column has too many thresholds for byte codes")
+    codes = np.stack([np.searchsorted(c, X[:, f], side="left")
+                      for f, c in enumerate(cuts)]).astype(np.uint8)
+    codes[np.isnan(X.T)] = ref.MISSING
+    walked = {m: ref.walk(ref.trees_from_text(got[m]["model"], cuts), codes)
+              for m in got}
+    report = {m: {k: v for k, v in g.items() if k in ("async", "seconds",
+                                                       "dropped")}
+              for m, g in got.items()}
+    report["async_sync_max_gap"] = float(np.max(np.abs(
+        got["true"]["score"] - got["false"]["score"])))
+    report["walk_max_gap"] = max(float(np.max(np.abs(
+        got[m]["score"] - walked[m]))) for m in got)
+    print(f"[smoke]   {json.dumps(report)}", flush=True)
+    check(got["true"]["async"] and not got["false"]["async"],
+          f"DART resolved async {report}")
+    check(got["true"]["dropped"] > DART_ITERS,
+          f"DART dropped {got['true']['dropped']} trees in {DART_ITERS} "
+          "iterations")
+    check(report["async_sync_max_gap"] <= 1e-5,
+          f"DART's async and sync scores part by "
+          f"{report['async_sync_max_gap']}")
+    check(report["walk_max_gap"] <= 1e-4,
+          f"DART's scores part from the reference's walk of its model by "
+          f"{report['walk_max_gap']}")
+    return report
+
+
 def _data_parallel(sizes: Sizes, devs) -> dict:
     """More than one chip: the train leg with tree_learner=data over all
     of them (rows per chip as in the one-chip leg), placement checked
@@ -671,6 +752,8 @@ def main(argv=None) -> int:
         reports["predict"] = _predict(bst, X, sizes)
     with phase("serve"):
         reports["serve"] = _serve(bst, X, len(devs))
+    with phase("dart"):
+        reports["dart"] = _dart(X, y)
     if len(devs) > 1:
         with phase("data_parallel"):
             reports["data_parallel"] = _data_parallel(sizes, devs)

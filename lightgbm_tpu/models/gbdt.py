@@ -279,12 +279,13 @@ class GBDT:
     def _async_on(self) -> bool:
         """Resolve (once) whether the sync-free fast path applies.
 
-        Requirements: plain GBDT boosting with no per-iteration host
-        feedback — no linear leaves (host lstsq), no CEGB bookkeeping,
-        no quantized leaf renewal, no L1-style RenewTreeOutput, no
-        position bias Newton step, and a sampler that either never
-        reads gradients (bagging) or can sample on device (GOSS via
-        sample_dev). Any tree learner qualifies: the distributed
+        Requirements: GBDT or DART boosting (DART's drops are drawn on the
+        host and re-scored on the device, models/dart.py) with no
+        per-iteration host feedback — no linear leaves (host lstsq), no
+        CEGB bookkeeping, no quantized leaf renewal, no L1-style
+        RenewTreeOutput, no position bias Newton step, and a sampler that
+        either never reads gradients (bagging) or can sample on device
+        (GOSS via sample_dev). Any tree learner qualifies: the distributed
         learners' collectives live inside the jitted grower program,
         and the device trees they return are replicated, so the
         deferred-materialization machinery is learner-agnostic."""
@@ -295,7 +296,7 @@ class GBDT:
             want = (jax.default_backend() != "cpu" if mode == "auto"
                     else mode in ("true", "1", "yes", "on"))
             self._async_mode = bool(
-                want and self.NAME == "gbdt"
+                want and self.NAME in ("gbdt", "dart")
                 and self._grow is not None
                 and self._gh_fn is not None
                 and not self._linear
@@ -423,6 +424,10 @@ class GBDT:
                 break
         return finished
 
+    def _tree_grown(self, k: int, leaf_id) -> None:
+        """Every row's leaf in the tree just grown for class ``k``, as the
+        grower returns it. DART keeps it (models/dart.py)."""
+
     def _async_traverse_add(self, score, tree_dev: TreeArrays, bins_dev,
                             rate: float, k: int, num_steps: int = None):
         """score[k] += rate * tree(bins) with degenerate trees masked —
@@ -549,6 +554,7 @@ class GBDT:
                 tree_dev, leaf_id = self._grow(
                     self._train_bins(), gh, fmask,
                     self._cegb_penalty(), rng_key)
+            self._tree_grown(k, leaf_id)
             rate = jnp.float32(self.shrinkage_rate)
             # jaxlint: disable=JL005 — dispatch-only timing, see above
             with global_timer.section("GBDT::UpdateScore",
@@ -656,7 +662,7 @@ class GBDT:
             mappers, monotone, penalty=contri) if mappers else None
         self.num_bin_max = int(max((m.num_bin for m in mappers), default=2))
         # the feature-major device copy is only needed by traversal paths
-        # (rollback, DART drops, continued training, valid replay) — it is
+        # (rollback, continued training, valid replay) — it is
         # materialized lazily so training doesn't hold a dead full-dataset
         # copy in HBM next to bins_rf / bins_sharded
         self._sharded_ingest = getattr(train, "shard", None) is not None
@@ -1178,8 +1184,9 @@ class GBDT:
         """Feature-major [F, R] device bins for traversal paths, lazily
         materialized (training reads bins_rf / bins_sharded instead).
         With multi-value sparse storage the dense matrix is reconstructed
-        on demand — only rollback/DART/continued-training traversal needs
-        it, and it costs the dense footprint (warned once)."""
+        on demand — only rollback/continued-training traversal needs it
+        (DART keeps each tree's leaf of every row instead), and it costs
+        the dense footprint (warned once)."""
         if getattr(self, "_sharded_ingest", False):
             log.fatal(
                 "this operation needs the full [F, N] training table, "
@@ -1193,7 +1200,7 @@ class GBDT:
             # direct-bundled storage: reconstruct logical bins once for
             # the traversal consumer (same cost note as multival below)
             log.warning("densifying EFB-bundled bins for a traversal "
-                        "path (rollback/DART/continued training) — this "
+                        "path (rollback/continued training) — this "
                         "costs the logical bin footprint")
             self._bins_fr_host = self.train_set.ensure_logical_bins()
         if self._bins_dev_cache is None and self._bins_fr_host is None:
@@ -1207,8 +1214,8 @@ class GBDT:
         if mv_pair is not None:
             from ..ops.hist_multival import densify
             log.warning("densifying multi-value sparse bins for a "
-                        "traversal path (rollback/DART/continued "
-                        "training) — this costs the dense bin footprint")
+                        "traversal path (rollback/continued training) — "
+                        "this costs the dense bin footprint")
             dflt = np.asarray(
                 [m.default_bin for m in self.train_set.used_bin_mappers()],
                 np.int32)
@@ -1884,9 +1891,13 @@ class GBDT:
 
     def _tree_outputs(self, t: HostTree, bins_dev,
                       raw: Optional[np.ndarray] = None) -> jnp.ndarray:
-        """Per-row output of a host tree over binned data. Linear trees
-        route over bins but add raw-feature linear terms (ref: tree.cpp
-        PredictionFunLinear operates on binned decisions + raw pointers)."""
+        """Per-row output of one host tree over binned data, for the
+        replays that start from host trees (rollback_one_iter,
+        init_from_model, add_valid_data) and for DART's linear trees only:
+        DART re-scores every other tree from the leaves it keeps for each
+        row (models/dart.py). Linear trees route over bins but add
+        raw-feature linear terms (ref: tree.cpp PredictionFunLinear
+        operates on binned decisions + raw pointers)."""
         arrs = _host_tree_to_arrays(t, self.config.num_leaves)
         leaf = tree_leaf_bins(arrs, bins_dev, self.feature_meta.num_bin,
                               self.feature_meta.missing_type,
@@ -2245,6 +2256,7 @@ class GBDT:
                 tree_dev, leaf_id = self._grow(train_bins, gh, fmask,
                                                self._cegb_penalty(),
                                                rng_key)
+            self._tree_grown(k, leaf_id)
             self._hb_sync_beat()
             with global_timer.section("Tree::ToHost", iteration=self.iter):
                 host = HostTree(jax.tree.map(np.asarray, tree_dev),

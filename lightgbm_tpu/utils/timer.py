@@ -43,7 +43,11 @@ holds still in the Pallas histogram kernel (``ops/hist_pallas.py``,
 ``expanded_rows``), and ``hist_output_lanes``, the lanes a column has in
 that kernel's output (``output_lanes``: 64 on float32 gradients, 32 on
 bfloat16 or quantized ones); both 0 where another backend builds the
-histograms.
+histograms. Two are counted by DART (``models/dart.py``) at each
+iteration that drops trees, where it dispatches the re-scoring:
+``dart_trees_rescored``, the trees dropped, and ``dart_rows_rescored``, the
+rows times the trees it looked up (training rows; the validation sets' are
+not counted).
 ``LIGHTGBM_TPU_TIMETAG`` (or ``global_timer.enabled = True``) turns on only
 the ``sync=`` barrier and the table printed at the end of training.
 
@@ -83,10 +87,12 @@ from . import log
 SCOPE = "lgbm."
 # every operation of the training step that does work sits in one of these;
 # ``gh_quantize`` (the scales, the rounding and the int8 cast of
-# ``use_quantized_grad``) only in a quantized program
+# ``use_quantized_grad``) only in a quantized program, ``dart_rescore`` (the
+# re-scoring of DART's dropped trees and the score updates it feeds,
+# ``models/dart.py``) only in a DART one
 STAGES = ("gradients", "hist_gather", "hist_kernel", "hist_subtract",
           "split_scan", "partition_fetch", "partition_order", "tree_update",
-          "score_update", "gh_quantize")
+          "score_update", "gh_quantize", "dart_rescore")
 AMBIGUOUS = "(ambiguous)"
 MAX_RECORDS = 4096
 

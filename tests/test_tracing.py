@@ -78,13 +78,14 @@ def compile_events():
 @pytest.mark.parametrize("quantized", [False, True])
 def test_stage_map_holds_every_stage_of_the_path(booster, quantized):
     """Every stage is in the map; ``gh_quantize`` only while a program
-    that quantizes its gradients (``use_quantized_grad``) lives."""
+    that quantizes its gradients (``use_quantized_grad``) lives, and
+    ``dart_rescore`` belongs to DART alone (``tests/test_dart.py``)."""
     import gc
     path = grown(use_quantized_grad=True, stochastic_rounding=False) \
         if quantized else booster
     assert path._engine._async_on()
     found = set(timer.stage_map().values())
-    assert set(timer.STAGES) - {"gh_quantize"} <= found
+    assert set(timer.STAGES) - {"gh_quantize", "dart_rescore"} <= found
     assert ("gh_quantize" in found) == quantized
     if quantized:
         del path
