@@ -311,7 +311,7 @@ _reg("tpu_partition_mode", str, "auto", ())  # auto | scatter | sort
 # (auto: scatter on CPU, core/plan.py; on a chip the grower sorts buckets
 #  of 32,768 rows up — measured 1.77 ms vs 5.17 ms scatter at 1M rows on
 #  v5e, docs/TPU_RUNBOOK.md — and scatters smaller ones)
-_reg("tpu_min_bucket", int, 2048, ())        # smallest pow2 segment bucket
+_reg("tpu_min_bucket", int, 2048, ())        # smallest segment ladder rung
 _reg("tpu_rows_per_block", int, 1024, ())    # row tile for histogram kernels
 # opt-in device-side bagging: draw the bagging mask on device from a
 # stateless key chain instead of host RNG + [N] mask upload (~15-25 ms

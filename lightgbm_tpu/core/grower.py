@@ -68,7 +68,9 @@ class GrowerConfig:
     level_hist_backend: str = ""
     # compact-mode segment partition primitive: scatter | sort
     partition_mode: str = "scatter"
-    # smallest pow2 segment bucket (smaller leaves pad up to this)
+    # smallest rung of the segment ladders (smaller leaves pad up to the
+    # first power of two at or over it; the partition's fetch, two rungs an
+    # octave, to the first rung at or over it)
     min_bucket: int = 2048
     # histogram memory policy: "full" keeps the [L, F, B, 3] per-leaf pool
     # (sibling subtraction, fastest); "none" keeps NO pool and computes
@@ -195,12 +197,13 @@ class GrowState(NamedTuple):
     # where there was none (compact scheduling; summed after the loop into
     # ``TreeArrays.hist_rows``)
     hist_calls: jnp.ndarray = None
-    # i32 [L-1, 2]: the (rows, rows read) of the leaf split ``i``
+    # i32 [L-1, 2]: the (rows, rows ordered) of the leaf split ``i``
     # partitioned: its segment and the static rows of the partition branch
     # that took it, its bucket or every row at a dense first split; (0, 0)
     # where step ``i`` split nothing. Written at the loop body's level from
-    # the index that picks the branch, summed after the loop into
-    # ``TreeArrays.part_rows``. A hybrid handoff records the compact loop's
+    # the index that picks the branch, counted after the loop into
+    # ``TreeArrays.part_rows``, where the rows the fetch read are derived
+    # from the segment's. A hybrid handoff records the compact loop's
     # splits alone (the level phase's sort is not a partition here); on a
     # mesh each shard its local rows; None off compact scheduling
     part_calls: jnp.ndarray = None
@@ -265,6 +268,24 @@ def _bucket_sizes(num_rows: int, min_bucket: int) -> list:
         sizes.append(p)
         p //= 2
     return sizes
+
+
+def _fetch_sizes(num_rows: int, min_bucket: int) -> list:
+    """Descending static rungs of the partition's column fetch: R, then
+    every 2^k and every 3 * 2^(k-1) under it down to ``min_bucket``.
+
+    Two rungs an octave where ``_bucket_sizes`` has one, and every bucket
+    among them: a leaf's rows are read from the column up to the smallest
+    rung that holds them, 15.5 % of the indices padding on leaf sizes even
+    on a log scale where the buckets leave 27.9 % (PERF.md section 6).
+    Only the fetch's gathers take it: the order's sort, compiled once a
+    bucket, keeps the bucket (ROADMAP B2)."""
+    rungs = {num_rows}
+    for k in range(num_rows.bit_length()):
+        for v in (1 << k, 3 << k >> 1):
+            if max(min_bucket, 1) <= v < num_rows:
+                rungs.add(v)
+    return sorted(rungs, reverse=True)
 
 
 def quantize_gradients(cfg: GrowerConfig, gh, rng_key,
@@ -738,6 +759,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
         if compact:
             sizes = _bucket_sizes(R, cfg.min_bucket)
             sizes_arr = jnp.asarray(sizes, jnp.int32)
+            fetch_sizes = _fetch_sizes(R, cfg.min_bucket)
             # the partition reads ONE column of the table per split:
             # column-major view of the same buffer, made once outside
             # the split loop (on TPU the [R, W] table already lies
@@ -850,23 +872,51 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                     col, thr, dl, f, pmeta, ncat if has_cat else None,
                     cbins if has_cat else None, fscal=fscal)
 
+            def make_fetch(Q, P):
+                def fetch(seg, start, start_c, *split):
+                    """``go_left`` over the ``P`` rows of the order's
+                    bucket ``seg`` (``order`` from ``start_c``), the column
+                    read at ``Q`` of them alone: ``order`` from ``start``
+                    clipped to ``R - Q``, which hold the leaf's rows and lie
+                    inside the bucket; the order masks the rest. The window
+                    is sliced out of ``seg``: with ``order`` an operand of
+                    this switch the compiler copied it once more a split
+                    (PERF.md section 6)."""
+                    if Q == P:
+                        return go_left_of(seg, *split)
+                    at = jnp.clip(start, 0, R - Q) - start_c
+                    go_left = go_left_of(lax.dynamic_slice(seg, (at,), (Q,)),
+                                         *split)
+                    return lax.dynamic_update_slice(
+                        jnp.zeros(P, go_left.dtype), go_left, (at,))
+                return fetch
+
             def make_part(P, dense=False):
-                def part(order, start, rows, f, thr, dl, ncat, cbins,
-                         colv, fscal):
+                # the fetch's rungs that no smaller bucket holds
+                below = max((S for S in sizes if S < P), default=0)
+                rungs = [Q for Q in fetch_sizes if below < Q <= P]
+                fetches = [make_fetch(Q, P) for Q in rungs]
+
+                def part(order, start, rows, *split):
                     """Stable two-way partition of the leaf's segment
                     (≡ DataPartition::Split, data_partition.hpp:102).
                     ``dense`` is a tree's first split: the segment is
                     every row and ``order`` still the identity, so the
-                    column is read whole and nothing is gathered."""
+                    column is read whole and nothing is gathered. Else the
+                    column is read at the smallest rung of the fetch's
+                    ladder that holds the leaf, and the bucket is sorted."""
                     start_c = jnp.clip(start, 0, max(R - P, 0))
                     delta = start - start_c
                     with timer.stage("partition_fetch"):
                         if dense:
                             seg = jnp.arange(R, dtype=jnp.int32)
+                            go_left = go_left_of(None, *split)
                         else:
                             seg = lax.dynamic_slice(order, (start_c,), (P,))
-                        go_left = go_left_of(None if dense else seg, f, thr,
-                                             dl, ncat, cbins, colv, fscal)
+                            rung = jnp.sum(
+                                jnp.asarray(rungs, jnp.int32) >= rows) - 1
+                            go_left = lax.switch(rung, fetches, seg, start,
+                                                 start_c, *split)
                     with timer.stage("partition_order"):
                         pos = jnp.arange(P, dtype=jnp.int32)
                         valid = (pos >= delta) & (pos < delta + rows)
@@ -1061,8 +1111,24 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             # from arange(R); a handoff further on never sees step 0)
             part_branches = [make_part(P) for P in sizes] + \
                 [make_part(R, dense=True)]
-            # the rows each branch reads: what the partition counter takes
+            # the rows each branch orders: what the partition counter takes
             part_read = jnp.asarray(sizes + [R], jnp.int32)
+            fetch_arr = jnp.asarray(fetch_sizes, jnp.int32)
+
+            def parts_counted(calls):
+                """int32 [4] over a tree's partitions (``part_calls``, once
+                a tree, after the loop): the split leaves' rows, the rows
+                the fetch read for them, the rows the order read (their
+                buckets), and the splits whose fetch took a rung under its
+                bucket. Step 0 is a tree's first split, which reads every
+                row in place, or a hybrid handoff's, which records none."""
+                rows, ordered = calls[:, 0], calls[:, 1]
+                rung = jnp.sum(fetch_arr >= rows[:, None], axis=1) - 1
+                read = jnp.where(jnp.arange(rows.shape[0]) == 0, ordered,
+                                 jnp.where(ordered > 0, fetch_arr[rung], 0))
+                return jnp.stack([jnp.sum(rows), jnp.sum(read),
+                                  jnp.sum(ordered),
+                                  jnp.sum(read < ordered)]).astype(jnp.int32)
             hist_branches = [make_histb(S) for S in sizes]
             # where the kernel reads the table in place, that split's
             # smaller child takes ``hist_first`` once its bucket is over
@@ -2028,8 +2094,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
             cat_bins=state.tree_cat,
             first_split_dense=state.first_dense.astype(jnp.int32),
             hist_rows=rows_counted(state.hist_calls) if compact else None,
-            part_rows=(jnp.sum(state.part_calls, axis=0, dtype=jnp.int32)
-                       if compact else None),
+            part_rows=parts_counted(state.part_calls) if compact else None,
         )
         if compact:
             # rebuild per-row leaf ids from the final segments: mark each

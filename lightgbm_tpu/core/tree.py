@@ -97,14 +97,16 @@ class TreeArrays(NamedTuple):
     # other maker
     hist_rows: jnp.ndarray = None  # i32 [3]
     # the compact grower's partitions of this tree, summed: (the split
-    # leaves' rows, the row indices the partition's fetch and order read
-    # for them: each leaf's bucket, every row at a dense first split).
-    # ``1 - live / read`` is the share of the partition's indices that are
-    # padding. A hybrid handoff counts the compact loop's splits alone, a
-    # mesh the first shard's rows; None from every other maker (the masked
-    # and level growers), which partition no segment. For utils/timer's
-    # counters, as ``hist_rows``
-    part_rows: jnp.ndarray = None  # i32 [2]
+    # leaves' rows, the row indices the partition's column fetch read for
+    # them: each leaf's rung of the fetch's ladder, every row at a dense
+    # first split; the row indices the order read: each leaf's bucket,
+    # every row at a dense first split; the splits whose fetch took a rung
+    # under its bucket). ``1 - live / read`` is the share of the fetch's
+    # indices that are padding. A hybrid handoff counts the compact loop's
+    # splits alone, a mesh the first shard's rows; None from every other
+    # maker (the masked and level growers), which partition no segment.
+    # For utils/timer's counters, as ``hist_rows``
+    part_rows: jnp.ndarray = None  # i32 [4]
 
     @staticmethod
     def empty(max_leaves: int, max_cat: int = 0) -> "TreeArrays":

@@ -2493,8 +2493,10 @@ class GBDT:
         for name, rows in zip(("live", "read", "bucket"), host.hist_rows):
             global_timer.count("hist_rows_" + name, rows)
         if host.part_rows is not None:
-            for name, rows in zip(("live", "read"), host.part_rows):
-                global_timer.count("part_rows_" + name, rows)
+            for name, rows in zip(("part_rows_live", "part_rows_read",
+                                   "part_rows_ordered",
+                                   "part_calls_mid_rung"), host.part_rows):
+                global_timer.count(name, rows)
         mappers = self.train_set.bin_mappers
         n_int = host.num_leaves - 1
         global_timer.count("splits", n_int)

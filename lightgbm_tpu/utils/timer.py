@@ -27,10 +27,14 @@ segments were padded to. ``1 - read / bucket`` is the share of the buckets
 the kernel skipped, ``1 - live / bucket`` the share of every gathered index
 that is padding; and the rows of its partitions (``TreeArrays.part_rows``):
 ``part_rows_live``, the split leaves' segments; ``part_rows_read``, the row
-indices the partition's column fetch and order read for them, each leaf's
-bucket and every row at a dense first split, so ``1 - live / read`` is the
-share of the partition's indices that are padding. The two ``part_rows_*``
-are counted only for a tree whose grower recorded them (the compact one):
+indices the partition's column fetch read for them, each leaf's rung of the
+fetch's ladder (two an octave) and every row at a dense first split, so
+``1 - live / read`` is the share of the fetched indices that are padding;
+``part_rows_ordered``, the row indices the partition's order read, each
+leaf's power-of-two bucket and every row at a dense first split; and
+``part_calls_mid_rung``, the splits whose fetch took a rung under their
+bucket. The four are counted only for a tree whose grower recorded them
+(the compact one):
 a masked or level grower's tree, or a loaded one, adds nothing to them,
 not a zero. Five are notes,
 set and not added (``global_timer.note``) at each set-up (``_setup_train``):

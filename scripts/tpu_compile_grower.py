@@ -11,11 +11,14 @@ It prints the compile seconds, the compiler's memory analysis (the
 temporaries are the block the chip reports as ``peak_bytes_reserved``) and,
 for one stage (``--stage``, default ``partition_fetch``), every gather with
 its operand's shape, layout and memory space: ``S(1)`` in a layout is VMEM,
-none is HBM. It also lists every ``copy`` and ``bitcast`` of an array of the
-table's size, with the computation it sits in: a copy inside a
-``branch_*`` computation is paid once a split. The Pallas kernels are
-compiled by Mosaic as on the chip (``pallas_custom_calls``). Nothing runs,
-so it gives no time. The last line, ``table_sized_copies_in_loop <n>``,
+none is HBM (``stage_gathers <n> stage_gathers_from_vmem <m>`` counts
+them: the fetch's gathers are one a rung of its ladder, in the branches of
+a switch inside each bucket's). It also lists every ``copy`` and
+``bitcast`` of an array of the table's size, with the computation it sits
+in: a copy inside a ``branch_*`` computation is paid once a split. The
+Pallas kernels are compiled by Mosaic as on the chip
+(``pallas_custom_calls``). Nothing runs, so it gives no time. The last
+line, ``table_sized_copies_in_loop <n>``,
 counts the copies and transposing fusions of an array of the table's size
 in the computations the split loop's body reaches: each is paid once a
 split. Layouts change with the shape (at 200,000 x 67 the row gather reads a
@@ -291,8 +294,11 @@ def scan_contractions(text):
 
 def report(text, table_elements, stage):
     """The lines of the compiled module that say where the stage's gathers
-    read from and what was copied."""
-    shape_of, comp = {}, None
+    read from and what was copied. A gather is the stage's where the stage
+    is the innermost ``lgbm.`` scope of its name, in a switch's branch too
+    (the partition's fetch switches over its rungs). Returns the count of
+    the stage's gathers and of those whose operand is in VMEM."""
+    shape_of, comp, gathers, in_vmem = {}, None, 0, 0
     for line in text.splitlines():
         started = _COMPUTATION.match(line)
         if started:
@@ -303,14 +309,18 @@ def report(text, table_elements, stage):
         name, shape, opcode, operands = m.groups()
         shape_of[name] = shape
         op = timer._OP_NAME.search(line)
-        if (opcode == "fusion" and "kind=kCustom" in line and op and
-                op.group(1).endswith(f"lgbm.{stage}/gather")):
+        inner = op.group(1).rsplit("/lgbm.", 1)[-1] if op else ""
+        if (opcode == "fusion" and "kind=kCustom" in line and
+                inner.startswith(f"{stage}/") and inner.endswith("/gather")):
             operand = timer._OPERAND.findall(operands)[0]
+            gathers += 1
+            in_vmem += "S(1)" in (shape_of.get(operand) or "")
             print(f"gather {name} = {shape}  in {comp}\n"
                   f"    operand {operand} = {shape_of.get(operand)}")
         elif (opcode in ("copy", "bitcast") and not shape.startswith("(")
               and _elements(shape) == table_elements):
             print(f"{opcode} {name} = {shape}  in {comp}")
+    return gathers, in_vmem
 
 
 def main():
@@ -379,7 +389,8 @@ def main():
         with open(args.text, "w") as f:
             f.write(text)
     print(f"pallas_custom_calls {text.count('tpu_custom_call')}")
-    report(text, args.rows * width, args.stage)
+    gathers, in_vmem = report(text, args.rows * width, args.stage)
+    print(f"stage_gathers {gathers} stage_gathers_from_vmem {in_vmem}")
     in_loop = copies_in_loop(text, args.rows * width)
     for comp, name, shape in in_loop:
         print(f"in the split loop: {name} = {shape}  in {comp}")

@@ -610,3 +610,90 @@ def test_hist_rows_counts_the_gathered_calls(rng, name, first_dense,
         assert read < bucket and live < read
     host = HostTree(tree, np.arange(tree.split_feature.shape[0] + 8))
     assert host.hist_rows == (live, read, bucket)
+
+
+# ---- the partition's column fetch reads its leaf on a finer ladder ---------
+
+def _missing_values(rng):
+    # ``test_missing_values``' table, four cells in five missing in its 13
+    # count columns: splits there send most of a leaf one way
+    from test_missing_values import table
+    _, X, y = table(0.8, 3000)
+    return X, y, {}
+
+
+def _clipped_fetch(rng):
+    # 3000 rows, buckets [3000, 2048, 1024, 512, 256]: the root sends about
+    # 1,260 rows right, to the end of ``order``, and its right child is
+    # split again: bucket 2048, fetched at the rung 1536, each clipped to
+    # its own start
+    X = rng.normal(size=(3000, 7))
+    right = X[:, 6] > 0.2
+    y = 3.0 * right + right * X[:, 1] + 0.1 * X[:, 0]
+    return X, y, {}
+
+
+FETCH_CASES = [_odd_columns, _missing_values, _efb, _categorical,
+               _clipped_fetch]
+
+
+def _assert_fetch_case_exercised(case, tree, bundle, X, sizes, rungs):
+    """The tree touches what the case is named for."""
+    feats = tree.split_feature[:int(tree.num_leaves) - 1]
+    if case is _missing_values:
+        # a split on a column with missing values
+        assert (feats < 13).any()
+    elif case is _categorical:
+        assert (tree.cat_count[:int(tree.num_leaves) - 1] > 0).any()
+    elif case is _clipped_fetch:
+        # the root's right child, at the end of ``order``, is split again
+        # at a rung under its bucket, both windows clipped to the end
+        rc = int(tree.right_child[0])
+        assert rc >= 0
+        n = int(tree.internal_count[rc])
+        P = min(S for S in sizes if S >= n)
+        Q = min(S for S in rungs if S >= n)
+        assert n < Q < P < X.shape[0]
+    else:
+        _assert_case_exercised(case, tree, bundle, X)
+
+
+@pytest.mark.parametrize("path", ["cpu", "chip"])
+@pytest.mark.parametrize("case", FETCH_CASES)
+def test_fetch_ladder_grows_the_same_trees(rng, case, path, monkeypatch):
+    """The partition's column fetch on its own ladder (two rungs an
+    octave) against the fetch on the order's power-of-two bucket, as it
+    was: a row's ``go_left`` is the same value read from the same column,
+    so ``order``, ``leaf_id`` and every array of the tree are the same bit
+    for bit, on the CPU's path (unpacked bytes, the scatter histogram and
+    partition) and on the chip's (packed words, the Pallas kernel
+    interpreted, the sorted partition), on a dense table, a table with
+    missing values, EFB bundles, a categorical column and a leaf at the end
+    of ``order`` whose fetch and bucket start at different clipped rows.
+    Only the partition's counters differ: the rows read fall and at least
+    one fetch takes a rung the buckets lack (``part_calls_mid_rung``)."""
+    grower = {} if path == "cpu" else dict(hist_rm_backend="pallas",
+                                           partition_mode="sort")
+    compact, meta, bundle, _, rm, gh, X = _tables(
+        rng, case, L=16 if path == "cpu" else 8, **grower)
+    if path == "chip":
+        compact = dataclasses.replace(compact, packed_cols=rm.shape[1])
+        rm = pack_words(rm)
+    grow = lambda: _grow_with_order(compact, meta, bundle,  # noqa: E731
+                                    jnp.asarray(rm), gh)
+    finer = grow()
+    rungs = grower_mod._fetch_sizes(X.shape[0], compact.min_bucket)
+    monkeypatch.setattr(grower_mod, "_fetch_sizes", grower_mod._bucket_sizes)
+    bucket = grow()
+    np.testing.assert_array_equal(finer[2], bucket[2])
+    np.testing.assert_array_equal(finer[1], bucket[1])
+    for name in finer[0]._fields:
+        x, y = getattr(finer[0], name), getattr(bucket[0], name)
+        if name != "part_rows" and x is not None:
+            np.testing.assert_array_equal(x, y, err_msg=name)
+    live, read, ordered, mid = (int(x) for x in finer[0].part_rows)
+    assert tuple(int(x) for x in bucket[0].part_rows) == (
+        live, ordered, ordered, 0)
+    assert live < read < ordered and mid >= 1
+    sizes = grower_mod._bucket_sizes(X.shape[0], compact.min_bucket)
+    _assert_fetch_case_exercised(case, finer[0], bundle, X, sizes, rungs)

@@ -1,18 +1,23 @@
-"""The partition's counters, ``part_rows_live`` / ``part_rows_read``
-(``TreeArrays.part_rows``, recorded in ``core/grower.py`` where the split
-loop picks the partition's branch, counted in ``models/gbdt.py``
-``_finalize_tree``), against two derivations that do not go through the
-record: the live rows are the tree's own ``internal_count`` summed over its
-splits; the rows read are every row at the first split, which partitions
-the table in place, and at every other split the smallest rung of the
-bucket ladder (the table's rows, then the powers of two under them down to
-the first at or over ``tpu_min_bucket``) that holds its ``internal_count``.
-On the CPU's path and on the chip's (the Pallas kernel on packed words,
-interpreted here), on a narrow dense table, on ``test_missing_values``'s
-table with four cells in five missing, whose trees are lopsided, and with a
-smaller ``tpu_min_bucket``. The growers that partition no segment leave
-both counters absent, and the record sits at the split loop's level of the
-compiled grower, in no branch, adding no ``conditional``."""
+"""The partition's counters, ``part_rows_live`` / ``part_rows_read`` /
+``part_rows_ordered`` / ``part_calls_mid_rung`` (``TreeArrays.part_rows``,
+recorded in ``core/grower.py`` where the split loop picks the partition's
+branch, counted in ``models/gbdt.py`` ``_finalize_tree``), against
+derivations that do not go through the record: the live rows are the
+tree's own ``internal_count`` summed over its splits; the rows the column
+fetch read are every row at the first split, which partitions the table in
+place, and at every other split the smallest rung of the fetch's ladder
+(the table's rows, then every power of two and every three halves of one
+under them down to ``tpu_min_bucket``) that holds its ``internal_count``;
+the rows the order read are the same with the bucket ladder (the table's
+rows, then the powers of two under them down to the first at or over
+``tpu_min_bucket``); the mid-rung calls are the splits whose two rungs
+differ. On the CPU's path and on the chip's (the Pallas kernel on packed
+words, interpreted here), on a narrow dense table, on
+``test_missing_values``'s table with four cells in five missing, whose
+trees are lopsided, and with a smaller ``tpu_min_bucket``. The growers that
+partition no segment leave the counters absent, and the record sits at the
+split loop's level of the compiled grower, in no branch, adding no
+``conditional``."""
 import importlib.util
 import os
 from unittest import mock
@@ -57,7 +62,22 @@ TABLES = {"dense": (dense_table, {}),
           "min_bucket": (dense_table, {"tpu_min_bucket": 256})}
 
 
+KEYS = ("part_rows_live", "part_rows_read", "part_rows_ordered",
+        "part_calls_mid_rung")
+
+
 def ladder_bucket(rows, R, min_bucket):
+    """The fetch's rung of a leaf of ``rows``: R, and every 2^k and
+    3 * 2^(k-1) from ``min_bucket`` up to under R."""
+    rungs = [R] + [v for k in range(R.bit_length())
+                   for v in (2 ** k, 3 * 2 ** k // 2)
+                   if min_bucket <= v < R]
+    return min(S for S in rungs if S >= rows)
+
+
+def pow2_bucket(rows, R, min_bucket):
+    """The order's bucket of a leaf of ``rows``: R, and every power of
+    two under it down to the first at or over ``min_bucket``."""
     rungs = [R] + [1 << k for k in range(R.bit_length())
                    if min_bucket <= 1 << k < R]
     return min(S for S in rungs if S >= rows)
@@ -70,8 +90,7 @@ def test_partition_rows_follow_the_trees_and_the_ladder(path, name):
     make, asked = TABLES[name]
     X, y = make(rows)
     counters = timer.global_timer.counters
-    keys = ("part_rows_live", "part_rows_read")
-    before = {k: counters.get(k, 0) for k in keys}
+    before = {k: counters.get(k, 0) for k in KEYS}
     booster = lgb.Booster({**PARAMS, "num_leaves": leaves, **extra, **asked},
                           lgb.Dataset(X, label=y))
     engine = booster._engine
@@ -90,10 +109,12 @@ def test_partition_rows_follow_the_trees_and_the_ladder(path, name):
         assert n == leaves - 1
         count = [int(c) for c in tree.internal_count[:n]]
         assert count[0] == rows
-        want = (sum(count), rows + sum(ladder_bucket(c, rows, min_bucket)
-                                       for c in count[1:]))
+        read = [ladder_bucket(c, rows, min_bucket) for c in count[1:]]
+        ordered = [pow2_bucket(c, rows, min_bucket) for c in count[1:]]
+        want = (sum(count), rows + sum(read), rows + sum(ordered),
+                sum(q < p for q, p in zip(read, ordered)))
         assert tree.part_rows == want
-        assert 0 < want[0] < want[1]
+        assert 0 < want[0] < want[1] <= want[2]
         sides = [[int(tree.internal_count[c]) if c >= 0 else
                   int(tree.leaf_count[~c]) for c in (left, right)]
                  for left, right in zip(tree.left_child[:n],
@@ -102,9 +123,34 @@ def test_partition_rows_follow_the_trees_and_the_ladder(path, name):
     if name == "missing":
         # splits that send under a tenth of their leaf one way
         assert lopsided
-    added = tuple(counters[k] - before[k] for k in keys)
+    added = tuple(counters[k] - before[k] for k in KEYS)
     assert added == tuple(sum(t.part_rows[k] for t in trees)
-                          for k in range(2))
+                          for k in range(len(KEYS)))
+
+
+def test_the_finer_ladder_pads_the_lopsided_table_less(monkeypatch):
+    """On ``test_missing_values``'s table, whose splits send most of a
+    leaf one way, the share of the fetched indices that are padding, ``1 -
+    part_rows_live / part_rows_read``, is below what the power-of-two
+    ladder leaves, ``1 - part_rows_live / part_rows_ordered``, which is
+    what the fetch read on that ladder: the rows read with the fetch put
+    back on the buckets."""
+    extra, rows, leaves = PATHS["cpu"]
+    X, y = missing_values_table(rows)
+
+    def counted():
+        fresh = timer.Timer()
+        with mock.patch.object(gbdt_mod, "global_timer", fresh):
+            booster = lgb.Booster({**PARAMS, "num_leaves": leaves, **extra},
+                                  lgb.Dataset(X, label=y))
+            for _ in range(STEPS):
+                booster.update()
+        return [fresh.counters[k] for k in KEYS]
+
+    live, read, ordered, mid = counted()
+    monkeypatch.setattr(grower_mod, "_fetch_sizes", grower_mod._bucket_sizes)
+    assert counted() == [live, ordered, ordered, 0]
+    assert 1 - live / read < 1 - live / ordered and mid > 0
 
 
 @pytest.mark.parametrize("params", [
@@ -124,8 +170,7 @@ def test_growers_that_partition_no_segment_count_nothing(params):
         trees = booster._engine.models
     assert fresh.counters["trees"] == 1 and trees[0].num_leaves > 1
     assert trees[0].part_rows is None
-    assert "part_rows_live" not in fresh.counters
-    assert "part_rows_read" not in fresh.counters
+    assert not set(KEYS) & set(fresh.counters)
 
 
 def test_a_hybrid_handoff_counts_the_compact_loops_splits():
@@ -137,9 +182,9 @@ def test_a_hybrid_handoff_counts_the_compact_loops_splits():
                           lgb.Dataset(X, label=y))
     booster.update()
     tree = booster._engine.models[0]
-    live, read = tree.part_rows
+    live, read, ordered, _ = tree.part_rows
     assert 0 < live < int(tree.internal_count[:tree.num_leaves - 1].sum())
-    assert live < read
+    assert live < read <= ordered
 
 
 def _compile_script():
